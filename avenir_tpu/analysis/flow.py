@@ -5,8 +5,8 @@ The AST rules (rules.py) see single-statement shapes; the IR rules
 (ir.py) see what tracing produced. The hazards that cost streamed jobs
 whole runs live BETWEEN those levels, in the host coordination code the
 reference delegated to Hadoop/Storm: threads, queues, and fold order.
-A `queue.get()` with no timeout is a hang the bench watcher cannot
-distinguish from a chip flap; an unjoined worker thread is silent
+A `queue.get()` with no timeout is a hang nothing outside the process
+can tell from slow work; an unjoined worker thread is silent
 truncation at shutdown; shared state mutated off-thread without a lock
 is a read-tear on the caller; blocking IO inside a fold body quietly
 deletes the double-buffered overlap; and a float accumulator folded
@@ -348,8 +348,8 @@ class UnboundedQueueGetRule(FlowRule):
                 yield self.finding(
                     ctx, node,
                     "bare queue .get() blocks forever if the producer "
-                    "dies or the shutdown sentinel is lost — a hang the "
-                    "bench watcher cannot tell from a chip flap")
+                    "dies or the shutdown sentinel is lost — a hang "
+                    "nothing outside the process can tell from slow work")
 
 
 class UnjoinedThreadRule(FlowRule):

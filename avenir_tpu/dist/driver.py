@@ -76,23 +76,17 @@ from avenir_tpu.dist.ledger import BlockLedger
 from avenir_tpu.dist.plan import (DEFAULT_FACTOR, ShardPlan, plan_shards,
                                   write_json_atomic, write_plan)
 from avenir_tpu.dist.worker import RESCAN_AT_FINISH
+from avenir_tpu.utils.devices import checkout_root, cpu_children_env
 
 
 class ShardError(RuntimeError):
     """A sharded run that lost workers or blocks."""
 
 
-def _pkg_parent() -> str:
-    import avenir_tpu
-
-    return os.path.dirname(os.path.dirname(os.path.abspath(
-        avenir_tpu.__file__)))
-
-
 def _worker_env() -> Dict[str, str]:
-    env = dict(os.environ)
+    env = cpu_children_env(dict(os.environ), "--shard")
     env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (_pkg_parent(), env.get("PYTHONPATH")) if p)
+        p for p in (checkout_root(), env.get("PYTHONPATH")) if p)
     return env
 
 
@@ -343,6 +337,7 @@ def run_sharded(name: str, conf, inputs: Sequence[str], output: str,
     canonical, prefix, cfg = _job_cfg(name, conf)
     ops = stream_fold_ops(canonical)
     policy = policy or StragglerPolicy()
+    worker_env = _worker_env()      # refuses before anything is planned
     root = shard_root or tempfile.mkdtemp(prefix="avenir_shard_")
     own_root = shard_root is None
     procs = max(int(procs), 1)
@@ -373,8 +368,8 @@ def run_sharded(name: str, conf, inputs: Sequence[str], output: str,
             workers.append((log, subprocess.Popen(
                 [sys.executable, "-m", "avenir_tpu.dist.worker",
                  root, str(w)],
-                stdout=log, stderr=log, env=_worker_env(),
-                cwd=_pkg_parent(), preexec_fn=preexec)))
+                stdout=log, stderr=log, env=worker_env,
+                cwd=checkout_root(), preexec_fn=preexec)))
         mined = None
         try:
             if worker_hook is not None:
@@ -556,6 +551,7 @@ def run_sharded_refresh(name: str, conf, inputs: Sequence[str],
             f"--incremental alone")
     ops = stream_fold_ops(canonical)
     policy = policy or StragglerPolicy()
+    worker_env = _worker_env()      # refuses before anything is planned
     inputs = [str(p) for p in inputs]
     iplan = _prepare_incremental(canonical, cfg, inputs, output,
                                  state_dir)
@@ -599,8 +595,8 @@ def run_sharded_refresh(name: str, conf, inputs: Sequence[str],
             workers.append((log, subprocess.Popen(
                 [sys.executable, "-m", "avenir_tpu.dist.worker",
                  root, str(w)],
-                stdout=log, stderr=log, env=_worker_env(),
-                cwd=_pkg_parent(), preexec_fn=preexec)))
+                stdout=log, stderr=log, env=worker_env,
+                cwd=checkout_root(), preexec_fn=preexec)))
         try:
             if worker_hook is not None:
                 worker_hook([p.pid for _log, p in workers], root)

@@ -606,6 +606,9 @@ class _Worker:
 
 
 def worker_main(argv) -> int:
+    from avenir_tpu.utils.devices import require_backend
+
+    require_backend()
     root, worker = argv[0], int(argv[1])
     _Worker(root, worker).run()
     return 0

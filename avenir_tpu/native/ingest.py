@@ -2,14 +2,21 @@
 
 The shared library builds lazily with g++ on first use (no pybind11 in the
 image; plain `extern "C"` + ctypes per the environment constraints) and is
-cached next to the source. Everything degrades to the Python parser when a
-compiler is unavailable — `native_available()` gates the fast path.
+cached next to the source under a name keyed on what it was built from:
+the source's content, the compiler flags and this host's CPU (the flags
+include ``-march=native``). A library built from other source, or on
+another machine, has another name and is never loaded. On the CPU the
+package degrades to the Python parser when the build fails —
+`native_available()` gates the fast path; a process on an accelerator
+calls `require_native()` and stops instead.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -20,32 +27,59 @@ from avenir_tpu import obs as _obs
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "csv_ingest.cpp")
-_LIB = os.path.join(_DIR, "libcsv_ingest.so")
+_FLAGS = ("-O3", "-march=native", "-shared", "-fPIC", "-pthread")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_build_failed = False
+#: why the library is unavailable (compiler output), once a build failed
+_build_error: Optional[str] = None
+
+
+def _host_cpu() -> str:
+    """What ``-march=native`` resolves against: this CPU's feature list."""
+    try:
+        with open("/proc/cpuinfo") as fh:
+            for line in fh:
+                if line.startswith(("flags", "Features")):
+                    return line.strip()
+    except OSError:
+        pass
+    return platform.machine()
+
+
+def _lib_path() -> str:
+    key = hashlib.sha256()
+    with open(_SRC, "rb") as fh:
+        key.update(fh.read())
+    key.update(" ".join(_FLAGS).encode())
+    key.update(_host_cpu().encode())
+    return os.path.join(_DIR, f"libcsv_ingest.{key.hexdigest()[:16]}.so")
+
+
+def _compile(lib_path: str) -> None:
+    """Build to a private name and rename into place, so a concurrent
+    process never loads a half-written library."""
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    try:
+        proc = subprocess.run(["g++", *_FLAGS, "-o", tmp, _SRC],
+                              capture_output=True, text=True, timeout=120)
+    except (OSError, subprocess.SubprocessError) as exc:
+        raise RuntimeError(f"g++ did not run: {exc!r}") from exc
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"g++ exited {proc.returncode}: {proc.stderr.strip()[-800:]}")
+    os.replace(tmp, lib_path)
 
 
 def _build() -> Optional[ctypes.CDLL]:
-    global _build_failed
-    if not os.path.exists(_LIB) or (
-        os.path.getmtime(_LIB) < os.path.getmtime(_SRC)
-    ):
-        try:
-            subprocess.run(
-                ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-                 "-pthread", "-o", _LIB, _SRC],
-                check=True, capture_output=True, timeout=120,
-            )
-        except (OSError, subprocess.SubprocessError):
-            _build_failed = True
-            return None
+    global _build_error
+    lib_path = _lib_path()
     try:
-        lib = ctypes.CDLL(_LIB)
-    except OSError:
-        # corrupt / wrong-arch cached .so: degrade to the Python parser
-        _build_failed = True
+        if not os.path.exists(lib_path):
+            _compile(lib_path)
+        lib = ctypes.CDLL(lib_path)
+    except (OSError, RuntimeError) as exc:
+        _build_error = str(exc)
         return None
     c_char_p = ctypes.c_char_p
     i64, i32 = ctypes.c_int64, ctypes.c_int32
@@ -82,15 +116,25 @@ def _build() -> Optional[ctypes.CDLL]:
 
 def _get_lib() -> Optional[ctypes.CDLL]:
     global _lib
-    if _lib is None and not _build_failed:
+    if _lib is None and _build_error is None:
         with _lock:
-            if _lib is None and not _build_failed:
+            if _lib is None and _build_error is None:
                 _lib = _build()
     return _lib
 
 
 def native_available() -> bool:
     return _get_lib() is not None
+
+
+def require_native() -> None:
+    """The accelerator path's rule (utils.devices.require_backend): the
+    Python parser is several times slower, so a chip fed by it is a
+    degrade nobody asked for — stop with the compiler's message."""
+    if _get_lib() is None:
+        raise RuntimeError(
+            "native CSV parser unavailable on an accelerator host "
+            f"({_build_error}); the Python parser is not a fallback here")
 
 
 def parse_csv_native(

@@ -67,6 +67,7 @@ from avenir_tpu.net.fault import (FaultPolicy, Lease, LeaseStore,
 from avenir_tpu.net.router import AffinityRouter, Placement
 from avenir_tpu.server.spool import (nonce_result_name,
                                      request_from_json, spool_dirs)
+from avenir_tpu.utils.devices import checkout_root, cpu_children_env
 
 #: fleet front poll granularity (seconds)
 _POLL_SECS = 0.1
@@ -75,13 +76,6 @@ _POLL_SECS = 0.1
 _PRICE_MEMO_TTL_SECS = 30.0
 #: price-memo size bound for resident fronts
 _PRICE_MEMO_MAX = 4096
-
-
-def _pkg_parent() -> str:
-    import avenir_tpu
-
-    return os.path.dirname(os.path.dirname(
-        os.path.abspath(avenir_tpu.__file__)))
 
 
 def affinity_key(request) -> Tuple:
@@ -377,9 +371,11 @@ class Fleet:
 
     # ------------------------------------------------------------ lifecycle
     def _host_env(self) -> Dict[str, str]:
-        env = dict(os.environ if self._env is None else self._env)
+        env = cpu_children_env(
+            dict(os.environ if self._env is None else self._env),
+            "fleet --hosts")
         env["PYTHONPATH"] = os.pathsep.join(
-            p for p in (_pkg_parent(), env.get("PYTHONPATH")) if p)
+            p for p in (checkout_root(), env.get("PYTHONPATH")) if p)
         return env
 
     def _spawn_host(self, i: int) -> None:
@@ -408,7 +404,7 @@ class Fleet:
         with open(self._logs[i], "ab") as log:
             proc = subprocess.Popen(cmd, stdout=log, stderr=log,
                                     env=self._host_env(),
-                                    cwd=_pkg_parent(),
+                                    cwd=checkout_root(),
                                     preexec_fn=preexec)
         with self._lock:
             self._procs[i] = proc

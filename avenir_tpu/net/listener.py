@@ -19,7 +19,8 @@ Surface:
   (``JobServer.metrics_snapshot``) plus an ``edge`` section.
 - ``GET /healthz`` — 200 ``{"status": "serving"}`` /
   503 ``{"status": "draining"}``: the drain state a fleet router or
-  load balancer health-checks. Supervision can overlay
+  load balancer health-checks, with the device the process runs on
+  (``utils.devices.device_report``). Supervision can overlay
   ``"quarantined"`` / ``"restarting"`` via :meth:`set_health_state`
   (503 as well) so operators and a fleet front probing the edge see
   the same state the supervisor acted on.
@@ -512,11 +513,14 @@ class _Handler(BaseHTTPRequestHandler):
         listener: NetListener = self.server.listener
         path = urlsplit(self.path).path
         if path == "/healthz":
+            from avenir_tpu.utils.devices import device_report
+
             status = listener.health_state()
             self._reply(200 if status == "serving" else 503,
                         {"status": status,
                          "queued": listener.server.queue_depth(),
-                         "edge": listener.edge_stats()})
+                         "edge": listener.edge_stats(),
+                         "device": device_report()})
             return
         if path == "/metrics":
             snap = listener.server.metrics_snapshot()

@@ -692,8 +692,6 @@ def knn_topk_pallas(
 
 def pallas_available() -> bool:
     """The compiled kernel needs a real TPU backend; everywhere else the
-    interpret path (tests) or the jnp route serves."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    interpret path (tests) or the jnp route serves. A backend that fails
+    to initialise raises here: it must not read as "no TPU"."""
+    return jax.default_backend() == "tpu"

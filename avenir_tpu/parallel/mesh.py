@@ -25,19 +25,10 @@ MODEL_AXIS = "model"
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking off.
-
-    ``jax.shard_map(..., check_vma=False)`` only exists on newer JAX; on
-    0.4.x the same program spells ``jax.experimental.shard_map.shard_map
-    (..., check_rep=False)``. Every mesh kernel in this package routes
-    through here so the sharding programs build identically on both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with replication checking off. Every mesh kernel
+    in this package routes through here so the choice is made once."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def data_mesh(devices: Optional[Sequence] = None,

@@ -415,6 +415,9 @@ def serve_main(argv) -> int:
                     help="listen mode: write the bound port here "
                          "(atomic), for scripts that asked for port 0")
     args = ap.parse_args(argv)
+    from avenir_tpu.utils.devices import require_backend
+
+    require_backend()
     metrics_path = args.metrics
     if metrics_path is None and args.spool:
         os.makedirs(args.spool, exist_ok=True)

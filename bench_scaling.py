@@ -5,10 +5,11 @@ Prints ONE JSON line:
    max devices>, "unit": "fraction_of_linear", "table": [...],
    "miner_tripwire": {...}}
 
-Runs on real chips when the host has them; otherwise bootstraps a virtual
-CPU device pool (same mechanism as __graft_entry__.dryrun_multichip). See
-avenir_tpu/parallel/scaling.py for what the virtual numbers do and don't
-mean.
+A CPU instrument: it pins JAX_PLATFORMS=cpu and a virtual CPU device pool
+in its own environment before JAX starts, so this process and every child
+it starts (fleets, shard workers, solo arms) state the same platform and
+none of them touches a chip. See avenir_tpu/parallel/scaling.py for what
+the virtual numbers do and don't mean.
 
 miner_tripwire: the two slowest streamed jobs of the 100M-row scale run
 (frequentItemsApriori, candidateGenerationWithSelfJoin — STREAM_SCALE_r05
@@ -21,6 +22,7 @@ until the next 100M-row run.
 """
 
 import json
+import os
 import sys
 import tempfile
 
@@ -1707,7 +1709,6 @@ def shard_tripwire(rows: int = 10_000_000, floor: float = 1.5,
                 [_sys.executable, "-c", code, job, json.dumps(conf),
                  inp, out],
                 capture_output=True, text=True, timeout=7200,
-                env=dict(os.environ, AVENIR_SKIP_DEVICE_PROBE="1"),
                 preexec_fn=preexec)
             if proc.returncode != 0:
                 raise RuntimeError(
@@ -2151,9 +2152,20 @@ def score_tripwire(queries: int = 512, floor: float = 3.0,
 
 
 def main(n_devices: int = 8, quick: bool = False):
-    from __graft_entry__ import _bootstrap_devices
+    # before the first `import jax`: the environment is read once, and
+    # the children inherit it
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
+             if not f.startswith("--xla_force_host_platform_device_count")]
+    os.environ["XLA_FLAGS"] = " ".join(
+        flags + [f"--xla_force_host_platform_device_count={n_devices}"])
+    import jax
 
-    devices = _bootstrap_devices(n_devices)
+    devices = jax.devices()
+    if len(devices) != n_devices or devices[0].platform != "cpu":
+        raise RuntimeError(
+            f"wanted {n_devices} virtual CPU devices, got {devices}: JAX "
+            "was initialised before bench_scaling.main set its environment")
     from avenir_tpu.parallel.scaling import measure_scaling
 
     # --quick: smoke-scale workloads (single-core hosts; CI)

@@ -513,8 +513,7 @@ class TestRunSharded:
         solo = str(tmp_path / "cli_solo.txt")
         run_job("mutualInformation", conf_path, [corpus["csv"]], solo)
         out = str(tmp_path / "cli_sharded.txt")
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   AVENIR_SKIP_DEVICE_PROBE="1")
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
         proc = subprocess.run(
             [sys.executable, "-m", "avenir_tpu", "mutualInformation",

@@ -136,12 +136,10 @@ def test_host_transfer_outside_loop_silent():
 
 # ------------------------------------------------------------ ir-widen-64bit
 def test_widen_64bit_fires_on_x64_trace():
-    from jax.experimental import enable_x64
-
     def bad(x):
         return x.astype(jnp.float64) + jnp.arange(4)   # f64 convert + i64 iota
 
-    with enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(bad)(jax.ShapeDtypeStruct((4,), np.float32))
     findings = check_jaxpr(_spec(), jaxpr, [Widen64BitRule()])
     assert _ids(findings) == {"ir-widen-64bit"}

@@ -372,8 +372,7 @@ def test_mid_scan_kill_then_rerun_reproduces_cold_bytes(tmp_path, job,
     child = _KILL_CHILD % {"repo": REPO, "kills": 2, "job": job,
                            "conf": json.dumps(conf), "csv": csv,
                            "out": out, "state": state}
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               AVENIR_SKIP_DEVICE_PROBE="1")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, "-c", child],
                           capture_output=True, text=True, timeout=600,
                           env=env, cwd=REPO)

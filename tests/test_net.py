@@ -43,7 +43,6 @@ from avenir_tpu.server import JobRequest, JobServer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SUB_ENV = dict(os.environ, JAX_PLATFORMS="cpu",
-                AVENIR_SKIP_DEVICE_PROBE="1",
                 PYTHONPATH=os.pathsep.join(
                     p for p in (REPO, os.environ.get("PYTHONPATH"))
                     if p))

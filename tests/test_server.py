@@ -780,8 +780,7 @@ def test_serve_cli_stdin(tmp_path):
          "--workers", "1"],
         input=json.dumps(req) + "\n", capture_output=True, text=True,
         timeout=240,
-        env=dict(os.environ, JAX_PLATFORMS="cpu",
-                 AVENIR_SKIP_DEVICE_PROBE="1"))
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert proc.returncode == 0, proc.stderr[-800:]
     row = json.loads(proc.stdout.strip().splitlines()[-1])
     assert row["ok"], row

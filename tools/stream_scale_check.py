@@ -336,14 +336,12 @@ def ensure_file(path, blob, reps):
 
 
 def run_child(job, conf, inp, out, incremental_state=None):
-    env = dict(os.environ, AVENIR_SKIP_DEVICE_PROBE="1")
     argv = ([sys.executable, "-c", _CHILD_INCR, job, json.dumps(conf),
              inp, out, incremental_state] if incremental_state
             else [sys.executable, "-c", _CHILD, job, json.dumps(conf),
                   inp, out])
     proc = subprocess.run(argv,
-                          capture_output=True, text=True, timeout=7200,
-                          env=env)
+                          capture_output=True, text=True, timeout=7200)
     if proc.returncode != 0:
         raise RuntimeError(f"{job} failed: {proc.stderr[-500:]}")
     line = json.loads(proc.stdout.strip().splitlines()[-1])
@@ -399,8 +397,7 @@ def audit_status(mode: str) -> str:
         proc = subprocess.run(
             [sys.executable, os.path.join("tools", "graftlint.py"),
              flag, "--json"],
-            capture_output=True, text=True, timeout=1800,
-            env=dict(os.environ, AVENIR_SKIP_DEVICE_PROBE="1"))
+            capture_output=True, text=True, timeout=1800)
         rows = json.loads(proc.stdout)[key]
         ok = sum(1 for r in rows if r[verdict])
         return f"{ok}/{len(rows)}"
@@ -491,11 +488,10 @@ def main():
             seq_outs.append(f"/tmp/avenir_scale_seq_{job}.txt")
         outdir = "/tmp/avenir_scale_fused"
         os.makedirs(outdir, exist_ok=True)
-        env = dict(os.environ, AVENIR_SKIP_DEVICE_PROBE="1")
         proc = subprocess.run(
             [sys.executable, "-c", _CHILD_SHARED,
              json.dumps([(j, c) for j, c, _p in jobs3]), CHURN_CSV, outdir],
-            capture_output=True, text=True, timeout=7200, env=env)
+            capture_output=True, text=True, timeout=7200)
         if proc.returncode != 0:
             raise RuntimeError(f"fused scan failed: {proc.stderr[-500:]}")
         fused = json.loads(proc.stdout.strip().splitlines()[-1])
@@ -560,7 +556,6 @@ def main():
         # scan split across 2 worker processes (block ledger, plan-
         # ordered merge), in a fresh child; byte-identity asserted
         # against the solo anchors above, shard counters recorded
-        env = dict(os.environ, AVENIR_SKIP_DEVICE_PROBE="1")
         shard_jobs = [
             ("mutualInformation",
              {"mut.feature.schema.file.path": schema_path,
@@ -579,7 +574,7 @@ def main():
             proc = subprocess.run(
                 [sys.executable, "-c", _CHILD_SHARDED, job,
                  json.dumps(conf), inp, out, "2"],
-                capture_output=True, text=True, timeout=7200, env=env)
+                capture_output=True, text=True, timeout=7200)
             if proc.returncode != 0:
                 raise RuntimeError(
                     f"sharded {job} failed: {proc.stderr[-500:]}")
@@ -614,7 +609,7 @@ def main():
             [sys.executable, "-c", _CHILD_SHARDED,
              "frequentItemsApriori", json.dumps(fia_conf), SEQ_CSV,
              out, "2"],
-            capture_output=True, text=True, timeout=7200, env=env)
+            capture_output=True, text=True, timeout=7200)
         if proc.returncode != 0:
             raise RuntimeError(
                 f"sharded miner failed: {proc.stderr[-500:]}")
@@ -646,7 +641,6 @@ def main():
         outdir = f"/tmp/avenir_scale_sidecar_{ROWS_M}m"
         shutil.rmtree(outdir, ignore_errors=True)
         os.makedirs(outdir, exist_ok=True)
-        env = dict(os.environ, AVENIR_SKIP_DEVICE_PROBE="1")
         sc_jobs = [
             ("mutualInformation",
              {"mut.feature.schema.file.path": schema_path,
@@ -663,7 +657,7 @@ def main():
             proc = subprocess.run(
                 [sys.executable, "-c", _CHILD_SIDECAR, job,
                  json.dumps(conf), inp, outdir],
-                capture_output=True, text=True, timeout=7200, env=env)
+                capture_output=True, text=True, timeout=7200)
             if proc.returncode != 0:
                 raise RuntimeError(
                     f"sidecar {job} failed: {proc.stderr[-500:]}")
@@ -683,11 +677,10 @@ def main():
 
         shutil.rmtree(outdir, ignore_errors=True)
         os.makedirs(outdir, exist_ok=True)
-        env = dict(os.environ, AVENIR_SKIP_DEVICE_PROBE="1")
         proc = subprocess.run(
             [sys.executable, "-c", _CHILD_SERVER,
              CHURN_CSV, SEQ_CSV, schema_path, outdir],
-            capture_output=True, text=True, timeout=7200, env=env)
+            capture_output=True, text=True, timeout=7200)
         if proc.returncode != 0:
             raise RuntimeError(f"server load failed: {proc.stderr[-800:]}")
         line = json.loads(proc.stdout.strip().splitlines()[-1])
