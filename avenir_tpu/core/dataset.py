@@ -21,12 +21,14 @@ ids never need to touch the device.
 
 from __future__ import annotations
 
+import contextlib
 import io
 import os
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from avenir_tpu import obs as _obs
 from avenir_tpu.core.schema import FeatureField, FeatureSchema
 
 
@@ -73,7 +75,23 @@ class Dataset:
 
         engine: 'auto' uses the native C++ parser (avenir_tpu/native) when
         built and applicable (path/blob/bytes source, single-char delimiter,
-        no keep_raw), 'native' requires it, 'python' forces the row parser."""
+        no keep_raw), 'native' requires it, 'python' forces the row parser.
+
+        A path source parses under a `dataset.parse` span whose children
+        (`dataset.read`, `.parse.native`, `.encode`, `.range`) stay on the
+        caller's thread; the block route (bytes, on the prefetcher's
+        thread, which `stream.parse` already spans) emits none of them."""
+        if isinstance(source, str) and os.path.exists(source):
+            with _obs.span("dataset.parse", path=source,
+                           nbytes=os.path.getsize(source)) as note:
+                ds = cls._from_source(source, schema, delim, keep_raw, engine)
+                note["rows"] = len(ds)
+            return ds
+        return cls._from_source(source, schema, delim, keep_raw, engine)
+
+    @classmethod
+    def _from_source(cls, source, schema: FeatureSchema, delim: str,
+                     keep_raw: bool, engine: str) -> "Dataset":
         if engine not in ("auto", "native", "python"):
             raise ValueError(f"unknown CSV engine {engine!r} "
                              "(want auto, native, or python)")
@@ -127,19 +145,28 @@ class Dataset:
                          delim: str, required: bool) -> Optional["Dataset"]:
         """Native one-pass columnar parse of a path/blob source; None when
         unavailable (caller falls through to the Python parser)."""
-        if os.path.exists(source):
-            with open(source, "rb") as fh:
-                data = fh.read()
+        is_path = os.path.exists(source)
+        if is_path:
+            with _obs.span("dataset.read") as note:
+                with open(source, "rb") as fh:
+                    data = fh.read()
+                note["nbytes"] = len(data)
         elif "\n" in source or delim in source or source == "":
             data = source.encode()
         else:
             raise FileNotFoundError(f"no such CSV file: {source!r}")
-        return cls._from_native_data(data, schema, delim, required)
+        return cls._from_native_data(data, schema, delim, required,
+                                     spanned=is_path)
 
     @classmethod
     def _from_native_data(cls, data: bytes, schema: FeatureSchema,
-                          delim: str, required: bool) -> Optional["Dataset"]:
+                          delim: str, required: bool,
+                          spanned: bool = False) -> Optional["Dataset"]:
         from avenir_tpu.native.ingest import native_available, parse_csv_native
+
+        # only the path route names its phases: a block's parse runs on
+        # the prefetcher's thread, inside that route's own stream.parse
+        span = _obs.span if spanned else _no_span
 
         if not native_available():
             if required:
@@ -158,16 +185,22 @@ class Dataset:
                    if not f.is_numeric and not f.is_categorical]
         strings += [f.ordinal for f in undeclared]
         try:
-            n, columns, lazy = parse_csv_native(data, delim, numeric,
-                                                categorical, strings,
-                                                lazy_strings=True)
-            for fld in undeclared:
-                # discovery needs the tokens now; materialize eagerly
-                toks = lazy.pop(fld.ordinal)()
-                _discover_cardinality(fld, toks.tolist())
-                index = fld.cardinality_index()
-                columns[fld.ordinal] = np.array(
-                    [index[t] for t in toks], dtype=np.int32)
+            with span("dataset.parse.native", columns=len(schema.fields)) as note:
+                n, columns, lazy = parse_csv_native(data, delim, numeric,
+                                                    categorical, strings,
+                                                    lazy_strings=True)
+                note["rows"] = n
+            with span("dataset.encode", fields=len(undeclared), rows=n):
+                for fld in undeclared:
+                    # discovery needs the tokens now; materialize eagerly
+                    toks = lazy.pop(fld.ordinal)()
+                    _discover_cardinality(fld, toks.tolist())
+                    index = fld.cardinality_index()
+                    columns[fld.ordinal] = np.array(
+                        [index[t] for t in toks], dtype=np.int32)
+                    # released here, not when the function returns: the
+                    # tokens' release is part of what encoding them costs
+                    del toks
         except ValueError as e:
             # align cardinality errors with the Python parser (field name);
             # other ValueErrors (e.g. invalid numerics) pass through as-is
@@ -181,9 +214,10 @@ class Dataset:
                             + f" not in declared cardinality of field "
                             f"{fld.name!r}") from None
             raise
-        for fld in schema.fields:
-            if fld.is_numeric and fld.ordinal in columns:
-                _discover_numeric_range(fld, columns[fld.ordinal])
+        with span("dataset.range", fields=len(numeric)):
+            for fld in schema.fields:
+                if fld.is_numeric and fld.ordinal in columns:
+                    _discover_numeric_range(fld, columns[fld.ordinal])
         return cls(schema, columns, n, lazy=lazy)
 
     @classmethod
@@ -355,6 +389,11 @@ class Dataset:
 
     def __repr__(self) -> str:
         return f"Dataset(n={self.n_rows}, fields={len(self.schema)})"
+
+
+def _no_span(name: str, **attrs):
+    """What stands where `obs.span` would on a route that names no phases."""
+    return contextlib.nullcontext(attrs)
 
 
 def _discover_cardinality(fld, tokens) -> None:

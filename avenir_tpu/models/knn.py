@@ -36,6 +36,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from avenir_tpu import obs
 from avenir_tpu.core.dataset import Dataset, pad_rows
 from avenir_tpu.models.naive_bayes import NaiveBayesModel
 from avenir_tpu.ops.distance import blocked_topk_neighbors, pad_train
@@ -45,8 +46,16 @@ KERNEL_SCALE = 100
 
 KERNELS = ("none", "linearMultiplicative", "linearAdditive", "gaussian")
 
+#: query rows per grid step of the pallas kernels; queries pad up to it
+_BLOCK_Q = 256
+
 
 from avenir_tpu.core.dataset import extract_mixed_features as _extract
+
+
+def _nbytes(*arrays) -> int:
+    """Bytes of the arrays that are there."""
+    return sum(int(a.nbytes) for a in arrays if a is not None)
 
 
 def _expand_mixed(x_num, ranges, x_cat, bins, metric: str):
@@ -135,6 +144,16 @@ class NeighborIndex:
         near-tied neighbors. The default (packed=False) keeps the exact
         kernel so TPU results match the jnp/reference path bit-for-bit
         modulo f32 dot-form error."""
+        attrs = sum(f.is_numeric or f.is_categorical
+                    for f in train.schema.feature_fields)
+        with obs.span("knn.index.build", rows=len(train), attrs=attrs) as note:
+            self._build(train, k, metric, block, approx, use_pallas, packed)
+            note.update(padded_rows=self.n_padded,
+                        nbytes=_nbytes(self.t_num, self.t_cat))
+
+    def _build(self, train: Dataset, k: int, metric: str, block: int,
+               approx: bool, use_pallas: Optional[bool],
+               packed: bool) -> None:
         self.schema = train.schema
         # the reference takes "the first topMatchCount values" — a train set
         # smaller than k just yields all of it
@@ -143,7 +162,8 @@ class NeighborIndex:
         self.approx = approx
         self.block = min(block, max(len(train), 1))
 
-        x_num, ranges, x_cat, bins = _extract(train)
+        with obs.span("knn.index.extract"):
+            x_num, ranges, x_cat, bins = _extract(train)
         # the pallas kernels serve numeric AND mixed data on real TPU (the
         # flop-heavy sifarish role): categoricals one-hot-expand into the
         # numeric matrix (_expand_mixed) so the hamming term is matmul work
@@ -178,15 +198,15 @@ class NeighborIndex:
             # 256x8192 f32 tile = 8 MB VMEM, the measured sweet spot; the
             # lane-packed kernel carries global chunk ids so block_t has no
             # index-bit cap (corpus cap 524288 rows enforced by the kernel)
-            x_num, self.n_attrs = _expand_mixed(x_num, ranges, x_cat, bins,
-                                                metric)
+            with obs.span("knn.index.expand"):
+                x_num, self.n_attrs = _expand_mixed(x_num, ranges, x_cat,
+                                                    bins, metric)
             x_cat = None
             # 256-row granularity: the lane kernel's pair-fold front end
             # requires block_t % 256 == 0 (the exact kernel only needs
             # 128, but a 128-odd block would crash the packed path)
             self.block = max(256, min(pad_rows(len(train), 256), 8192))
-            t_num, x_cat, n_valid = pad_train(x_num, None, self.block)
-        else:
+        with obs.span("knn.index.pad"):
             t_num, x_cat, n_valid = pad_train(x_num, x_cat, self.block)
         # the cap is a static property of the corpus: decide the packed
         # routing once here, not per query (beyond the lane kernel's
@@ -196,38 +216,51 @@ class NeighborIndex:
             from avenir_tpu.ops.pallas_knn import LANE_CORPUS_CAP
 
             self.packed = t_num.shape[0] <= LANE_CORPUS_CAP
-        self.t_num = jnp.asarray(t_num) if t_num is not None else None
-        self.t_cat = jnp.asarray(x_cat) if x_cat is not None else None
+        with obs.span("knn.index.put") as note:
+            self.t_num = jnp.asarray(t_num) if t_num is not None else None
+            self.t_cat = jnp.asarray(x_cat) if x_cat is not None else None
+            self.ranges = jnp.asarray(ranges) if ranges.size else None
+            note["nbytes"] = _nbytes(self.t_num, self.t_cat)
         self.cat_bins = bins
-        self.ranges = jnp.asarray(ranges) if ranges.size else None
         self.n_valid = n_valid
         self.n_padded = (
             self.t_num.shape[0] if self.t_num is not None else self.t_cat.shape[0]
         )
 
-    def neighbors(self, test: Dataset) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """(dist [nq,k], train index [nq,k]); unfillable slots are (+inf, -1)."""
+    @property
+    def kernel(self) -> str:
+        """Which top-k route serves this index's queries."""
+        if not self.use_pallas:
+            return "jnp"
+        return "packed" if self.packed else "exact"
+
+    def queries(self, test: Dataset) -> Tuple:
+        """A test block as the search takes it: (q_num, q_cat, nq). On the
+        pallas route q_num is normalized, one-hot-expanded and padded to
+        the kernels' 256-row query block, and q_cat is None."""
         q_num, _, q_cat, _ = _extract(test)
+        if not self.use_pallas:
+            return q_num, q_cat, len(test)
+        q, _ = _expand_mixed(q_num, self._expand_ranges, q_cat,
+                             self.cat_bins, self.metric)
+        nq = q.shape[0]
+        pad = (-nq) % _BLOCK_Q
+        if pad:
+            q = np.concatenate([q, np.zeros((pad, q.shape[1]), q.dtype)])
+        return q, None, nq
+
+    def search(self, queries: Tuple) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """(dist [nq,k], train index [nq,k]) of prepared `queries`, as
+        dispatched; unfillable slots are (+inf, -1)."""
+        q_num, q_cat, nq = queries
         if self.use_pallas:
             from avenir_tpu.ops.pallas_knn import knn_topk_lanes, knn_topk_pallas
 
-            q, _ = _expand_mixed(q_num, self._expand_ranges, q_cat,
-                                 self.cat_bins, self.metric)
-            bq = 256
-            nq = q.shape[0]
-            pad = (-nq) % bq
-            if pad:
-                q = np.concatenate([q, np.zeros((pad, q.shape[1]), q.dtype)])
-            if self.packed:
-                dist, idx = knn_topk_lanes(
-                    jnp.asarray(q), self.t_num, k=self.k, block_q=bq,
-                    block_t=self.block, metric=self.metric,
-                    n_valid=self.n_valid, n_attrs=self.n_attrs)
-            else:
-                dist, idx = knn_topk_pallas(
-                    jnp.asarray(q), self.t_num, k=self.k, block_q=bq,
-                    block_t=self.block, metric=self.metric,
-                    n_valid=self.n_valid, n_attrs=self.n_attrs)
+            topk = knn_topk_lanes if self.packed else knn_topk_pallas
+            dist, idx = topk(
+                jnp.asarray(q_num), self.t_num, k=self.k, block_q=_BLOCK_Q,
+                block_t=self.block, metric=self.metric,
+                n_valid=self.n_valid, n_attrs=self.n_attrs)
             return dist[:nq], idx[:nq]
         return blocked_topk_neighbors(
             jnp.asarray(q_num) if self.t_num is not None else None,
@@ -243,31 +276,30 @@ class NeighborIndex:
             approx=self.approx,
         )
 
-    def classify_scores(self, test: Dataset, train_labels: jnp.ndarray,
+    def neighbors(self, test: Dataset) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """(dist [nq,k], train index [nq,k]); unfillable slots are (+inf, -1)."""
+        return self.search(self.queries(test))
+
+    def classify_scores(self, queries: Tuple, train_labels: jnp.ndarray,
                         n_classes: int, kernel_fn: str,
                         kernel_param: float) -> Optional[jnp.ndarray]:
-        """Fully fused device classification: kernel-weighted top-k vote
-        scores [nq, C] via ops.pallas_knn.knn_classify_lanes — the top-k
-        results never leave the kernel (non-class-conditional vote modes).
-        Returns None when this index can't serve the fused path (jnp
-        route, or a block too small for the lane kernel's pair fold)."""
+        """Fully fused device classification of prepared `queries`:
+        kernel-weighted top-k vote scores [nq, C] via
+        ops.pallas_knn.knn_classify_lanes — the top-k results never leave
+        the kernel (non-class-conditional vote modes). Returns None when
+        this index can't serve the fused path (jnp route, or a block too
+        small for the lane kernel's pair fold)."""
         if not self.use_pallas or self.block % 256 != 0:
             return None
         from avenir_tpu.ops.pallas_knn import knn_classify_lanes
 
-        q_num, _, q_cat, _ = _extract(test)
-        q, _ = _expand_mixed(q_num, self._expand_ranges, q_cat,
-                             self.cat_bins, self.metric)
-        bq = 256
-        nq = q.shape[0]
-        pad = (-nq) % bq
-        if pad:
-            q = np.concatenate([q, np.zeros((pad, q.shape[1]), q.dtype)])
+        q, _, nq = queries
         scores = knn_classify_lanes(
             jnp.asarray(q), self.t_num, train_labels, k=self.k,
             n_classes=n_classes, n_attrs=self.n_attrs,
-            kernel_fn=kernel_fn, kernel_param=kernel_param, block_q=bq,
-            block_t=self.block, metric=self.metric, n_valid=self.n_valid)
+            kernel_fn=kernel_fn, kernel_param=kernel_param,
+            block_q=_BLOCK_Q, block_t=self.block, metric=self.metric,
+            n_valid=self.n_valid)
         return scores[:nq]
 
 
@@ -313,22 +345,28 @@ class NearestNeighborClassifier:
         )
         pad = self.index.n_padded
         n_valid = self.index.n_valid
-        labels = np.zeros((pad,), np.int32)
-        labels[:n_valid] = train.labels()
-        self.train_labels = jnp.asarray(labels)
+        with obs.span("knn.index.put") as note:
+            labels = np.zeros((pad,), np.int32)
+            labels[:n_valid] = train.labels()
+            self.train_labels = jnp.asarray(labels)
+            note["nbytes"] = labels.nbytes
 
         # class-conditional weighting: P(features_i | class_i) per train row,
         # the quantity jobs (2)-(4) of the reference pipeline compute + join
         # (BayesianPredictor bap.output.feature.prob.only=true mode) — the
         # same NaiveBayesPredictor.feature_prob the file-based job emits
-        post = np.ones((pad,), np.float32)
+        prob = None
         if class_cond_weighted:
             from avenir_tpu.models.naive_bayes import NaiveBayesPredictor
 
             model = nb_model if nb_model is not None else NaiveBayesModel.fit(train)
-            post[: len(train)] = NaiveBayesPredictor(model).feature_prob(
-                train).astype(np.float32)
-        self.train_post = jnp.asarray(post)
+            prob = NaiveBayesPredictor(model).feature_prob(train)
+        with obs.span("knn.index.put") as note:
+            post = np.ones((pad,), np.float32)
+            if prob is not None:
+                post[: len(train)] = prob.astype(np.float32)
+            self.train_post = jnp.asarray(post)
+            note["nbytes"] = post.nbytes
 
     # ------------------------------------------------------------- neighbors
     def neighbors(self, test: Dataset) -> Tuple[np.ndarray, np.ndarray]:
@@ -338,21 +376,27 @@ class NearestNeighborClassifier:
     # --------------------------------------------------------------- predict
     def predict(self, test: Dataset) -> Tuple[np.ndarray, np.ndarray]:
         """Returns (predicted class codes [nq], class scores [nq, K])."""
-        scores = None
-        if self.fused and not self.class_cond:
-            scores = self.index.classify_scores(
-                test, self.train_labels, len(self.class_values),
-                self.kernel, self.kernel_param)
-        if scores is None:
-            dist, idx = self.neighbors(test)
-            neigh_labels = self.train_labels[idx]
-            neigh_post = self.train_post[idx]
-            scores = _vote(
-                dist, neigh_labels, neigh_post,
-                self.kernel, self.kernel_param, len(self.class_values),
-                self.class_cond, self.inverse_weighted,
-            )
-        scores = np.asarray(scores)
+        with obs.span("knn.query.prepare", rows=len(test)):
+            queries = self.index.queries(test)
+        # as dispatched: nothing here waits for the device, the fetch does
+        with obs.span("knn.query.dispatch", rows=len(test)) as note:
+            scores = None
+            if self.fused and not self.class_cond:
+                scores = self.index.classify_scores(
+                    queries, self.train_labels, len(self.class_values),
+                    self.kernel, self.kernel_param)
+            note["kernel"] = "fused" if scores is not None else self.index.kernel
+            if scores is None:
+                dist, idx = self.index.search(queries)
+                neigh_labels = self.train_labels[idx]
+                neigh_post = self.train_post[idx]
+                scores = _vote(
+                    dist, neigh_labels, neigh_post,
+                    self.kernel, self.kernel_param, len(self.class_values),
+                    self.class_cond, self.inverse_weighted,
+                )
+        with obs.span("knn.query.fetch", rows=len(test)):
+            scores = np.asarray(scores)
         # the reference's threshold branch exists only in non-class-cond mode
         # (Neighborhood.classify(), :272-312: weighted path pure-argmaxes)
         if (self.decision_threshold > 0 and len(self.class_values) == 2

@@ -35,6 +35,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from avenir_tpu import obs
 from avenir_tpu.core.dataset import Dataset
 from avenir_tpu.core.schema import FeatureField, FeatureSchema
 from avenir_tpu.utils.metrics import ConfusionMatrix, CostBasedArbitrator
@@ -162,10 +163,13 @@ class NaiveBayesModel:
 
     @classmethod
     def fit(cls, dataset: Dataset) -> "NaiveBayesModel":
-        model = cls.empty(dataset.schema)
-        codes, _ = dataset.feature_codes(model.binned_fields)
-        x_cont = dataset.feature_matrix(model.cont_fields)
-        model.accumulate(codes, dataset.labels(), x_cont)
+        with obs.span("nb.fit", rows=len(dataset)):
+            model = cls.empty(dataset.schema)
+            codes, _ = dataset.feature_codes(model.binned_fields)
+            # the matrix is a temporary of the call, so that its release
+            # falls inside the span as its making does
+            model.accumulate(codes, dataset.labels(),
+                             dataset.feature_matrix(model.cont_fields))
         return model
 
     def merge(self, other: "NaiveBayesModel") -> "NaiveBayesModel":
@@ -452,13 +456,32 @@ class NaiveBayesPredictor:
         """Per-row P(features | actual class): the bap.output.feature.prob.only
         mode whose output the reference's KNN pipeline joins as
         class-conditional weights (BayesianPredictor.java:262-286)."""
+        with obs.span("nb.feature_prob", rows=len(dataset),
+                      binned=len(self.model.binned_fields),
+                      continuous=len(self.model.cont_fields)):
+            logp = np.zeros(len(dataset), np.float64)
+            with obs.span("nb.feature_prob.binned"):
+                y = dataset.labels()
+                self._add_binned(dataset, y, logp)
+            with obs.span("nb.feature_prob.continuous"):
+                self._add_continuous(dataset, y, logp)
+                return np.exp(logp)
+
+    def _add_binned(self, dataset: Dataset, y: np.ndarray,
+                    logp: np.ndarray) -> None:
+        """Add to `logp`, per row, log P(bin | class y) of each binned
+        feature."""
         codes, _ = dataset.feature_codes(self.model.binned_fields)
-        y = dataset.labels()
-        logp = np.zeros(len(dataset), np.float64)
         if codes.shape[1]:
             lp = np.asarray(self.tables["log_post"])       # [F, K, B]
             for f in range(codes.shape[1]):
                 logp += lp[f, y, codes[:, f]]
+
+    def _add_continuous(self, dataset: Dataset, y: np.ndarray,
+                        logp: np.ndarray) -> None:
+        """The same for each continuous feature (Gaussian density). The
+        matrix and the loop's row-long temporaries are this call's own,
+        released when it returns."""
         x_cont = dataset.feature_matrix(self.model.cont_fields)
         if x_cont.shape[1]:
             mean = np.asarray(self.tables["cont_mean"])    # [Fc, K]
@@ -467,4 +490,3 @@ class NaiveBayesPredictor:
                 m, s = mean[f, y], std[f, y]
                 logp += (-0.5 * np.log(2 * np.pi) - np.log(s)
                          - 0.5 * ((x_cont[:, f] - m) / s) ** 2)
-        return np.exp(logp)

@@ -1,16 +1,25 @@
 """avenir-trace: the always-on, low-overhead telemetry subsystem.
 
-Three pieces, all host-side and stdlib-pure (imported by core.stream at
-package init, so nothing here may import jax/numpy at module scope):
+Three pieces, all stdlib-pure (imported by core.stream at package init,
+so nothing here may import jax/numpy at module scope):
 
 - **Span flight recorder** (:mod:`avenir_tpu.obs.trace`): a thread-safe
   ring buffer of ``(name, tid, t0, dur, attrs)`` span events with
   bounded memory and Chrome-trace/Perfetto JSON export. Instrumentation
   points live in core/stream (per-chunk read/parse/fold spans plus
-  producer/consumer stall attribution), runner (per-job phase spans for
-  the solo, shared, incremental and fused-incremental paths) and
+  producer/consumer stall attribution), runner (the ``job.cli`` root of
+  a batch job, per-job phase spans for the solo, shared, incremental
+  and fused-incremental paths), core/dataset, models/knn and
+  models/naive_bayes (the phases inside a kNN job: parse, index build,
+  NB fit and posterior, query prepare/dispatch/fetch, output) and
   server/jobserver (per-request queued/held/dispatch spans with batch
-  linkage attrs).
+  linkage attrs). ``obs.span(name)`` is the one way to time a phase: it
+  records into the ring on the host's ``perf_counter`` clock and, when
+  ``jax`` is already imported, enters a ``jax.profiler.TraceAnnotation``
+  of the same name, so that under a profiler session (``python -m
+  avenir_tpu <job> --trace DIR``) the span stands on the host's line of
+  the device trace, on the device operations' clock. ``jax`` is looked
+  up, never imported.
 - **Streaming histograms** (:mod:`avenir_tpu.obs.histogram`): fixed
   log-spaced bucket accumulators that merge like ``RunningStats``
   (counts and sums are additive, so ``merge`` is associative and

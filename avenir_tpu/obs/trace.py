@@ -5,9 +5,10 @@ The recorder is process-global and always on (module docstring of
 wall-clock interval: ``t0``/``dur`` are ``time.perf_counter`` seconds,
 ``tid`` the recording thread, ``attrs`` a small dict of primitives.
 Device work dispatches asynchronously, so a span around a jitted fold
-measures dispatch+host time, not device occupancy — the per-chunk
-read/parse/fold attribution the streaming stack needs lives entirely on
-the host timeline anyway.
+measures dispatch+host time, not device occupancy. What the device did
+meanwhile is the profiler's to say: :func:`span` also enters a
+``jax.profiler.TraceAnnotation``, so under a profiler session the same
+span stands on the host's line of the device trace, on its clock.
 
 Export is Chrome-trace JSON (the ``traceEvents`` complete-event form:
 ``ph:"X"`` with microsecond ``ts``/``dur``), loadable by Perfetto and
@@ -24,6 +25,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, Iterator, List, NamedTuple, Optional
@@ -161,12 +163,27 @@ def record_min(name: str, t0: float, min_dur: float = STALL_MIN_SECS,
 
 
 @contextlib.contextmanager
-def span(name: str, **attrs) -> Iterator[None]:
+def span(name: str, **attrs) -> Iterator[Dict]:
     """Context-manager span around a region (exception-safe: the span
-    records however the block exits)."""
+    records however the block exits). Yields the span's attribute dict,
+    so what is known only at the end (``rows``, ``nbytes``) is set there.
+
+    With tracing on and ``jax`` already imported the region also enters
+    a ``jax.profiler.TraceAnnotation`` of the same name: under a live
+    profiler session the span stands on the host's line of the
+    ``.xplane.pb``, on the device operations' clock; with none live the
+    annotation costs one activity check. ``jax`` is looked up, never
+    imported, so this module stays stdlib-pure."""
+    if not _ENABLED:
+        yield attrs
+        return
+    jax = sys.modules.get("jax")
+    note = jax.profiler.TraceAnnotation(name) if jax is not None \
+        else contextlib.nullcontext()
     t0 = time.perf_counter()
     try:
-        yield
+        with note:
+            yield attrs
     finally:
         record(name, t0, **attrs)
 

@@ -22,6 +22,7 @@ Markov matrix files, LR coefficient history.
 
 from __future__ import annotations
 
+import contextlib
 import io
 import json
 import math
@@ -1911,24 +1912,26 @@ def nearest_neighbor(cfg: JobConfig, inputs: List[str], output: str) -> JobResul
     with open(out, "w") as fh:
         for test in stream_job_inputs(cfg, [test_path], schema):
             codes, scores = clf.predict(test)
-            if arbitrator is not None:
-                # getClassProb int-percent scale (Neighborhood.java:319-334)
-                tot = np.maximum(scores.sum(axis=1), 1e-9)
-                pos_prob = np.floor(100.0 * scores[:, pos_i] / tot)
-                codes = np.where(arbitrator.classify(pos_prob),
-                                 pos_i, neg_i).astype(np.int32)
-            for i, (rid, c) in enumerate(zip(test.ids(), codes)):
-                fields = [str(rid), cls_vals[int(c)]]
-                if with_distr:
-                    tot = float(np.sum(scores[i])) or 1.0
-                    fields += [f"{cls_vals[j]}:{scores[i][j] / tot:.3f}"
-                               for j in range(len(cls_vals))]
-                fh.write(out_delim.join(fields) + "\n")
-            if validate:
-                if cm is None:
-                    cm = ConfusionMatrix(cls_vals,
-                                         pos_class=clf.positive_class)
-                cm.add(test.labels(), codes)
+            with _obs.span("knn.output.write", rows=len(test)):
+                if arbitrator is not None:
+                    # getClassProb int-percent scale
+                    # (Neighborhood.java:319-334)
+                    tot = np.maximum(scores.sum(axis=1), 1e-9)
+                    pos_prob = np.floor(100.0 * scores[:, pos_i] / tot)
+                    codes = np.where(arbitrator.classify(pos_prob),
+                                     pos_i, neg_i).astype(np.int32)
+                for i, (rid, c) in enumerate(zip(test.ids(), codes)):
+                    fields = [str(rid), cls_vals[int(c)]]
+                    if with_distr:
+                        tot = float(np.sum(scores[i])) or 1.0
+                        fields += [f"{cls_vals[j]}:{scores[i][j] / tot:.3f}"
+                                   for j in range(len(cls_vals))]
+                    fh.write(out_delim.join(fields) + "\n")
+                if validate:
+                    if cm is None:
+                        cm = ConfusionMatrix(cls_vals,
+                                             pos_class=clf.positive_class)
+                    cm.add(test.labels(), codes)
     counters: Dict[str, float] = cm.counters() if cm is not None else {}
     return JobResult("nearestNeighbor", counters, [out])
 
@@ -3398,9 +3401,14 @@ def run_from_cli(argv: Sequence[str]) -> JobResult:
     server processes behind the affinity router (avenir_tpu.net.fleet),
     and `python -m avenir_tpu stats <paths...>` renders one server's
     live metrics.json — or a fleet's, merged through the additive
-    histogram algebra (avenir_tpu.obs.report)."""
-    import argparse
+    histogram algebra (avenir_tpu.obs.report).
 
+    A job runs under the root span `job.cli`. `--trace DIR` also runs it
+    inside a `jax.profiler` session (utils.profiling.trace) that writes
+    the device trace under DIR, and when the job ends writes the span
+    ring to `DIR/trace.json` (`tools/trace_report.py DIR` rolls it up;
+    Perfetto opens both). Without the flag nothing is started or
+    written."""
     if argv and argv[0] == "serve":
         from avenir_tpu.server.spool import serve_main
 
@@ -3433,7 +3441,44 @@ def run_from_cli(argv: Sequence[str]) -> JobResult:
             sys.exit(rc)
         return JobResult("tune")
 
-    ap = argparse.ArgumentParser(prog="avenir_tpu")
+    # looked up before the root span opens, so that the profiler session
+    # encloses it and `job.cli` stands in the device trace as well
+    trace_dir = _trace_flag().parse_known_args(list(argv))[0].trace
+    if trace_dir:
+        from avenir_tpu.utils.profiling import trace
+
+        os.makedirs(trace_dir, exist_ok=True)
+        session = trace(trace_dir)
+    else:
+        session = contextlib.nullcontext()
+    try:
+        with session, _obs.span("job.cli") as note:
+            return _job_from_cli(argv, note)
+    finally:
+        if trace_dir:
+            _obs.recorder().export_chrome(
+                os.path.join(trace_dir, "trace.json"))
+
+
+def _trace_flag():
+    """The parser of `--trace DIR` alone (a parent of the job parser)."""
+    import argparse
+
+    ap = argparse.ArgumentParser(add_help=False)
+    ap.add_argument("--trace", metavar="DIR", default=None,
+                    help="run the job under jax.profiler: the device "
+                         "trace goes under DIR, the span ring to "
+                         "DIR/trace.json")
+    return ap
+
+
+def _job_from_cli(argv: Sequence[str], note: Dict) -> JobResult:
+    """The job surface of `run_from_cli`, inside its `job.cli` span
+    (`note` is that span's attributes): argument parse, device rule,
+    the job, the result line."""
+    import argparse
+
+    ap = argparse.ArgumentParser(prog="avenir_tpu", parents=[_trace_flag()])
     ap.add_argument("jobname", help="job name or reference Tool class")
     ap.add_argument("--conf", required=False, default=None,
                     help="properties file (the -Dconf.path analog)")
@@ -3477,6 +3522,7 @@ def run_from_cli(argv: Sequence[str]) -> JobResult:
         props["stream.autotune"] = "true"
     short = args.jobname.rsplit(".", 1)[-1]
     name = args.jobname if args.jobname in _REGISTRY else short[0].lower() + short[1:]
+    note["job"] = name
     inputs, output = args.paths[:-1], args.paths[-1]
     if args.shard and args.incremental and (
             _REGISTRY[name][0] if name in _REGISTRY else name) in (

@@ -3,12 +3,11 @@
 The reference has no tracing or profiling at all — only log4j debug flags
 and Hadoop counters (SURVEY §5: "New framework: jax.profiler traces +
 per-phase wall clock; this is green-field"). This module is that
-green-field piece:
+green-field piece (per-phase wall clock is `avenir_tpu.obs.span`):
 
-- PhaseTimer: named per-phase wall-clock accounting for multi-stage jobs
-  (the timing analog of the reference's per-job Hadoop counter groups).
 - trace(): context manager around jax.profiler for TensorBoard-readable
-  device traces of a region.
+  device traces of a region; `python -m avenir_tpu <job> --trace DIR`
+  runs a job inside it.
 - RunningStats: mergeable count/mean/variance/min/max accumulator (the
   chombo SimpleStat role, SURVEY §0 dependency table) — moments add, so
   shard results combine exactly like the device psum path.
@@ -18,87 +17,17 @@ from __future__ import annotations
 
 import contextlib
 import math
-import threading
-import time
 from dataclasses import dataclass
-from typing import Dict, Iterator, List
-
-
-class PhaseTimer:
-    """Accumulated wall clock per named phase.
-
-    with timer.phase("ingest"): ...
-    with timer.phase("train"): ...
-    timer.report() -> {"ingest": seconds, ...}
-
-    Thread-safe: phase exits mutate the accumulators under a lock, so
-    one timer can be shared across server worker threads (phases that
-    OVERLAP in time still sum their full durations — per-worker timers
-    aggregated through :meth:`merge` are the per-thread view)."""
-
-    def __init__(self):
-        self.totals: Dict[str, float] = {}
-        self.counts: Dict[str, int] = {}
-        self._order: List[str] = []
-        self._lock = threading.Lock()
-
-    @contextlib.contextmanager
-    def phase(self, name: str) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            with self._lock:
-                if name not in self.totals:
-                    self._order.append(name)
-                    self.totals[name] = 0.0
-                    self.counts[name] = 0
-                self.totals[name] += dt
-                self.counts[name] += 1
-
-    def _snapshot(self) -> Dict[str, tuple]:
-        with self._lock:
-            return {name: (self.totals[name], self.counts[name])
-                    for name in self._order}
-
-    def merge(self, other: "PhaseTimer") -> "PhaseTimer":
-        """Fold another timer's accumulators into this one (additive,
-        like every fold-state merge in the repo) — how per-worker
-        timers aggregate into one report. Snapshot-then-apply: the two
-        locks are never held together, so ``a.merge(b)`` can never
-        deadlock against a concurrent ``b.merge(a)``."""
-        for name, (total, count) in other._snapshot().items():
-            with self._lock:
-                if name not in self.totals:
-                    self._order.append(name)
-                    self.totals[name] = 0.0
-                    self.counts[name] = 0
-                self.totals[name] += total
-                self.counts[name] += count
-        return self
-
-    def report(self) -> Dict[str, float]:
-        with self._lock:
-            return {name: self.totals[name] for name in self._order}
-
-    def summary(self) -> str:
-        with self._lock:
-            total = sum(self.totals.values()) or 1.0
-            lines = []
-            for name in self._order:
-                t = self.totals[name]
-                lines.append(
-                    f"{name:>20s}  {t:9.3f}s  {100 * t / total:5.1f}%  "
-                    f"x{self.counts[name]}")
-        return "\n".join(lines)
+from typing import Iterator
 
 
 @contextlib.contextmanager
 def trace(log_dir: str) -> Iterator[None]:
     """jax.profiler device trace of the enclosed region, written for
     TensorBoard / xprof. No-ops cleanly if the profiler can't start (e.g.
-    an already-active trace).
+    an already-active trace). The Python tracer is off: the program's
+    own spans stand on the host's line (`obs.span` annotates), and an
+    event per Python call would slow the job it measures.
 
     The region also records into the avenir-trace span recorder
     (``jax.profiler.trace`` span with the device trace dir and whether
@@ -108,9 +37,11 @@ def trace(log_dir: str) -> Iterator[None]:
 
     from avenir_tpu import obs
 
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
     started = False
     try:
-        jax.profiler.start_trace(log_dir)
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
         started = True
     except Exception:
         pass

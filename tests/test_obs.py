@@ -103,6 +103,63 @@ def test_span_context_manager_records_on_exception():
     assert spans[0].attrs == {"tag": "x"}
 
 
+def test_span_yields_its_attributes_so_the_end_can_set_them():
+    with trace.capture() as rec:
+        with trace.span("parse", path="p") as note:
+            note["rows"] = 7
+        prev = trace.set_enabled(False)
+        try:
+            with trace.span("off", path="q") as note:
+                note["rows"] = 8          # the same code runs with tracing off
+        finally:
+            trace.set_enabled(prev)
+        with trace.span("bare"):
+            pass
+    assert [(sp.name, sp.attrs) for sp in rec.spans()] == [
+        ("parse", {"path": "p", "rows": 7}), ("bare", None)]
+
+
+def test_span_annotates_for_the_profiler_only_when_jax_is_there(monkeypatch):
+    """`obs` imports nothing but the standard library: `span` looks `jax`
+    up among the loaded modules, and records all the same without it."""
+    import sys
+    import types
+
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            entered.append("/" + self.name)
+
+    fake = types.SimpleNamespace(
+        profiler=types.SimpleNamespace(TraceAnnotation=Annotation))
+    with trace.capture() as rec:
+        monkeypatch.setitem(sys.modules, "jax", fake)
+        with trace.span("outer"):
+            with trace.span("inner"):
+                pass
+        prev = trace.set_enabled(False)
+        try:
+            with trace.span("off"):
+                pass
+        finally:
+            trace.set_enabled(prev)
+        monkeypatch.delitem(sys.modules, "jax")
+        with trace.span("no_jax"):
+            pass
+    assert entered == ["outer", "inner", "/inner", "/outer"]
+    assert [sp.name for sp in rec.spans()] == ["inner", "outer", "no_jax"]
+    with open(trace.__file__) as fh:
+        source = fh.read()
+    assert "import jax" not in source and "import numpy" not in source
+
+
 def test_record_min_suppresses_instant_spans():
     with trace.capture() as rec:
         trace.record_min("stall", trace.now(), min_dur=10.0)   # instant
@@ -333,6 +390,8 @@ def test_trace_report_rolls_phases_and_stalls(tmp_path):
     assert report["stalls"][0]["total_ms"] == pytest.approx(200.0)
     folds = {r["sink"]: r for r in report["folds"]}
     assert folds["nb"]["chunks"] == 3
+    # nothing nests here: every span's self time is its own
+    assert all(r["self_ms"] == r["total_ms"] for r in report["phases"])
     # the CLI renders without error and exits 0
     assert tr.main([path]) == 0
     # the bare JSON-array Chrome-trace form loads too
@@ -344,3 +403,33 @@ def test_trace_report_rolls_phases_and_stalls(tmp_path):
     bad = str(tmp_path / "bad.json")
     open(bad, "w").write("not json")
     assert tr.main([bad]) == 2
+
+
+def test_trace_report_self_time_counts_nested_spans_once(tmp_path):
+    """A parent only encloses: its self time is its duration less what
+    the spans inside it on its own thread cover, and self times add up
+    to the outermost span's duration."""
+    import tools.trace_report as tr
+
+    rec = SpanRecorder()
+    rec.record("job.cli", t0=0.0, dur=1.0, tid=1)
+    rec.record("dataset.parse", t0=0.1, dur=0.4, tid=1)
+    rec.record("dataset.read", t0=0.1, dur=0.1, tid=1)
+    rec.record("dataset.encode", t0=0.25, dur=0.25, tid=1)
+    rec.record("knn.query.fetch", t0=0.6, dur=0.1, tid=1)
+    rec.record("knn.query.fetch", t0=0.8, dur=0.1, tid=1)
+    rec.record("stream.stall.consumer", t0=0.5, dur=0.05, tid=1)
+    # another thread's span inside the root's interval is not the root's
+    rec.record("stream.parse", t0=0.3, dur=0.2, tid=2)
+    report = tr.build_report(rec.export_chrome(str(tmp_path / "t.json")))
+    self_ms = {r["phase"]: r["self_ms"] for r in report["phases"]}
+    assert self_ms == pytest.approx({
+        "job.cli": 350.0, "dataset.parse": 50.0, "dataset.read": 100.0,
+        "dataset.encode": 250.0, "knn.query.fetch": 200.0,
+        "stream.parse": 200.0})
+    total = {r["phase"]: r["total_ms"] for r in report["phases"]}
+    assert total["job.cli"] == pytest.approx(1000.0)
+    assert total["dataset.parse"] == pytest.approx(400.0)
+    # with the stall's 50 ms the root thread's self times are the root
+    assert sum(v for k, v in self_ms.items() if k != "stream.parse") + 50.0 \
+        == pytest.approx(1000.0)

@@ -1,73 +1,12 @@
-"""PhaseTimer / trace / RunningStats / Histogram percentile utilities."""
+"""trace / RunningStats / Histogram percentile utilities."""
 
 import math
-import threading
-import time
 
 import numpy as np
 import pytest
 
-from avenir_tpu.utils.profiling import PhaseTimer, RunningStats, trace
+from avenir_tpu.utils.profiling import RunningStats, trace
 from avenir_tpu.utils.sampling import Histogram
-
-
-def test_phase_timer_accumulates():
-    t = PhaseTimer()
-    with t.phase("a"):
-        time.sleep(0.01)
-    with t.phase("b"):
-        time.sleep(0.005)
-    with t.phase("a"):
-        time.sleep(0.01)
-    rep = t.report()
-    assert list(rep) == ["a", "b"]
-    assert rep["a"] >= 0.018 and rep["b"] >= 0.004
-    assert t.counts["a"] == 2
-    assert "a" in t.summary() and "%" in t.summary()
-
-
-def test_phase_timer_is_thread_safe():
-    """Regression: the dict mutations in phase() used to race when one
-    timer was shared across server worker threads — concurrent first
-    exits of the same phase could lose counts (read-modify-write on
-    totals/counts) or double-append to the report order."""
-    t = PhaseTimer()
-    n_threads, per_thread = 8, 200
-    barrier = threading.Barrier(n_threads)
-
-    def worker():
-        barrier.wait()                  # maximize first-exit contention
-        for _ in range(per_thread):
-            with t.phase("hot"):
-                pass
-            with t.phase("cold"):
-                pass
-
-    threads = [threading.Thread(target=worker) for _ in range(n_threads)]
-    for th in threads:
-        th.start()
-    for th in threads:
-        th.join()
-    assert t.counts["hot"] == n_threads * per_thread
-    assert t.counts["cold"] == n_threads * per_thread
-    assert sorted(t.report()) == ["cold", "hot"]   # no duplicate order rows
-
-
-def test_phase_timer_merge_aggregates_workers():
-    a, b = PhaseTimer(), PhaseTimer()
-    with a.phase("ingest"):
-        time.sleep(0.005)
-    with b.phase("ingest"):
-        time.sleep(0.005)
-    with b.phase("train"):
-        time.sleep(0.002)
-    out = a.merge(b)
-    assert out is a
-    assert a.counts == {"ingest": 2, "train": 1}
-    assert a.report()["ingest"] >= 0.008
-    assert list(a.report()) == ["ingest", "train"]
-    # b is only read: per-worker timers survive their own aggregation
-    assert b.counts == {"ingest": 1, "train": 1}
 
 
 def test_trace_writes_profile(tmp_path):

@@ -3,7 +3,8 @@
 The span flight recorder (avenir_tpu.obs.trace) exports ``traceEvents``
 JSON that Perfetto / chrome://tracing render on a timeline; this tool is
 the terminal view of the same file: a per-phase rollup (count, total,
-mean, p95, max per span name), a per-chunk breakdown of the streaming
+self, mean, p95, max per span name; self is a span's time less what the
+spans it encloses on its thread cover, the column that adds up), a per-chunk breakdown of the streaming
 phases (read / parse / fold), and a stall-attribution section that ranks
 the producer/consumer stall sources by total blocked time — the first
 question profiling-guided tuning asks ("where does the time go per
@@ -54,6 +55,41 @@ def load_events(path):
     return out, meta
 
 
+def add_self_times(events):
+    """Give every event its `self_ms`: its duration less what the spans it
+    encloses on its own thread cover. Spans nest (a parent only encloses),
+    so a total over nested spans would count the same time once per level;
+    self times add up to the outermost span's duration."""
+    by_tid = defaultdict(list)
+    for ev in events:
+        ev["self_ms"] = ev["dur_ms"]
+        by_tid[ev["tid"]].append(ev)
+    for evs in by_tid.values():
+        evs.sort(key=lambda ev: (ev["ts"], -ev["dur_ms"]))
+        open_spans = []                  # enclosing spans, outermost first
+        for ev in evs:
+            while open_spans and _end_us(open_spans[-1]) <= ev["ts"]:
+                open_spans.pop()
+            if open_spans:
+                parent = open_spans[-1]
+                inside = min(_end_us(ev), _end_us(parent)) - ev["ts"]
+                parent["self_ms"] -= inside / 1000.0
+            open_spans.append(ev)
+    return events
+
+
+def _end_us(ev):
+    return ev["ts"] + ev["dur_ms"] * 1000.0
+
+
+def self_totals(events):
+    """{name: summed self_ms} of events `add_self_times` has seen."""
+    totals = defaultdict(float)
+    for ev in events:
+        totals[ev["name"]] += ev["self_ms"]
+    return dict(totals)
+
+
 def rollup(events):
     """{name: LatencyHistogram-of-ms} across all spans."""
     hists = defaultdict(LatencyHistogram)
@@ -62,14 +98,17 @@ def rollup(events):
     return dict(hists)
 
 
-def phase_table(hists, wall_ms):
+def phase_table(hists, wall_ms, self_ms):
     """The per-phase rows, widest total first. `wall_ms` (trace extent)
-    scales the %-of-wall column; phases overlap across threads, so the
-    percentages legitimately sum past 100 on a fused run."""
+    scales the %-of-wall column; phases overlap across threads and
+    parents enclose their children, so the percentages legitimately sum
+    past 100. `self_ms` ({name: ms}, `self_totals`) is the column that
+    adds up."""
     rows = []
     for name, h in hists.items():
         rows.append({"phase": name, "count": h.count,
                      "total_ms": round(h.total, 3),
+                     "self_ms": round(self_ms.get(name, 0.0), 3),
                      "mean_ms": round(h.mean, 3),
                      "p95_ms": round(h.quantile(95), 3),
                      "max_ms": round(h.max_val, 3),
@@ -119,6 +158,7 @@ def build_report(path, top=20):
     t_lo = min(ev["ts"] for ev in events)
     t_hi = max(ev["ts"] + ev["dur_ms"] * 1000.0 for ev in events)
     wall_ms = (t_hi - t_lo) / 1000.0
+    add_self_times(events)
     work = [ev for ev in events
             if not ev["name"].startswith(STALL_PREFIX)]
     return {"trace": path,
@@ -126,7 +166,8 @@ def build_report(path, top=20):
             "dropped_spans": int(meta.get("dropped_spans", 0)),
             "wall_ms": round(wall_ms, 3),
             "threads": len({ev["tid"] for ev in events}),
-            "phases": phase_table(rollup(work), wall_ms)[:top],
+            "phases": phase_table(rollup(work), wall_ms,
+                                  self_totals(work))[:top],
             "folds": chunk_table(events)[:top],
             "stalls": stall_table(events)[:top]}
 
@@ -171,8 +212,8 @@ def main(argv=None):
           f"({report['dropped_spans']} dropped) across "
           f"{report['threads']} thread(s), {report['wall_ms']:.1f}ms wall")
     _print_rows(report["phases"],
-                ["phase", "count", "total_ms", "mean_ms", "p95_ms",
-                 "max_ms", "pct_wall"], "per-phase rollup (ms):")
+                ["phase", "count", "total_ms", "self_ms", "mean_ms",
+                 "p95_ms", "max_ms", "pct_wall"], "per-phase rollup (ms):")
     _print_rows(report["folds"],
                 ["sink", "chunks", "total_ms", "mean_ms", "p95_ms"],
                 "per-sink fold time (ms):")
