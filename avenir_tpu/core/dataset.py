@@ -162,7 +162,9 @@ class Dataset:
     def _from_native_data(cls, data: bytes, schema: FeatureSchema,
                           delim: str, required: bool,
                           spanned: bool = False) -> Optional["Dataset"]:
-        from avenir_tpu.native.ingest import native_available, parse_csv_native
+        from avenir_tpu.native.ingest import (distinct_column_native,
+                                              native_available,
+                                              parse_csv_native)
 
         # only the path route names its phases: a block's parse runs on
         # the prefetcher's thread, inside that route's own stream.parse
@@ -173,34 +175,29 @@ class Dataset:
                 raise RuntimeError("native CSV ingest unavailable")
             return None
         numeric = [f.ordinal for f in schema.fields if f.is_numeric]
-        # categoricals with a fixed declared vocabulary encode in C; those
-        # with an undeclared (data-discovered, growable) vocabulary come
-        # back as tokens and encode below
-        declared = [f for f in schema.fields if f.is_categorical
-                    and f.cardinality and not f.discovered_cardinality]
-        undeclared = [f for f in schema.fields if f.is_categorical
-                      and (not f.cardinality or f.discovered_cardinality)]
-        categorical = [(f.ordinal, f.cardinality) for f in declared]
+        cats = [f for f in schema.fields if f.is_categorical]
+        # a vocabulary the schema does not declare (data-discovered,
+        # growable) is settled first, from the column's distinct tokens;
+        # the parse then encodes every categorical in C alike
+        discovered = [f for f in cats
+                      if not f.cardinality or f.discovered_cardinality]
         strings = [f.ordinal for f in schema.fields
                    if not f.is_numeric and not f.is_categorical]
-        strings += [f.ordinal for f in undeclared]
         try:
+            with span("dataset.encode", fields=len(discovered), rows=0,
+                      native=0, vocab=0) as note:
+                for fld in discovered:
+                    distinct, note["rows"] = distinct_column_native(
+                        data, delim, fld.ordinal)
+                    _discover_cardinality(fld, distinct)
+                    note["native"] += 1
+                    note["vocab"] += len(distinct)
             with span("dataset.parse.native", columns=len(schema.fields)) as note:
-                n, columns, lazy = parse_csv_native(data, delim, numeric,
-                                                    categorical, strings,
-                                                    lazy_strings=True)
+                n, columns, lazy = parse_csv_native(
+                    data, delim, numeric,
+                    [(f.ordinal, f.cardinality) for f in cats], strings,
+                    lazy_strings=True)
                 note["rows"] = n
-            with span("dataset.encode", fields=len(undeclared), rows=n):
-                for fld in undeclared:
-                    # discovery needs the tokens now; materialize eagerly
-                    toks = lazy.pop(fld.ordinal)()
-                    _discover_cardinality(fld, toks.tolist())
-                    index = fld.cardinality_index()
-                    columns[fld.ordinal] = np.array(
-                        [index[t] for t in toks], dtype=np.int32)
-                    # released here, not when the function returns: the
-                    # tokens' release is part of what encoding them costs
-                    del toks
         except ValueError as e:
             # align cardinality errors with the Python parser (field name);
             # other ValueErrors (e.g. invalid numerics) pass through as-is

@@ -5,7 +5,11 @@
 // framework replaces HDFS splits with host CSV -> device arrays, and this
 // library makes that host step native: one pass over the byte buffer
 // producing float32 numeric columns and dictionary-encoded int32
-// categorical columns directly (no Python string objects per field).
+// categorical columns directly (no Python string objects per field). A
+// categorical whose vocabulary the schema does not declare is encoded the
+// same way: csv_distinct_column hands back the column's distinct tokens,
+// the caller settles the vocabulary from that handful, and the parse
+// encodes the column beside the others.
 //
 // Exposed via ctypes (no pybind11 in the image); see
 // avenir_tpu/native/ingest.py for the Python contract.
@@ -43,16 +47,28 @@ struct Vocab {
         return h;
     }
 
+    void place(size_t v) {
+        size_t h = hash(values[v].data(), values[v].size()) & mask;
+        while (slots[h] >= 0) h = (h + 1) & mask;
+        slots[h] = static_cast<int32_t>(v);
+    }
+
     void build() {
         size_t cap = 8;
         while (cap < values.size() * 2) cap <<= 1;
         slots.assign(cap, -1);
         mask = cap - 1;
-        for (size_t v = 0; v < values.size(); ++v) {
-            size_t h = hash(values[v].data(), values[v].size()) & mask;
-            while (slots[h] >= 0) h = (h + 1) & mask;
-            slots[h] = static_cast<int32_t>(v);
-        }
+        for (size_t v = 0; v < values.size(); ++v) place(v);
+    }
+
+    // Discovery: a token not seen yet takes the next code. Only a new
+    // token is copied, so a column of few values allocates a few times.
+    void add(const char* b, size_t n) {
+        if (slots.empty()) build();
+        if (find(b, n) >= 0) return;
+        values.emplace_back(b, n);
+        if (values.size() * 2 > slots.size()) build();
+        else place(values.size() - 1);
     }
 
     int32_t find(const char* b, size_t n) const {
@@ -235,6 +251,51 @@ std::vector<const char*> stripe_bounds(const char* buf, int64_t len,
     return bounds;
 }
 
+// Stripes worth spawning over len bytes for a caller asking n_threads
+// (0: the host's cores): below ~4MB a stripe the spawn+count overhead
+// beats the parallel win. At least 1.
+int32_t stripe_count(int64_t len, int32_t n_threads) {
+    if (n_threads <= 0)
+        n_threads = static_cast<int32_t>(std::thread::hardware_concurrency());
+    int64_t max_stripes = len / (4 << 20);
+    if (n_threads > max_stripes) n_threads = static_cast<int32_t>(max_stripes);
+    return n_threads < 1 ? 1 : n_threads;
+}
+
+// fn(b, e) on the trimmed token at `ordinal` of every non-blank line of
+// [p, end), in row order; a short row gives the empty token, which keeps
+// the rows aligned. Returns the rows visited.
+template <typename Fn>
+int64_t for_each_token(const char* p, const char* end, char delim,
+                       int32_t ordinal, Fn fn) {
+    int64_t rows = 0;
+    while (p < end) {
+        const char* nl = static_cast<const char*>(memchr(p, '\n', end - p));
+        const char* line_end = nl ? nl : end;
+        const char* b = p;
+        const char* e = line_end;
+        trim(b, e);
+        if (e > b) {
+            int32_t ord = 0;
+            const char* fb = p;
+            const char* q = p;
+            for (; q < line_end; ++q) {
+                if (*q == delim) {
+                    if (ord == ordinal) break;
+                    ++ord;
+                    fb = q + 1;
+                }
+            }
+            if (ord != ordinal) fb = q;  // short row
+            trim(fb, q);
+            fn(fb, q);
+            ++rows;
+        }
+        p = nl ? nl + 1 : end;
+    }
+    return rows;
+}
+
 // Run fn(i) on n threads; false if spawning failed (work may be partially
 // done — callers must treat false as "redo sequentially").
 template <typename Fn>
@@ -303,13 +364,7 @@ int64_t csv_parse_mt(const char* buf, int64_t len, char delim,
                      const char* vocab_blob, const int32_t* vocab_counts,
                      int32_t* cat_out, int64_t n_rows,
                      int64_t* err_row, int32_t* err_ord, int32_t n_threads) {
-    if (n_threads <= 0) {
-        n_threads = static_cast<int32_t>(std::thread::hardware_concurrency());
-        if (n_threads <= 0) n_threads = 1;
-    }
-    // below ~4MB the spawn+count overhead beats the parallel win
-    int64_t max_stripes = len / (4 << 20);
-    if (n_threads > max_stripes) n_threads = static_cast<int32_t>(max_stripes);
+    n_threads = stripe_count(len, n_threads);
     if (n_threads <= 1)
         return csv_parse(buf, len, delim, max_ord, num_ords, n_num, num_out,
                          cat_ords, n_cat, vocab_blob, vocab_counts, cat_out,
@@ -357,12 +412,7 @@ int64_t csv_parse_mt(const char* buf, int64_t len, char delim,
 // Striped row count: the sequential pre-count is otherwise the Amdahl
 // bottleneck of the parallel ingest (two full-buffer scans, one serial).
 int64_t csv_count_rows_mt(const char* buf, int64_t len, int32_t n_threads) {
-    if (n_threads <= 0) {
-        n_threads = static_cast<int32_t>(std::thread::hardware_concurrency());
-        if (n_threads <= 0) n_threads = 1;
-    }
-    int64_t max_stripes = len / (4 << 20);
-    if (n_threads > max_stripes) n_threads = static_cast<int32_t>(max_stripes);
+    n_threads = stripe_count(len, n_threads);
     if (n_threads <= 1) return count_range(buf, buf + len);
     std::vector<const char*> bounds = stripe_bounds(buf, len, n_threads);
     std::vector<int64_t> rows(n_threads, 0);
@@ -379,36 +429,8 @@ int64_t csv_count_rows_mt(const char* buf, int64_t len, int32_t n_threads) {
 int64_t csv_column_bytes(const char* buf, int64_t len, char delim,
                          int32_t ordinal) {
     int64_t total = 0;
-    const char* p = buf;
-    const char* end = buf + len;
-    while (p < end) {
-        const char* nl = static_cast<const char*>(memchr(p, '\n', end - p));
-        const char* line_end = nl ? nl : end;
-        const char* b = p;
-        const char* e = line_end;
-        trim(b, e);
-        if (e > b) {
-            int32_t ord = 0;
-            const char* fb = p;
-            bool found = false;
-            for (const char* q = p; q <= line_end; ++q) {
-                if (q == line_end || *q == delim) {
-                    if (ord == ordinal) {
-                        const char* tb = fb;
-                        const char* te = q;
-                        trim(tb, te);
-                        total += (te - tb) + 1;
-                        found = true;
-                        break;
-                    }
-                    ++ord;
-                    fb = q + 1;
-                }
-            }
-            if (!found) total += 1;  // short row: empty token keeps alignment
-        }
-        p = nl ? nl + 1 : end;
-    }
+    for_each_token(buf, buf + len, delim, ordinal,
+                   [&](const char* b, const char* e) { total += e - b + 1; });
     return total;
 }
 
@@ -417,45 +439,72 @@ int64_t csv_column_bytes(const char* buf, int64_t len, char delim,
 int64_t csv_extract_column(const char* buf, int64_t len, char delim,
                            int32_t ordinal, char* out, int64_t cap) {
     int64_t w = 0;
-    const char* p = buf;
-    const char* end = buf + len;
-    while (p < end) {
-        const char* nl = static_cast<const char*>(memchr(p, '\n', end - p));
-        const char* line_end = nl ? nl : end;
-        const char* b = p;
-        const char* e = line_end;
-        trim(b, e);
-        if (e > b) {
-            int32_t ord = 0;
-            const char* fb = p;
-            bool found = false;
-            for (const char* q = p; q <= line_end; ++q) {
-                if (q == line_end || *q == delim) {
-                    if (ord == ordinal) {
-                        const char* tb = fb;
-                        const char* te = q;
-                        trim(tb, te);
-                        int64_t n = te - tb;
-                        if (w + n + 1 > cap) return -1;
-                        memcpy(out + w, tb, n);
-                        w += n;
-                        out[w++] = '\n';
-                        found = true;
-                        break;
-                    }
-                    ++ord;
-                    fb = q + 1;
-                }
-            }
-            if (!found) {  // short row: empty token keeps row alignment
-                if (w + 1 > cap) return -1;
-                out[w++] = '\n';
-            }
+    bool fits = true;
+    for_each_token(buf, buf + len, delim, ordinal,
+                   [&](const char* b, const char* e) {
+        int64_t n = e - b;
+        if (!fits || w + n + 1 > cap) {
+            fits = false;
+            return;
         }
-        p = nl ? nl + 1 : end;
-    }
-    return w;
+        memcpy(out + w, b, n);
+        w += n;
+        out[w++] = '\n';
+    });
+    return fits ? w : -1;
 }
+
+// The distinct tokens of one column, each followed by '\n' (a token holds
+// none), in no order, as a malloc'd buffer in *out that the caller hands
+// back to csv_free; *n_rows takes the rows visited. This is how a
+// categorical with no declared vocabulary is discovered: every stripe
+// keeps a set of its own over (ptr, len), the sets are merged, and what
+// leaves is the column's few values, never a token per row. Returns the
+// buffer's bytes, or -1 when it could not be allocated.
+int64_t csv_distinct_column(const char* buf, int64_t len, char delim,
+                            int32_t ordinal, int32_t n_threads, char** out,
+                            int64_t* n_rows) {
+    n_threads = stripe_count(len, n_threads);
+    std::vector<Vocab> seen(n_threads);
+    std::vector<int64_t> rows(n_threads, 0);
+    auto scan = [&](int32_t i, const char* b, const char* e) {
+        Vocab& mine = seen[i];
+        rows[i] = for_each_token(b, e, delim, ordinal,
+                                 [&mine](const char* tb, const char* te) {
+            mine.add(tb, te - tb);
+        });
+    };
+    if (n_threads > 1) {
+        std::vector<const char*> bounds = stripe_bounds(buf, len, n_threads);
+        if (!run_threads(n_threads, [&](int32_t i) {
+                scan(i, bounds[i], bounds[i + 1]);
+            }))
+            n_threads = 1;                  // a failed spawn: start over
+    }
+    if (n_threads == 1) {
+        seen.assign(1, Vocab());
+        scan(0, buf, buf + len);
+    }
+    Vocab& all = seen[0];
+    *n_rows = rows[0];
+    for (int32_t i = 1; i < n_threads; ++i) {
+        for (const std::string& v : seen[i].values) all.add(v.data(), v.size());
+        *n_rows += rows[i];
+    }
+    int64_t size = 0;
+    for (const std::string& v : all.values) size += v.size() + 1;
+    char* w = static_cast<char*>(malloc(size > 0 ? size : 1));
+    if (!w) return -1;
+    *out = w;
+    for (const std::string& v : all.values) {
+        memcpy(w, v.data(), v.size());
+        w += v.size();
+        *w++ = '\n';
+    }
+    return size;
+}
+
+void csv_free(char* p) { free(p); }
 
 // Ragged tokenize + dictionary-encode (the sequence-job ingest: markov /
 // HMM lines are "id,class,s1,s2,..." with per-row token counts). One scan

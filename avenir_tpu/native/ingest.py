@@ -105,6 +105,11 @@ def _build() -> Optional[ctypes.CDLL]:
     lib.csv_extract_column.restype = i64
     lib.csv_extract_column.argtypes = [c_char_p, i64, ctypes.c_char, i32,
                                        ctypes.c_char_p, i64]
+    lib.csv_distinct_column.restype = i64
+    lib.csv_distinct_column.argtypes = [c_char_p, i64, ctypes.c_char, i32, i32,
+                                        ctypes.POINTER(ctypes.c_void_p), p_i64]
+    lib.csv_free.restype = None
+    lib.csv_free.argtypes = [ctypes.c_void_p]
     p_i64_arr = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
     lib.seq_token_count.restype = i64
     lib.seq_token_count.argtypes = [c_char_p, i64, ctypes.c_char, p_i64]
@@ -150,11 +155,15 @@ def parse_csv_native(
 
     Numeric columns come back float32 (missing -> NaN), categorical int32
     codes against the given cardinalities (unknown value raises ValueError,
-    matching the Python parser's contract), string/id columns as numpy
-    object arrays — or, with lazy_strings=True, as zero-arg thunks in the
-    third return value (materializing millions of python strings costs
-    more than the whole numeric/categorical parse; algorithms that never
-    read ids skip it entirely)."""
+    matching the Python parser's contract; a short row is the empty token,
+    so it raises too unless the cardinality holds ``""``). A categorical
+    whose vocabulary is discovered from the data comes through here like a
+    declared one, once `distinct_column_native` has found its values.
+    String/id columns come back as numpy object arrays — or, with
+    lazy_strings=True, as zero-arg thunks in the third return value
+    (materializing millions of python strings costs more than the whole
+    numeric/categorical parse; algorithms that never read ids skip it
+    entirely)."""
     lib = _get_lib()
     if lib is None:
         raise RuntimeError("native CSV ingest unavailable (no g++?)")
@@ -168,14 +177,21 @@ def parse_csv_native(
         v.encode() + b"\0" for _, card in categorical for v in card
     )
     vocab_counts = np.asarray([len(card) for _, card in categorical], np.int32)
+    if vocab_blob.count(b"\0") != int(vocab_counts.sum()):
+        raise ValueError("a categorical value holds a NUL byte, which the "
+                         "native parser's vocabulary cannot carry")
     all_ords = list(numeric_ordinals) + [o for o, _ in categorical] + list(
         string_ordinals)
     max_ord = max(all_ords) if all_ords else 0
 
     # prefill sentinels: rows shorter than the schema leave numeric NaN
-    # (matching the Python parser) and categorical -1 (checked below)
+    # and categorical the empty token's code (both matching the Python
+    # parser), or -1 where the vocabulary has no such value (checked below)
     num_out = np.full((len(num_ords), n), np.nan, np.float32)
     cat_out = np.full((len(cat_ords), n), -1, np.int32)
+    for i, (_, card) in enumerate(categorical):
+        if "" in card:
+            cat_out[i] = card.index("")
     err_row = ctypes.c_int64(-1)
     err_ord = ctypes.c_int32(-1)
     # threads=0 lets the library pick hardware_concurrency; stripes are
@@ -238,6 +254,29 @@ def _extract_column(lib, data: bytes, d: bytes, ordinal: int) -> List[str]:
     if not raw:
         return []
     return raw.decode().split("\n")[:-1]
+
+
+def distinct_column_native(data: bytes, delim: str, ordinal: int,
+                           threads: int = 0) -> Tuple[List[str], int]:
+    """(the distinct trimmed tokens of one column in no order, the rows
+    scanned): what vocabulary discovery needs of a column, found by a
+    striped native scan that makes no Python string per row. A short row
+    counts as the empty token, as in `extract_column_raw`."""
+    lib = _get_lib()
+    if lib is None:
+        raise RuntimeError("native CSV ingest unavailable (no g++?)")
+    out = ctypes.c_void_p()
+    n_rows = ctypes.c_int64(0)
+    size = int(lib.csv_distinct_column(
+        data, len(data), delim.encode()[0:1], np.int32(ordinal),
+        np.int32(threads), ctypes.byref(out), ctypes.byref(n_rows)))
+    if size < 0:
+        raise MemoryError("csv_distinct_column could not allocate its result")
+    try:
+        blob = ctypes.string_at(out, size)
+    finally:
+        lib.csv_free(out)
+    return blob.decode().split("\n")[:-1], n_rows.value
 
 
 def extract_column_raw(data: bytes, delim: str, ordinal: int

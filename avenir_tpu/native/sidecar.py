@@ -338,6 +338,9 @@ def _unpack_dataset_block(buf: bytes, entry: dict, schema, delim: str):
         else:
             fld = schema.field_by_ordinal(o)
             if fld.is_categorical:
+                # not the native discovery of Dataset._from_native_data:
+                # here an empty token is a blank line, which the CSV
+                # parser's row rule skips, so the rows would shift
                 toks = part.decode().split("\n")[:-1]
                 _discover_cardinality(fld, toks)
                 index = fld.cardinality_index()

@@ -326,28 +326,36 @@ def test_parses_actual_reference_schemas():
         assert len(s.fields) > 0, p
 
 
-def test_undeclared_categorical_discovers_vocab():
+@pytest.mark.parametrize("engine", ["python", "native"])
+def test_undeclared_categorical_discovers_vocab(engine):
     """Categorical without declared cardinality (elearnActivity.json's
     status field): vocabulary discovered from data, consistent across
     splits parsed with the same schema, growable on unseen values."""
     from avenir_tpu.core.dataset import Dataset
     from avenir_tpu.core.schema import FeatureSchema
 
-    for engine in ("python", "native"):
-        s = FeatureSchema.from_json({"fields": [
-            {"name": "x", "ordinal": 0, "dataType": "double", "feature": True},
-            {"name": "status", "ordinal": 1, "dataType": "categorical"},
-        ]})
-        ds1 = Dataset.from_csv("1,pass\n2,fail\n3,pass\n", s, engine=engine)
-        assert s.field_by_name("status").cardinality == ["fail", "pass"]
-        np.testing.assert_array_equal(ds1.labels(), [1, 0, 1])
-        # a later split with only one value keeps the same codes
-        ds2 = Dataset.from_csv("4,pass\n", s, engine=engine)
-        np.testing.assert_array_equal(ds2.labels(), [1])
-        # and an unseen value extends instead of raising
-        ds3 = Dataset.from_csv("5,hold\n", s, engine=engine)
-        assert s.field_by_name("status").cardinality == ["fail", "pass", "hold"]
-        np.testing.assert_array_equal(ds3.labels(), [2])
+    s = FeatureSchema.from_json({"fields": [
+        {"name": "x", "ordinal": 0, "dataType": "double", "feature": True},
+        {"name": "status", "ordinal": 1, "dataType": "categorical"},
+    ]})
+    status = s.field_by_name("status")
+    ds1 = Dataset.from_csv("1,pass\n2,fail\n3,pass\n", s, engine=engine)
+    assert status.cardinality == ["fail", "pass"]
+    assert status.discovered_cardinality
+    np.testing.assert_array_equal(ds1.labels(), [1, 0, 1])
+    assert ds1.labels().dtype == np.int32
+    # a later split with only one value keeps the same codes
+    ds2 = Dataset.from_csv("4,pass\n", s, engine=engine)
+    np.testing.assert_array_equal(ds2.labels(), [1])
+    # and an unseen value extends instead of raising
+    ds3 = Dataset.from_csv("5,hold\n", s, engine=engine)
+    assert status.cardinality == ["fail", "pass", "hold"]
+    np.testing.assert_array_equal(ds3.labels(), [2])
+    # several unseen values at once go behind the known ones, sorted
+    # among themselves, and a short row is the empty token
+    ds4 = Dataset.from_csv("6,zeta\n7\n8,alpha\n9,fail\n", s, engine=engine)
+    assert status.cardinality == ["fail", "pass", "hold", "", "alpha", "zeta"]
+    np.testing.assert_array_equal(ds4.labels(), [5, 3, 4, 0])
 
 
 def test_implicit_feature_roles_without_flags():

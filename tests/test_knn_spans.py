@@ -134,7 +134,8 @@ def test_the_job_emits_each_span_as_often_as_it_should(files, flow, tmp_path):
     assert by_name["dataset.read"].attrs == {"nbytes": parse["nbytes"]}
     assert by_name["dataset.parse.native"].attrs == {"rows": TRAIN_ROWS,
                                                      "columns": 5}
-    assert by_name["dataset.encode"].attrs == {"fields": 1, "rows": TRAIN_ROWS}
+    assert by_name["dataset.encode"].attrs == {
+        "fields": 1, "rows": TRAIN_ROWS, "native": 1, "vocab": 2}
     assert by_name["dataset.range"].attrs == {"fields": 3}
     build = by_name["knn.index.build"].attrs
     assert build["rows"] == TRAIN_ROWS and build["attrs"] == 3
@@ -286,8 +287,9 @@ def test_the_block_route_names_no_phases(files):
     assert [s.name for s in rec.spans()] == []
     with obs.capture() as rec:
         by_path = Dataset.from_csv(files["test"], schema)
+    # the vocabulary is settled before the parse that encodes against it
     assert [s.name for s in rec.spans()] == [
-        "dataset.read", "dataset.parse.native", "dataset.encode",
+        "dataset.read", "dataset.encode", "dataset.parse.native",
         "dataset.range", "dataset.parse"]
     assert len(by_block) == len(by_path) == TEST_ROWS
     np.testing.assert_array_equal(by_block.labels(), by_path.labels())

@@ -177,12 +177,13 @@ def test_multithreaded_parse_matches_sequential():
 
 def test_fuzz_native_matches_python_parser():
     """Differential fuzz: random CSVs with whitespace, blank lines, short
-    rows, negatives, exponent floats, and empty numeric fields must parse
-    identically through the native and python engines."""
+    rows, negatives, exponent floats, empty numeric fields and two
+    categoricals of undeclared vocabulary must parse identically through
+    the native and python engines."""
     from avenir_tpu.core.dataset import Dataset
     from avenir_tpu.core.schema import FeatureSchema
 
-    schema = FeatureSchema.from_json({"fields": [
+    schema = {"fields": [
         {"name": "id", "ordinal": 0, "dataType": "string", "id": True},
         {"name": "a", "ordinal": 1, "dataType": "double", "feature": True,
          "min": -100, "max": 100},
@@ -192,9 +193,18 @@ def test_fuzz_native_matches_python_parser():
          "min": -100, "max": 100},
         {"name": "cls", "ordinal": 4, "dataType": "categorical",
          "class": True, "cardinality": ["neg", "pos"]},
-    ]})
+        # no cardinality: discovered from the data, and grown by every
+        # later trial parsed against the same schema object
+        {"name": "u", "ordinal": 5, "dataType": "categorical",
+         "feature": True},
+        {"name": "v", "ordinal": 6, "dataType": "categorical",
+         "feature": True},
+    ]}
+    nat_schema = FeatureSchema.from_json(schema)
+    py_schema = FeatureSchema.from_json(schema)
     rng = np.random.default_rng(99)
     cats, classes = ["x", "y", "z"], ["neg", "pos"]
+    pool = ["", "a", "b", "ab", "B", "\u00e9t\u00e9", "z z", "0", "-"]
     for trial in range(10):
         lines = []
         for i in range(rng.integers(5, 60)):
@@ -206,19 +216,212 @@ def test_fuzz_native_matches_python_parser():
                 a = ""                              # empty numeric -> NaN
             b = f"{int(rng.integers(-99, 99))}"
             pad = " " * int(rng.integers(0, 3))
-            lines.append(f"{pad}r{i},{a},{pad}{cats[rng.integers(0,3)]}"
-                         f"{pad},{b},{classes[rng.integers(0,2)]}")
+            line = (f"{pad}r{i},{a},{pad}{cats[rng.integers(0,3)]}"
+                    f"{pad},{b},{classes[rng.integers(0,2)]}")
+            # the undeclared pair: drawn from a pool that widens with the
+            # trials, padded, and cut off in some rows (a short row is
+            # the empty token there, never an error)
+            width = 2 + trial
+            cut = rng.random()
+            if cut > 0.1:
+                line += f",{pad}{pool[rng.integers(0, width) % len(pool)]}"
+            if cut > 0.2:
+                line += f",{pool[rng.integers(0, width) % len(pool)]}{pad}"
+            lines.append(line)
             if rng.random() < 0.15:
                 lines.append("")                    # blank line
         text = "\n".join(lines) + "\n"
-        nat = Dataset.from_csv(text, schema, engine="native")
-        py = Dataset.from_csv(text, schema, engine="python")
+        nat = Dataset.from_csv(text, nat_schema, engine="native")
+        py = Dataset.from_csv(text, py_schema, engine="python")
         assert len(nat) == len(py)
+        for name in ("u", "v"):
+            assert (nat_schema.field_by_name(name).cardinality
+                    == py_schema.field_by_name(name).cardinality)
         for o in (1, 3):
             np.testing.assert_array_equal(np.isnan(nat.column(o)),
                                           np.isnan(py.column(o)))
             m = ~np.isnan(py.column(o))
             np.testing.assert_allclose(nat.column(o)[m], py.column(o)[m],
                                        rtol=1e-6)
-        for o in (2, 4):
+        for o in (2, 4, 5, 6):
+            assert nat.column(o).dtype == np.int32
             np.testing.assert_array_equal(nat.column(o), py.column(o))
+
+
+# --------------------------------------------------------------------------
+# categoricals whose vocabulary is discovered from the data
+# --------------------------------------------------------------------------
+def _discovering_schema(extra=()):
+    from avenir_tpu.core.schema import FeatureSchema
+
+    return FeatureSchema.from_json({"fields": [
+        {"name": "x", "ordinal": 0, "dataType": "double", "feature": True},
+        {"name": "status", "ordinal": 1, "dataType": "categorical"},
+        *extra]})
+
+
+_SECOND = ({"name": "grade", "ordinal": 3, "dataType": "categorical",
+            "feature": True},)
+
+#: name -> (further schema fields, the splits parsed one after another
+#: against one schema object, the vocabulary of `status` after the last)
+DISCOVERY_CASES = {
+    "first_discovery": ((), ["1,pass\n2,fail\n3,pass\n"], ["fail", "pass"]),
+    "later_split_extends": (
+        (), ["1,pass\n2,fail\n", "3,pass\n", "4,zeta\n5,fail\n6,hold\n7,alpha\n"],
+        ["fail", "pass", "alpha", "hold", "zeta"]),
+    "short_rows": ((), ["1,pass\n2\n3,fail\n", "4\n"], ["", "fail", "pass"]),
+    "short_row_in_a_later_split": (
+        (), ["1,pass\n", "2\n3,fail\n"], ["pass", "", "fail"]),
+    "crlf_and_padding": (
+        (), ["1, pass \r\n2,\tfail\r\n\r\n  \n3,pass\t \r\n4,fail"],
+        ["fail", "pass"]),
+    "non_ascii": (
+        (), ["1,r\u00e9ussi\n2,\u00e9chec\n3,z\n4,\u65e5\u672c\n5,r\u00e9ussi\n"],
+        ["r\u00e9ussi", "z", "\u00e9chec", "\u65e5\u672c"]),
+    "two_undeclared_fields": (
+        _SECOND, ["1,pass,-,b\n2,fail,-,a\n3,pass,-\n", "4,hold,-,c\n"],
+        ["fail", "pass", "hold"]),
+    "zero_rows": ((), ["", "\n \n", "1,pass\n"], ["pass"]),
+    "many_values": (
+        (), ["".join(f"{i},v{(i * 7919) % 200_000}\n" for i in range(200_000)),
+             "1,v7\n2,w\n"],
+        sorted(f"v{i}" for i in range(200_000)) + ["w"]),
+}
+
+
+def _as_source(route, text, tmp_path, i):
+    if route == "bytes":                # the block route (core/stream.py)
+        return text.encode()
+    if route == "path" and text:
+        p = tmp_path / f"split{i}.csv"
+        p.write_bytes(text.encode())
+        return str(p)
+    return text
+
+
+@pytest.mark.parametrize("route", ["text", "bytes", "path"])
+@pytest.mark.parametrize("case", sorted(DISCOVERY_CASES))
+def test_discovered_categorical_matches_python_engine(case, route, tmp_path):
+    """The contract of a discovered vocabulary, stated by the python engine
+    and kept by the native one on the same bytes: int32 codes, sorted on
+    first discovery, known codes stable and new values appended sorted in
+    a later split, a short row the empty token, whatever the route."""
+    extra, splits, want = DISCOVERY_CASES[case]
+    nat_schema, py_schema = _discovering_schema(extra), _discovering_schema(extra)
+    cat_fields = ["status"] + [f["name"] for f in extra]
+    for i, text in enumerate(splits):
+        nat = Dataset.from_csv(_as_source(route, text, tmp_path, i),
+                               nat_schema, engine="native")
+        py = Dataset.from_csv(text, py_schema, engine="python")
+        assert len(nat) == len(py)
+        for name in cat_fields:
+            nf, pf = nat_schema.field_by_name(name), py_schema.field_by_name(name)
+            assert nf.cardinality == pf.cardinality
+            assert nf.discovered_cardinality and pf.discovered_cardinality
+            col = nat.column(nf.ordinal)
+            assert col.dtype == np.int32 and col.shape == (len(py),)
+            np.testing.assert_array_equal(col, py.column(pf.ordinal))
+    assert nat_schema.field_by_name("status").cardinality == want
+
+
+@pytest.fixture(scope="module")
+def striped_blob():
+    """17 MB, so that up to four stripes of 4 MB are cut: an undeclared
+    column of 23 values at the last ordinal, every 1,000th row short."""
+    rows = []
+    for i in range(800_000):
+        rows.append(f"id{i:07d},{i % 977}.5" if i % 1000 == 999
+                    else f"id{i:07d},{i % 977}.5,tok{(i * 31) % 23}")
+    blob = ("\n".join(rows) + "\n").encode()
+    assert len(blob) > 16 * (1 << 20)
+    return blob
+
+
+@pytest.mark.parametrize("threads", [1, 2, 3, 4, 0])
+def test_distinct_column_is_the_same_from_one_thread_or_many_stripes(
+        striped_blob, threads):
+    from avenir_tpu.native.ingest import distinct_column_native
+
+    tokens, rows = distinct_column_native(striped_blob, ",", 2, threads=threads)
+    assert rows == 800_000
+    assert len(tokens) == 24            # no value twice, whatever the stripe
+    assert sorted(tokens) == sorted([""] + [f"tok{i}" for i in range(23)])
+
+
+@pytest.mark.parametrize("threads", [1, 4])
+def test_discovered_column_parses_the_same_striped(striped_blob, threads):
+    from avenir_tpu.native.ingest import parse_csv_native
+
+    vocab = sorted([""] + [f"tok{i}" for i in range(23)])
+    n, cols, _ = parse_csv_native(striped_blob, ",", [1], [(2, vocab)], [],
+                                  threads=threads)
+    assert n == 800_000
+    i = np.arange(n)
+    want = np.array([vocab.index(f"tok{k}") for k in range(23)])[(i * 31) % 23]
+    want[i % 1000 == 999] = vocab.index("")
+    np.testing.assert_array_equal(cols[2], want)
+
+
+def test_no_python_string_per_row_for_a_discovered_field(monkeypatch):
+    """The discovered field goes to the parser as a categorical with its
+    vocabulary complete, never as a string column: no thunk over its
+    tokens comes back, and the dataset holds int32 codes only."""
+    from avenir_tpu.native import ingest
+
+    seen = {}
+    real = ingest.parse_csv_native
+
+    def spy(data, delim, numeric, categorical, strings, **kw):
+        out = real(data, delim, numeric, categorical, strings, **kw)
+        seen.update(categorical=categorical, strings=strings, lazy=out[2])
+        return out
+
+    monkeypatch.setattr(ingest, "parse_csv_native", spy)
+    from avenir_tpu.core.schema import FeatureSchema
+    schema = FeatureSchema.from_json({"fields": [
+        {"name": "id", "ordinal": 0, "id": True, "dataType": "string"},
+        {"name": "x", "ordinal": 1, "dataType": "double", "feature": True},
+        {"name": "status", "ordinal": 2, "dataType": "categorical"},
+    ]})
+    for text in (b"a,1,pass\nb,2,fail\n", b"c,3,hold\n"):
+        ds = Dataset.from_csv(text, schema, engine="native")
+        assert seen["strings"] == [0]
+        assert seen["categorical"] == [(2, schema.field_by_name("status").cardinality)]
+        assert set(seen["lazy"]) == {0} and set(ds._lazy) == {0}
+        assert ds.columns[2].dtype == np.int32
+    assert schema.field_by_name("status").cardinality == ["fail", "pass", "hold"]
+
+
+@pytest.mark.parametrize("engine", ["native", "python"])
+@pytest.mark.parametrize("text,message", [
+    ("C1,low,med,low,good,50,open\nC2,BOGUS,med,low,good,50,open\n",
+     "value 'BOGUS' not in declared cardinality of field 'minUsed'"),
+    ("C1,low,med\n",
+     "value '' not in declared cardinality of field 'CSCalls'"),
+], ids=["unknown_value", "short_row"])
+def test_declared_categorical_still_raises(text, message, engine):
+    """The declared route keeps its contract and its words: a short row is
+    the empty token, which a declared vocabulary as a rule does not hold."""
+    with pytest.raises(ValueError) as err:
+        Dataset.from_csv(text, churn_schema(), engine=engine)
+    assert str(err.value) == message
+
+
+@pytest.mark.parametrize("engine", ["native", "python"])
+def test_short_row_is_the_empty_token_where_the_vocabulary_declares_it(engine):
+    from avenir_tpu.core.schema import FeatureSchema
+
+    schema = FeatureSchema.from_json({"fields": [
+        {"name": "x", "ordinal": 0, "dataType": "double", "feature": True},
+        {"name": "c", "ordinal": 1, "dataType": "categorical",
+         "feature": True, "cardinality": ["a", "", "b"]}]})
+    ds = Dataset.from_csv("1,b\n2\n3,\n4,a\n", schema, engine=engine)
+    np.testing.assert_array_equal(ds.column(1), [2, 1, 1, 0])
+    assert schema.field_by_name("c").cardinality == ["a", "", "b"]
+
+
+def test_vocabulary_value_with_a_nul_byte_is_refused():
+    with pytest.raises(ValueError, match="NUL"):
+        Dataset.from_csv(b"1,a\x00b\n2,c\n", _discovering_schema(),
+                         engine="native")

@@ -373,3 +373,55 @@ def test_warmstore_eviction_keeps_byte_identity(tmp_path):
     assert _bytes_of(repack) == _bytes_of(cold)
     assert _sc(repack, "HitBlocks") == 0 and _sc(repack, "DeltaBlocks") >= 1
     store.close()
+
+
+# ------------------------------- 7. a categorical of discovered vocabulary
+#: what a sidecar on disk holds for the block below, written out by hand:
+#: the float32 page of `x`, then the `t` column, the native parser's
+#: newline-joined trimmed tokens with a short row as the empty token
+_DISCOVERED_BLOCK = b"1,pass\n2, fail \n3\n4,pass\r\n5,alpha\n"
+_DISCOVERED_TOKENS = b"pass\nfail\n\npass\nalpha\n"
+_DISCOVERED_ENTRY = {"rows": 5, "cols": [[0, "f", 0, 20], [1, "t", 0, 22]]}
+
+
+def _status_schema():
+    from avenir_tpu.core.schema import FeatureSchema
+
+    return FeatureSchema.from_json({"fields": [
+        {"name": "x", "ordinal": 0, "dataType": "double", "feature": True},
+        {"name": "status", "ordinal": 1, "dataType": "categorical"}]})
+
+
+@pytest.mark.parametrize("segment", ["packed_now", "as_on_disk_today"])
+def test_discovered_categorical_packs_tokens_and_replays_codes(segment):
+    """The parser encodes a discovered categorical itself now; the sidecar
+    keeps its format all the same (kind `t`, the raw column bytes), and a
+    replay against a fresh schema discovers the vocabulary the cold parse
+    did and gives its codes."""
+    import io
+
+    from avenir_tpu.core.dataset import Dataset
+
+    cold_schema = _status_schema()
+    cold = Dataset.from_csv(_DISCOVERED_BLOCK, cold_schema, engine="native")
+    x_page = np.arange(1, 6, dtype=np.float32).tobytes()
+    if segment == "packed_now":
+        fh = io.BytesIO()
+        cols = sidecar._pack_dataset_block(_DISCOVERED_BLOCK, cold,
+                                           cold_schema, ",", fh)
+        assert {"rows": len(cold), "cols": cols} == _DISCOVERED_ENTRY
+        buf = fh.getvalue()
+        assert buf == x_page + _DISCOVERED_TOKENS
+    else:
+        buf = x_page + _DISCOVERED_TOKENS
+    warm_schema = _status_schema()
+    warm = sidecar._unpack_dataset_block(buf, _DISCOVERED_ENTRY,
+                                         warm_schema, ",")
+    status = warm_schema.field_by_name("status")
+    assert status.cardinality == ["", "alpha", "fail", "pass"] \
+        == cold_schema.field_by_name("status").cardinality
+    assert status.discovered_cardinality
+    assert warm.column(1).dtype == np.int32
+    np.testing.assert_array_equal(warm.column(1), [3, 2, 0, 3, 1])
+    np.testing.assert_array_equal(warm.column(1), cold.column(1))
+    np.testing.assert_array_equal(warm.column(0), cold.column(0))
