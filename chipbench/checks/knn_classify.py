@@ -34,6 +34,8 @@ from chipbench import compare, generate
 from chipbench import reference as ref_impl
 
 _TRUE = ("true", "yes", "1")
+#: the numbers of which the lower-precision control has to fail one
+CONTROL_FAILS = ("share_gap_max", "class_flips")
 
 
 def reference_of(properties: Dict[str, str]) -> Dict:

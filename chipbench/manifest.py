@@ -4,10 +4,12 @@ Whatever belongs to one configuration, one traffic mix or one per-layer
 metric is a file of its own, found by the name in `BENCHMARK.json`:
 `configs/<config>.json`, `traffic/<traffic>.json`, `metrics/<metric>.json`.
 The code such a file calls for is found by the name it gives, too: the
-generator a configuration names, `generators/<kind>.py`; its check,
-`checks/<kind>.py`; the loop a mix names, `loops/<loop>.py`; the reader a
-metric names, `readers/<reader>.py`. Adding a cell, a mix or a metric is
-adding files and one entry; no file here is edited.
+input module a configuration names, `inputs/<inputs_kind>.py` (one that
+names none gets `train_test_csv`); its generator, `generators/<kind>.py`;
+its check, `checks/<kind>.py`; the loop a mix names, `loops/<loop>.py`;
+the reader a metric names, `readers/<reader>.py`. Adding a cell, a mix, a
+metric or a deployment of another job family is adding files and
+entries; no file here is edited.
 """
 
 from __future__ import annotations
@@ -21,6 +23,7 @@ from chipbench import generate
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+DEFAULT_INPUTS = "train_test_csv"    # a train file and the mix's test files
 
 
 def _load(path: str) -> Dict:
@@ -60,9 +63,16 @@ class Manifest:
         return _load(self.path("metrics", name))
 
     def module(self, folder: str, name: str):
-        """`<folder>/<name>.py` of the benchmark: `generators/`, `loops/`,
-        `checks/` and `readers/` are found this way."""
+        """`<folder>/<name>.py` of the benchmark: `inputs/`, `generators/`,
+        `loops/`, `checks/` and `readers/` are found this way."""
         return generate.load_module(self.bench_dir, folder, name)
+
+    def inputs(self, config: Dict):
+        """The module that makes what the configuration's job reads: its
+        `Inputs(cell, seed, work)` writes every input file from the seed
+        and gives `n_files`, `out_suffix`, `argv(file_no, out)` and
+        `warmup_argv(out)`."""
+        return self.module("inputs", config.get("inputs_kind", DEFAULT_INPUTS))
 
     def reader(self, name: str) -> Callable:
         """The `read(ctx, params)` of `readers/<name>.py`."""

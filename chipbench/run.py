@@ -4,22 +4,27 @@
 
 One process, on the machine it is started on. It finds the cell's
 configuration, traffic and metric files by the names in `BENCHMARK.json`
-(`manifest.py`), and by the names those files give the generator, the
-loop, the check and the readers (`generators/`, `loops/`, `checks/`,
-`readers/`). It makes every input from `--seed`, runs one whole job of the
-cell as the warm-up (that is what compiles), then drives the program's own
-entry, `avenir_tpu.runner.run_from_cli`, in the mix's loop for `--seconds`
+(`manifest.py`), and by the names those files give the input module, the
+generator, the loop, the check and the readers (`inputs/`, `generators/`,
+`loops/`, `checks/`, `readers/`). The configuration's input module makes
+every input from `--seed` and says what a job's arguments are; the
+harness runs one whole job of the cell as the warm-up (that is what
+compiles), then drives the program's own entry,
+`avenir_tpu.runner.run_from_cli`, in the mix's loop for `--seconds`
 seconds: with `closed`, one client, a whole job from input files to the
-complete output file, again and again, each job on the next of the cell's
-test files; a job that has started when the time runs out is finished and
-counted. With `--trace 1` the window is exactly one job, under
-`jax.profiler` and `obs.capture()`.
+complete output file, again and again, each job on the next of the
+cell's input files; a job that has started when the time runs out is
+finished and counted. With `--trace 1` the window is exactly one job,
+under `jax.profiler` and `obs.capture()`.
 
 When the window has closed and the peak memory has been read, the output
 files the timed jobs wrote are compared with the plain reference by the
-configuration's check (`checks/knn_classify.py`, `reference.py`). The last line of standard output is the
-result; without an accelerator, or with fewer chips than the cell asks
-for, there is no result and the exit code is not 0.
+check the configuration names (`checks/<reference.kind>.py`). Nothing
+here knows a job family: which files a job reads, what it writes and
+what is compared are the input module's and the check's. The last line
+of standard output is the result; without an accelerator, or with fewer
+chips than the cell asks for, there is no result and the exit code is
+not 0.
 """
 
 from __future__ import annotations
@@ -44,8 +49,7 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from chipbench import (compare, generate, manifest, reduce,  # noqa: E402
-                       xtrace)
+from chipbench import compare, manifest, reduce, xtrace  # noqa: E402
 
 WORK_DIR = ".chipbench_work"         # inside the checkout, git-ignored
 JOB_ANNOTATION = "chipbench.job"
@@ -141,59 +145,6 @@ def fullest_chip(readings: List[List[Dict]]) -> Dict[str, int]:
     return max(chips, key=lambda c: c["peak"])
 
 
-class Inputs:
-    """Everything one run feeds the program, made from the seed: the train
-    file by the configuration's generator, the mix's test files by the
-    same, the schema and the properties."""
-
-    def __init__(self, cell: manifest.Cell, seed: int, work: str):
-        cfg, mix = cell.config, cell.traffic
-        gen = cfg["generator"]
-        fields = generate.feature_fields(cfg["schema"])
-        self.classes = list(gen["classes"])
-        self.prefix = gen["id_prefix"]
-        self.work = work
-        self.train_path = os.path.join(work, "train.csv")
-        self.train = generate.make_csv(
-            self.train_path, seed, 0, int(cfg["train_rows"]), gen, fields, 0,
-            cell.bench_dir)
-        self.tests: List[generate.Rows] = []
-        self.test_paths: List[str] = []
-        for j, rows in enumerate(generate.file_rows(mix)):
-            path = os.path.join(work, f"test_{j:02d}.csv")
-            self.tests.append(generate.make_csv(
-                path, seed, 1 + j, rows, gen, fields,
-                int(gen["test_id_start"]) + j * int(gen["test_id_stride"]),
-                cell.bench_dir))
-            self.test_paths.append(path)
-        self.schema_path = os.path.join(work, "schema.json")
-        with open(self.schema_path, "w") as fh:
-            json.dump(cfg["schema"], fh)
-        self.props_path = os.path.join(work, "job.properties")
-        with open(self.props_path, "w") as fh:
-            for key, val in cfg["properties"].items():
-                fh.write(f"{key}={val.format(schema=self.schema_path)}\n")
-        self.job = cfg["job"]
-        self.input_slots = list(cfg["inputs"])
-
-    def argv(self, file_no: int, out: str) -> List[str]:
-        return self._argv(self.test_paths[file_no], out)
-
-    def warmup_argv(self, out: str) -> List[str]:
-        """The warm-up job's arguments: test file 0 under another name, so
-        that what the program caches beside a test file (its columnar
-        sidecar) does not make the window's first job differ from the
-        rest, while the two outputs stay comparable byte for byte."""
-        twin = os.path.join(self.work, "test_warmup.csv")
-        shutil.copyfile(self.test_paths[0], twin)
-        return self._argv(twin, out)
-
-    def _argv(self, test_path: str, out: str) -> List[str]:
-        paths = [slot.format(train=self.train_path, test=test_path)
-                 for slot in self.input_slots]
-        return [self.job, "--conf", self.props_path, *paths, out]
-
-
 def default_entry(argv: List[str]) -> None:
     """The program's own entry, its stdout line sent to stderr so that the
     result stays the last line of standard output."""
@@ -213,18 +164,21 @@ def run_cell(cell: manifest.Cell, man: manifest.Manifest, seed: int,
     object. `entry` is what the window drives (a test puts a broken one in
     its place); `device` is what `look_for_chip` found; `environment` what
     `apply_environment` applied. The loop is the one the mix names
-    (`loops/<loop>.py`), the check the one the configuration names
-    (`checks/<reference.kind>.py`)."""
+    (`loops/<loop>.py`); the input module and the check are the ones the
+    configuration names (`inputs/<inputs_kind>.py`,
+    `checks/<reference.kind>.py`). Of the inputs the harness uses
+    `n_files`, `out_suffix`, `argv(file_no, out)` and `warmup_argv(out)`;
+    the rest is between the input module and the check."""
     loop = man.module("loops", cell.traffic["loop"])
     check = man.module("checks", cell.config["reference"]["kind"])
     work = os.path.join(work_root, cell.name)
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)
     t_in = time.perf_counter()
-    inputs = Inputs(cell, seed, work)
-    n_files = len(inputs.test_paths)
+    inputs = man.inputs(cell.config).Inputs(cell, seed, work)
+    n_files = inputs.n_files
     t_warm = time.perf_counter()
-    warm_out = os.path.join(work, "out_warmup.csv")
+    warm_out = os.path.join(work, "out_warmup" + inputs.out_suffix)
     entry(inputs.warmup_argv(warm_out))
     setup_s = time.perf_counter() - t0
     parts = {"before_inputs_s": t_in - t0, "inputs_s": t_warm - t_in,
@@ -236,7 +190,7 @@ def run_cell(cell: manifest.Cell, man: manifest.Manifest, seed: int,
     def one_job(i: int) -> None:
         memory.append(memory_readings())
         file_no = i % n_files
-        out = os.path.join(work, f"out_{i:03d}.csv")
+        out = os.path.join(work, f"out_{i:03d}{inputs.out_suffix}")
         t = time.perf_counter()
         try:
             entry(inputs.argv(file_no, out))
@@ -285,8 +239,8 @@ def run_cell(cell: manifest.Cell, man: manifest.Manifest, seed: int,
     result["job_seconds"] = [j["seconds"] for j in jobs]
     result["checked"] = {
         r["name"]: {"value": r["value"], "limit": r["limit"]} for r in rows}
-    result["checked"]["_seen"] = {k: numbers[k] for k in ("ties", "sampled")
-                                  if k in numbers}
+    result["checked"]["_seen"] = {k: v for k, v in numbers.items()
+                                  if k not in result["checked"]}
     return result
 
 

@@ -3,6 +3,7 @@ to a size a CPU test can hold. Nothing here describes a TPU topology or
 loads libtpu; every test runs on the CPU backend `tests/conftest.py` pins.
 """
 
+import contextlib
 import json
 import os
 import shutil
@@ -45,3 +46,36 @@ def small_copy(tmp: str, train_rows: int = 2048, test_rows: int = 256,
         with open(path, "w") as fh:
             json.dump(doc, fh)
     return manifest.Manifest(tmp, bench)
+
+
+def file_stamps(folder: str) -> dict:
+    """mtime and size of every file under `folder`: what a test that adds
+    files holds the files that were there to."""
+    out = {}
+    for sub, _dirs, files in os.walk(folder):
+        for f in files:
+            p = os.path.join(sub, f)
+            out[p] = os.path.getmtime(p), os.path.getsize(p)
+    return out
+
+
+@contextlib.contextmanager
+def compile_cache_off():
+    """The persistent compile cache off while jobs run in a test, and the
+    worker's cache directory put back afterwards (the jobs' own device rule
+    places it): what this worker would otherwise write into the checkout's
+    `.jax_cache`, now or in a later test file, is what
+    `tests/test_devices.py` watches for in another worker."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = (jax.config.jax_enable_compilation_cache,
+           jax.config.jax_compilation_cache_dir)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was[0])
+        jax.config.update("jax_compilation_cache_dir", was[1])
+        compilation_cache.reset_cache()

@@ -4,29 +4,23 @@ are added as files plus one entry."""
 
 import json
 import os
-import re
 import shutil
 
 import pytest
 
-from bench_fixtures import CPU_DEVICE, ROOT, small_copy
+import pins
+from bench_fixtures import (CPU_DEVICE, ROOT, compile_cache_off, file_stamps,
+                            small_copy)
+from pins import NAME, PATH, UNIT, VMEM, UNTIL, one_line
 
-from chipbench import generate, manifest, run
+from chipbench import compare, generate, manifest, run
 
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
-SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
-
+HERE = os.path.dirname(os.path.abspath(__file__))
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _fh:
     DOC = json.load(_fh)
 CELLS = [w["name"] for w in DOC["workloads"]]
 LAYER_METRICS = [m["name"] for m in DOC["per_layer"]]
 E2E = {m["name"]: m for m in DOC["end_to_end"]}
-
-
-def one_line(text, most=200):
-    return 1 <= len(text) <= most and "\n" not in text and "\t" not in text
 
 
 def test_top_level_keys_and_command():
@@ -49,34 +43,9 @@ def test_run_seconds_fits_a_full_check_of_24_cells():
 
 @pytest.mark.parametrize("cfg", DOC["configs"], ids=lambda c: c["name"])
 def test_configuration_entry_and_file(cfg):
-    assert set(cfg) == {"name", "source", "file", "reduced", "why"}
-    assert NAME.match(cfg["name"]) and one_line(cfg["source"]) and one_line(cfg["why"])
-    assert cfg["file"].startswith("chipbench/") and PATH.match(cfg["file"])
-    assert len(cfg["reduced"]) <= 16 and all(NAME.match(k) for k in cfg["reduced"])
-    assert any(w["config"] == cfg["name"] for w in DOC["workloads"])
-    with open(os.path.join(ROOT, cfg["file"])) as fh:
-        doc = json.load(fh)
-    assert doc["name"] == cfg["name"] and doc["source"] == cfg["source"]
-    # the shapes the deployment fixes, and the guarantees it states
-    # upstream elearnActivity.json as the repo records it
-    # (tests/test_reference_configs.py): studentID, nine whole-number
-    # activity fields with these maxima, the status class
-    fields = doc["schema"]["entity"]["fields"]
-    feats = generate.feature_fields(doc["schema"])
-    assert [f["max"] for f in feats] == [600, 200, 100, 28, 100, 100, 280, 180, 26]
-    assert all(f["min"] == 0 and f["dataType"] == "int" for f in feats)
-    assert fields[0]["id"] and fields[-1]["dataType"] == "categorical"
-    assert len(fields) == 11
-    assert doc["properties"]["nen.top.match.count"] == "5"
-    assert doc["reference"] == {"kind": "knn_classify"}
-    assert os.path.exists(os.path.join(
-        ROOT, "chipbench", "generators", doc["generator"]["kind"] + ".py"))
-    assert os.path.exists(os.path.join(
-        ROOT, "chipbench", "checks", doc["reference"]["kind"] + ".py"))
-    assert set(doc["assumed"]) >= {"train_rows", "generator", "schema"}
-    assert doc["precision"] == "float32" and len(doc["guarantees"]) >= 3
-    assert doc["train_rows"] % 8192 == 0
-    assert set(doc["check"]["limits"]) >= {"lines_bad", "share_gap_max", "class_flips"}
+    """What every configuration of any job family is held to, and under
+    the e-learning deployment's names the shapes it fixes (`pins.py`)."""
+    pins.hold_configuration(manifest.Manifest(), cfg)
 
 
 def test_configurations_do_not_share_a_file_or_a_source():
@@ -87,20 +56,29 @@ def test_configurations_do_not_share_a_file_or_a_source():
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_files_are_found_by_name(cell):
-    man = manifest.Manifest()
     entry = next(w for w in DOC["workloads"] if w["name"] == cell)
-    assert set(entry) == {"name", "config", "traffic", "chips", "why"}
-    assert NAME.match(cell) and NAME.match(entry["traffic"]) and one_line(entry["why"])
-    assert entry["chips"] == 1
-    got = man.cell(cell)
-    assert got.config["name"] == entry["config"]
-    assert os.path.exists(man.path("traffic", entry["traffic"]))
-    assert callable(man.module("loops", got.traffic["loop"]).drive)
-    assert got.traffic["clients"] == 1
-    assert all(r % 256 == 0 for r in generate.file_rows(got.traffic))
-    # every cell reports setup_s, one more end-to-end metric, one per-layer
-    names = [m["name"] for m in got.end_to_end]
-    assert "setup_s" in names and len(names) >= 2 and got.per_layer
+    pins.hold_cell(manifest.Manifest(), entry)
+    assert entry["chips"] == 1      # no path of the job crosses chips (D5)
+
+
+def test_at_most_a_quarter_of_the_cells_ask_for_four_chips():
+    pins.hold_four_chip_share(DOC)
+    four = dict(DOC["workloads"][0], chips=4)
+    pins.hold_four_chip_share({"workloads": [four]})            # one always may
+    pins.hold_four_chip_share({"workloads": [four] * 2 + DOC["workloads"] * 3})
+    with pytest.raises(AssertionError):
+        pins.hold_four_chip_share({"workloads": [four] * 2 + DOC["workloads"]})
+
+
+def test_the_closed_loop_drives_one_client_and_says_so():
+    """`clients` is the loop's to hold, not every cell's: another loop may
+    drive several."""
+    closed = manifest.Manifest().module("loops", "closed")
+    jobs = []
+    assert closed.drive(jobs.append, 0.0, {"clients": 1}) == 1 and jobs == [0]
+    with pytest.raises(ValueError, match="one client"):
+        closed.drive(jobs.append, 0.0, {"clients": 2})
+    assert jobs == [0]
 
 
 def test_no_pair_of_configuration_and_traffic_twice():
@@ -122,24 +100,8 @@ def test_end_to_end_metric(name):
 
 @pytest.mark.parametrize("name", LAYER_METRICS)
 def test_per_layer_metric_has_its_file_reader_and_cells(name):
-    man = manifest.Manifest()
     m = next(x for x in DOC["per_layer"] if x["name"] == name)
-    assert set(m) <= {"name", "unit", "better", "source", "layer", "moves",
-                      "workloads"}
-    assert NAME.match(name) and UNIT.match(m["unit"]) and one_line(m["layer"])
-    assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
-    spec = man.metric(name)
-    assert spec["name"] == name and spec["unit"] == m["unit"]
-    assert callable(man.reader(spec["reader"]))
-    # the one end-to-end metric it moves is reported in each of its cells
-    assert m["moves"] in E2E
-    for cell in m.get("workloads", CELLS):
-        assert cell in CELLS
-        moved = E2E[m["moves"]]
-        assert cell in moved.get("workloads", CELLS)
-        assert name in [x["name"] for x in man.cell(cell).per_layer]
-    if name.endswith("_roofline") or "mfu" in name:
-        assert m["unit"] == "%" and m["better"] == "higher"
+    pins.hold_per_layer_metric(manifest.Manifest(), m)
 
 
 def test_layers_are_spelled_one_way():
@@ -159,25 +121,11 @@ def test_files_under_paths_are_named_from_a_names_characters():
 
 
 # ------------------------------------------------- the runtime's one setting
-VMEM = "--xla_tpu_scoped_vmem_limit_kib=32768"
-UNTIL = {"file": "avenir_tpu/ops/pallas_knn.py", "function": "knn_topk_pallas",
-         "keyword": "vmem_limit_bytes"}
-
-
 @pytest.mark.parametrize("cfg", DOC["configs"], ids=lambda c: c["name"])
 def test_a_configuration_states_only_the_one_runtime_flag(cfg):
-    with open(os.path.join(ROOT, cfg["file"])) as fh:
-        doc = json.load(fh)
-    assert doc["environment"] == {"LIBTPU_INIT_ARGS": VMEM}
-    assert doc["environment_until"] == UNTIL and doc["environment_why"]
-    env = {}
-    # today the program's exact kernel states no limit of its own, so the
-    # flag is applied, and echoed for the result line
-    assert run.apply_environment(doc, ROOT, env) == {"LIBTPU_INIT_ARGS": VMEM}
-    assert env == {"LIBTPU_INIT_ARGS": VMEM}
-    env = {"LIBTPU_INIT_ARGS": "--other=1"}
-    run.apply_environment(doc, ROOT, env)
-    assert env["LIBTPU_INIT_ARGS"] == "--other=1 " + VMEM
+    """None, or what `run.ALLOWED_ENVIRONMENT` allows; the e-learning
+    deployment its one flag (`pins.hold_environment`)."""
+    pins.hold_environment(manifest.Manifest(), cfg, ROOT)
 
 
 @pytest.mark.parametrize("key, val", [
@@ -220,16 +168,9 @@ def test_a_cell_a_mix_and_a_metric_are_added_as_files_plus_one_entry(tmp_path):
     was there edited. The harness finds them by name and runs the new
     cell: another k, test files of three sizes, a loop, a generator and a
     check of its own."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache
-
     man = small_copy(str(tmp_path))
     bench = man.bench_dir
-    before = {}
-    for folder, _d, files in os.walk(bench):
-        for f in files:
-            p = os.path.join(folder, f)
-            before[p] = os.path.getmtime(p), os.path.getsize(p)
+    before = file_stamps(bench)
 
     def add(folder, name, content):
         with open(os.path.join(bench, folder, name), "w") as fh:
@@ -293,17 +234,9 @@ def test_a_cell_a_mix_and_a_metric_are_added_as_files_plus_one_entry(tmp_path):
 
     # and the harness runs it, on the CPU: two jobs of 256 and 512 rows
     # with nine neighbours each, held to the reference for k = 9
-    was = (jax.config.jax_enable_compilation_cache,
-           jax.config.jax_compilation_cache_dir)
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with compile_cache_off():
         res = run.run_cell(cell, again, 41, 0.0, False, dict(CPU_DEVICE),
                            work_root=os.path.join(str(tmp_path), "work"))
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was[0])
-        jax.config.update("jax_compilation_cache_dir", was[1])
-        compilation_cache.reset_cache()
     assert res["correct"] is True, res["checked"]
     assert res["attempted"] == 2 and res["failed"] == 0
     assert res["checked"]["_seen"]["sampled"] == 256
@@ -317,3 +250,190 @@ def test_a_cell_a_mix_and_a_metric_are_added_as_files_plus_one_entry(tmp_path):
     for p, stamp in before.items():
         assert (os.path.getmtime(p), os.path.getsize(p)) == stamp, p
     shutil.rmtree(str(tmp_path), ignore_errors=True)
+
+
+FAMILY = os.path.join(HERE, "data", "family_dectree")
+
+
+def test_a_deployment_of_another_job_family_is_added_as_files_and_entries(tmp_path):
+    """What the next `model_config` PR does, with the files kept under
+    `data/family_dectree/`: a `decTree` job on a schema of three
+    categorical features and one numeric (the shape of call_hangup.json),
+    one input and a model file for output. It brings its input module (one
+    CSV, no test files), generator, check, loop, mix and span metric, adds
+    its entries, joins a metric that was there, and edits nothing. The
+    harness runs it, and every pin on configurations, cells and metrics
+    holds for what was added as for what was there."""
+    from avenir_tpu import obs
+    from avenir_tpu.models.tree import DecisionPathList
+
+    # 8,192 train rows: the e-learning pins want whole kernel blocks
+    man = small_copy(str(tmp_path), train_rows=8192)
+    bench = man.bench_dir
+    before = file_stamps(bench)
+
+    added = []
+    for folder in sorted(os.listdir(FAMILY)):
+        if os.path.isdir(os.path.join(FAMILY, folder)):
+            os.makedirs(os.path.join(bench, folder), exist_ok=True)
+            for f in os.listdir(os.path.join(FAMILY, folder)):
+                dst = os.path.join(bench, folder, f)
+                assert dst not in before, dst
+                shutil.copy(os.path.join(FAMILY, folder, f), dst)
+                added.append(os.path.join(folder, f))
+    assert {os.path.dirname(a) for a in added} == {
+        "configs", "inputs", "generators", "checks", "loops", "traffic",
+        "metrics"}
+    with open(os.path.join(FAMILY, "entries.json")) as fh:
+        entries = json.load(fh)
+    doc = json.loads(json.dumps(man.doc))
+    for key in ("configs", "workloads", "per_layer"):
+        doc[key] += entries[key]
+    for metric, cell_name in entries["joins"].items():
+        next(m for m in doc["per_layer"]
+             if m["name"] == metric)["workloads"].append(cell_name)
+    with open(os.path.join(man.root, "BENCHMARK.json"), "w") as fh:
+        json.dump(doc, fh)
+
+    again = manifest.Manifest(man.root, bench)
+    cell = again.cell("dectree-hangup-tiny.rebuild")
+    kinds = [f["dataType"] for f in cell.config["schema"]["fields"]
+             if f.get("feature")]
+    assert kinds.count("categorical") >= 2 and "int" in kinds
+    assert [m["name"] for m in cell.per_layer] == [
+        "train_parse_ms_per_job", "tree_parse_ms_per_job"]
+
+    # every pin, over the copy's entries: the new ones and the old
+    for cfg in doc["configs"]:
+        pins.hold_configuration(again, cfg)
+        pins.hold_environment(again, cfg, ROOT)
+    for entry in doc["workloads"]:
+        pins.hold_cell(again, entry)
+    pins.hold_four_chip_share(doc)
+    for m in doc["per_layer"]:
+        pins.hold_per_layer_metric(again, m)
+    for name in pins.SPAN_EIGHT:
+        pins.hold_span_metric_entry(doc, name)
+    pins.hold_the_first_sixteen(doc)
+    assert len({c["source"] for c in doc["configs"]}) == len(doc["configs"])
+
+    # the harness runs it on the CPU: two tree builds from one input
+    spans = []
+
+    def entry_with_spans(argv):
+        with obs.capture() as rec:
+            run.default_entry(argv)
+        spans.append([{"name": sp.name, "t0": sp.t0, "dur": sp.dur}
+                      for sp in rec.spans()])
+
+    work_root = os.path.join(str(tmp_path), "work")
+    with compile_cache_off():
+        res = run.run_cell(cell, again, 2**31 + 27, 0.0, False,
+                           dict(CPU_DEVICE), entry=entry_with_spans,
+                           work_root=work_root)
+        # a tree built from half the rows fails the exact number
+        cut = run.run_cell(cell, again, 7, 0.0, False, dict(CPU_DEVICE),
+                           entry=half_the_rows, work_root=work_root + "_cut")
+    assert cut["correct"] is False and cut["checked"]["population_gap"]["value"] > 0
+    assert res["correct"] is True, res["checked"]
+    assert res["attempted"] == 2 and res["failed"] == 0
+    assert set(res["checked"]) == {"model_bad", "unstable_bytes",
+                                   "population_gap", "class_share_gap_max",
+                                   "_seen"}
+    assert res["checked"]["_seen"]["paths"] > 1
+    work = os.path.join(str(tmp_path), "work", cell.name)
+    assert sorted(os.listdir(work)) == [
+        "job.properties", "out_000.json", "out_001.json", "out_warmup.json",
+        "schema.json", "train.csv"]
+    model = DecisionPathList.load(os.path.join(work, "out_001.json"))
+    assert len(model.paths) == res["checked"]["_seen"]["paths"]
+    with open(os.path.join(work, "out_000.json"), "rb") as a, \
+            open(os.path.join(work, "out_001.json"), "rb") as b:
+        assert a.read() == b.read()
+    with open(os.path.join(work, "train.csv")) as fh:
+        row = fh.readline().rstrip("\n").split(",")
+    assert len(row) == 6 and row[1] in ("business", "residence") \
+        and row[3] in ("AM", "PM") and row[4].isdigit() and row[5] in "FT"
+
+    # its control, one precision lower, comes out as not correct
+    check = again.module("checks", "dectree_paths")
+    limits = cell.config["check"]["limits"]
+    good, _ = compare.verdict(check.control_numbers(cell, 5, 2, "float32"), limits)
+    assert good
+    low = check.control_numbers(cell, 5, 2, "bfloat16")
+    good, _ = compare.verdict(low, limits)
+    assert not good and low["class_share_gap_max"] > 100 * limits["class_share_gap_max"]
+
+    # both metrics read the job's own spans: the one it brought, and the
+    # one that was there, whose list the new cell joined
+    ctx = {"jobs": 1, "spans": spans[-1]}
+    for m in cell.per_layer:
+        spec = again.metric(m["name"])
+        assert again.reader(spec["reader"])(ctx, spec["params"]) > 0.0
+    assert "tree_parse_ms_per_job" not in [
+        m["name"] for m in again.cell(CELLS[0]).per_layer]
+
+    assert not set(added) & {os.path.relpath(p, bench) for p in before}
+    for p, stamp in before.items():
+        assert (os.path.getmtime(p), os.path.getsize(p)) == stamp, p
+    shutil.rmtree(str(tmp_path), ignore_errors=True)
+
+
+def half_the_rows(argv):
+    """Half of the batch left out: the tree is built from the first half
+    of the train file."""
+    with open(argv[3]) as fh:
+        lines = fh.readlines()
+    half = argv[3] + ".half"
+    with open(half, "w") as fh:
+        fh.writelines(lines[:len(lines) // 2])
+    run.default_entry(argv[:3] + [half] + argv[4:])
+
+
+# ------------------------------------------------- the inputs, byte for byte
+#: sha256 of what PR 26's `run.Inputs` wrote for seed 2**31 + 27 in the
+#: small copy (the work directory's path in job.properties replaced by
+#: `{work}`); the test files are the same in both cells, as every mix is
+#: cut alike there
+PARENT_SHA256 = {
+    "schema.json": "3e377c499e2f4ae290454f564644b1afa6d578c0d16714944bc50b43bfc391d2",
+    "test_00.csv": "32691dc46a34f96ea124dfdecef52815a33f4aadcee28b005e9ef82b0da21fd5",
+    "test_01.csv": "48adeb6f1ceb1a25144eaa3160d554e70f6a358cffa9bdbfc169a742e88906a2",
+    "test_warmup.csv": "32691dc46a34f96ea124dfdecef52815a33f4aadcee28b005e9ef82b0da21fd5",
+    "train.csv": "f77610e5cc6094e9ba130c374a6236a3e5706afc9e39a9f8585545ba20ee2a47",
+}
+PARENT_PROPERTIES_SHA256 = {
+    "knn-elearn.bulk": "5f28cc751ffdf1acf48afbb9f2d0b7df2b24c2ba56677ce89a224a9870cc29eb",
+    "knn-elearn-ccw.adhoc": "b0e3158e0faaf2d2b48bac97ca953d1872fbc1b08bacd5197bcdec44feaaee73",
+    "knn-elearn-ccw.bulk": "b0e3158e0faaf2d2b48bac97ca953d1872fbc1b08bacd5197bcdec44feaaee73",
+}
+
+
+@pytest.mark.parametrize("cell_name", sorted(PARENT_PROPERTIES_SHA256))
+def test_the_input_module_writes_the_bytes_the_harness_wrote(tmp_path, cell_name):
+    """`Inputs` moved from `run.py` to `inputs/train_test_csv.py`: for a
+    fixed seed the train file, the test files, the schema and the
+    properties are the parent's, byte for byte, and so are the arguments."""
+    import hashlib
+
+    man = small_copy(str(tmp_path))
+    cell = man.cell(cell_name)
+    assert "inputs_kind" not in cell.config
+    work = os.path.join(str(tmp_path), "work")
+    os.makedirs(work)
+    inputs = man.inputs(cell.config).Inputs(cell, 2**31 + 27, work)
+    warm = inputs.warmup_argv("OUT")
+    got = {}
+    for f in os.listdir(work):
+        with open(os.path.join(work, f), "rb") as fh:
+            got[f] = hashlib.sha256(
+                fh.read().replace(work.encode(), b"{work}")).hexdigest()
+    assert got == dict(PARENT_SHA256,
+                       **{"job.properties": PARENT_PROPERTIES_SHA256[cell_name]})
+    at = lambda name: os.path.join(work, name)  # noqa: E731
+    assert inputs.n_files == 2 and inputs.out_suffix == ".csv"
+    assert inputs.argv(1, "OUT") == [
+        "nearestNeighbor", "--conf", at("job.properties"), at("train.csv"),
+        at("test_01.csv"), "OUT"]
+    assert warm == ["nearestNeighbor", "--conf", at("job.properties"),
+                    at("train.csv"), at("test_warmup.csv"), "OUT"]

@@ -10,7 +10,8 @@ import os
 import numpy as np
 import pytest
 
-from bench_fixtures import CELL_OF, CONFIGS, CPU_DEVICE, small_copy
+from bench_fixtures import (CELL_OF, CONFIGS, CPU_DEVICE, compile_cache_off,
+                            small_copy)
 
 from chipbench import compare, reference, run
 from chipbench.checks import knn_classify
@@ -18,23 +19,11 @@ from chipbench.checks import knn_classify
 
 @pytest.fixture(scope="module")
 def bench(tmp_path_factory):
-    """The small copy. The persistent compile cache is off while these
-    tests run, and the worker's cache directory is put back afterwards (the
-    jobs' own device rule places it): what this worker would otherwise write
-    into the checkout's `.jax_cache`, now or in a later test file, is what
-    `tests/test_devices.py` watches for in another worker."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache
-
+    """The small copy, with the persistent compile cache off while these
+    tests run (`bench_fixtures.compile_cache_off`)."""
     tmp = tmp_path_factory.mktemp("bench")
-    was = (jax.config.jax_enable_compilation_cache,
-           jax.config.jax_compilation_cache_dir)
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield small_copy(str(tmp)), str(tmp)
-    jax.config.update("jax_enable_compilation_cache", was[0])
-    jax.config.update("jax_compilation_cache_dir", was[1])
-    compilation_cache.reset_cache()
+    with compile_cache_off():
+        yield small_copy(str(tmp)), str(tmp)
 
 
 def drive(bench, cell_name, seed, entry=run.default_entry, seconds=0.0):

@@ -10,7 +10,8 @@ import os
 
 import pytest
 
-from bench_fixtures import ROOT  # noqa: F401
+import pins
+from bench_fixtures import ROOT
 
 from chipbench import manifest
 
@@ -57,23 +58,34 @@ def test_each_new_metric_file_reads_the_recorded_job(ctx, name):
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))
 def test_each_new_metric_has_its_entry_cells_and_layer(name):
-    entry = next(m for m in DOC["per_layer"] if m["name"] == name)
-    assert entry["source"] == "program_span" and entry["moves"] == "job_s"
-    weighted_only = name.startswith("nb_")
-    assert entry["workloads"] == (["knn-elearn-ccw.adhoc"] if weighted_only else
-                                  ["knn-elearn.bulk", "knn-elearn-ccw.adhoc"])
-    assert (entry["unit"], entry["better"]) == (
-        ("%", "higher") if name == "idle_named_share" else ("ms", "lower"))
+    """Its entry as PR 25 wrote it; its cells at least those it has had
+    (the three `nb_*` the weighted cell, the rest both), and more may be
+    listed."""
+    pins.hold_span_metric_entry(DOC, name)
 
 
 def test_the_new_entries_come_last_and_the_old_stand_as_they_were():
-    names = [m["name"] for m in DOC["per_layer"]]
-    assert names[:8] == ["compiles_in_window", "parse_ms_per_job",
-                         "stall_consumer_ms_per_job",
-                         "nb_fold_device_ms_per_job", "knn_kernel_ms_per_job",
-                         "knn_kernel_roofline", "device_idle_share",
-                         "peak_hbm_gb"]
-    assert sorted(names[8:]) == sorted(EXPECTED)
+    """PR 24's eight, then PR 25's eight (the ones this file reads the
+    recorded job with); what follows the sixteenth is free."""
+    pins.hold_the_first_sixteen(DOC)
+    assert sorted(EXPECTED) == sorted(pins.SPAN_EIGHT)
+
+
+ENCODE = "train_encode_ms_per_job"
+
+
+def test_the_seventeenth_metric_reads_the_encode_leaf(ctx):
+    """`dataset.encode` is a leaf of `dataset.parse`: the recorded job
+    spends 6 of the parse's 30 ms there."""
+    entry = DOC["per_layer"][16]
+    assert entry["name"] == ENCODE and entry["layer"] == "Parse / replay"
+    assert (entry["source"], entry["moves"]) == ("program_span", "job_s")
+    assert set(entry["workloads"]) == {w["name"] for w in DOC["workloads"]}
+    parse = span(ctx, "dataset.parse")
+    ctx["spans"].append({"name": "dataset.encode", "t0": parse["t0"] + 0.002,
+                         "dur": 0.006})
+    assert read(ctx, ENCODE) == pytest.approx(6.0, rel=1e-6)
+    assert read(ctx, "train_parse_ms_per_job") == pytest.approx(30.0, rel=1e-6)
 
 
 def test_idle_is_named_by_the_leaf_the_host_was_in(ctx):
