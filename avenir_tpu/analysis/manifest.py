@@ -183,15 +183,18 @@ def _family_entries() -> List[KernelSpec]:
             num_classes=_NB_CLASSES, bmax=_NB_BMAX)
 
     def tree_build(mesh):
+        from avenir_tpu.models.tree import LANES
+
         r = row(mesh)
         fn = D.distributed_tree_level_fn(
             mesh, TREE["n_leaves"], TREE["n_splits"], TREE["smax"],
             TREE["num_classes"])
         return fn, (
-            put(mesh, np.zeros((ROWS,), np.int32), r),
-            put(mesh, np.zeros((ROWS, TREE["n_splits"]), np.int8), r),
-            put(mesh, np.zeros((ROWS,), np.int32), r),
-            put(mesh, np.ones((ROWS,), np.float32), r),
+            put(mesh, np.zeros((ROWS, LANES), np.int32), r),
+            put(mesh, np.zeros((TREE["n_splits"], ROWS, LANES), np.int8),
+                None, r),
+            put(mesh, np.zeros((ROWS, LANES), np.int32), r),
+            put(mesh, np.ones((ROWS, LANES), np.int32), r),
         )
 
     def tree_payload(mesh):

@@ -410,21 +410,20 @@ class ClassPartitionGenerator:
     def _histograms(self) -> List[np.ndarray]:
         """Per split: [n_segments, k] class counts — the tree level
         histogram kernel with a single root leaf."""
-        from avenir_tpu.models.tree import _level_histogram
+        from avenir_tpu.models.tree import (_level_histogram, segment_matrix,
+                                            to_lines)
 
         if not self.splits:
             return []
         n = len(self.ds)
         smax = max(s.n_segments for s in self.splits)
-        seg = np.stack(
-            [s.segment_of(np.asarray(self.ds.column(s.attribute)))
-             for s in self.splits], axis=1,
-        ).astype(np.int8)                                    # [n, NS]
+        labels = to_lines(self.ds.labels())
         hists = np.asarray(_level_histogram(
-            jnp.zeros(n, jnp.int32), jnp.asarray(seg),
-            jnp.asarray(self.ds.labels()), jnp.ones(n, jnp.float32),
-            1, len(self.splits), smax, self.k,
-        ))[0]                                                # [NS, smax, k]
+            jnp.zeros(labels.shape, jnp.int32),
+            jnp.asarray(segment_matrix(self.splits, self.ds)),
+            jnp.asarray(labels), jnp.asarray(to_lines(np.ones(n, np.int32))),
+            1, smax, self.k,
+        ))[0].astype(np.float64)                             # [NS, smax, k]
         return [hists[i, : s.n_segments] for i, s in enumerate(self.splits)]
 
     def split_stats(self) -> List[Tuple[object, float]]:

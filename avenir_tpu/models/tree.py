@@ -19,12 +19,15 @@ Reference semantics (org.avenir.tree, SURVEY §2.3/§3.4):
   (DecisionTreeBuilder.java:200-236, :353-369).
 
 TPU design: candidate splits are static (schema-driven), so each split is a
-record->segment mapping computed ONCE as an int8 matrix [n, n_splits]; a
-tree level is then a single one-hot einsum producing the histogram tensor
-[leaves, splits, segments, classes] — no predicate branching, no shuffle.
-The host picks best splits / applies stopping (tiny tensors) and updates the
-on-device leaf assignment by gathering the winning split's segment column.
-Random forest reuses the same segment matrix across trees with per-tree row
+record->segment mapping computed ONCE as an int8 matrix [n_splits, n], held
+in lines of 128 rows (`to_lines`: 1 B a row and split on the device, where
+a row-major [n, n_splits] is tiled to 128 lanes a row); a tree level is then one pass
+over the rows in blocks, each block a one-hot int8 contraction into the
+int32 histogram tensor [leaves, splits, segments, classes] — no predicate
+branching, no shuffle, and counts that are exact at any row count. The
+host picks best splits / applies stopping (tiny tensors) and updates the
+on-device leaf assignment from the winning split's segment row. Random
+forest reuses the same segment matrix across trees with per-tree row
 weights (bootstrap counts) and attribute masks.
 
 Model format: DecisionPathList-compatible JSON (jackson field names), so
@@ -37,6 +40,7 @@ import itertools
 import json
 import math
 import os
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -45,11 +49,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from avenir_tpu import obs
 from avenir_tpu.core.dataset import Dataset
 from avenir_tpu.core.schema import FeatureField, FeatureSchema
 from avenir_tpu.utils.metrics import ConfusionMatrix
 
 ROOT_PATH = "$root"
+_SAMPLE_WORKERS = 3          # threads that count bootstrap draws
 
 
 def _np_bits_entropy(counts: np.ndarray, axis: int = -1) -> np.ndarray:
@@ -125,8 +131,13 @@ class CandidateSplit:
 
     def segment_of(self, col: np.ndarray) -> np.ndarray:
         if self._kind == "numeric":
-            return np.searchsorted(self._bounds, col, side="right").astype(np.int8)
-        return self._group_of[col.astype(np.int64)].astype(np.int8)
+            # the boundaries at or below the value, one comparison each: a
+            # split has a handful, and a binary search per row costs more
+            seg = np.zeros(len(col), np.int8)
+            for b in self._bounds:
+                seg += col >= b
+            return seg
+        return self._group_of.astype(np.int8).take(col)
 
 
 def _numeric_splits(fld: FeatureField, max_split: int) -> List[List[float]]:
@@ -231,57 +242,189 @@ def enumerate_splits(schema: FeatureSchema,
 # ---------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnames=("n_leaves", "n_splits", "smax", "k"))
-def _level_histogram(leaf_id, seg_matrix, labels, weights,
-                     n_leaves: int, n_splits: int, smax: int, k: int):
-    """counts[l, s, seg, c] for all leaves x splits x segments x classes in
-    one segment_sum — the whole MR shuffle of one tree level."""
-    # combined key: ((leaf * n_splits + split) * smax + segment) * k + class
-    base = (leaf_id.astype(jnp.int32) * n_splits)[:, None] + jnp.arange(n_splits)[None, :]
-    key = (base * smax + seg_matrix.astype(jnp.int32)) * k + labels[:, None]
-    flat = jax.ops.segment_sum(
-        jnp.broadcast_to(weights[:, None], key.shape).reshape(-1),
-        key.reshape(-1),
-        num_segments=n_leaves * n_splits * smax * k,
-    )
-    return flat.reshape(n_leaves, n_splits, smax, k)
+#: the device holds every per-row array in lines of LANES rows, [.., R,
+#: LANES]: the TPU tiles the last two axes (8 x 128 int32, 32 x 128 int8),
+#: so a [T, n] array of ten trees would be padded to sixteen and an [n, NS]
+#: one to 128 lanes a row; in lines nothing is padded but the last tile
+LANES = 128
+#: rows of one block of the level pass: what a pass holds beyond its
+#: arguments is one block's one-hot operands, whatever the row count
+ROW_BLOCK = 1 << 17
+_BLOCK_LINES = ROW_BLOCK // LANES
+_DIGIT_BITS = 7                     # an int8 operand holds 0..127
+MAX_WEIGHT = (1 << 31) - 1
 
 
-@partial(jax.jit, static_argnames=())
-def _advance_leaves(leaf_id, seg_matrix, best_split_of_leaf, child_offset):
-    """new_leaf = child_offset[leaf] + segment under the leaf's chosen split;
-    leaves without a split (stopped/unsplit) keep a fixed id via offset -1."""
-    split = best_split_of_leaf[leaf_id]                       # [n]
-    seg = jnp.take_along_axis(
-        seg_matrix, jnp.maximum(split, 0)[:, None], axis=1
-    )[:, 0].astype(jnp.int32)
-    off = child_offset[leaf_id]
-    return jnp.where(split >= 0, off + seg, leaf_id)
+def to_lines(x: np.ndarray) -> np.ndarray:
+    """[.., n] -> [.., R, LANES] on the host: the last axis padded with
+    zeros to whole lines (a pad row has weight 0 and counts nowhere)."""
+    n = x.shape[-1]
+    pad = -n % LANES
+    if pad:
+        x = np.concatenate(
+            [x, np.zeros(x.shape[:-1] + (pad,), x.dtype)], axis=-1)
+    return x.reshape(x.shape[:-1] + ((n + pad) // LANES, LANES))
 
 
-@partial(jax.jit, static_argnames=("n_leaves", "n_splits", "smax", "k"))
+def _weight_digits(max_weight: int) -> int:
+    """Base-128 digits of the largest row weight (1 for any bootstrap
+    count): a static argument of the level pass."""
+    return max(1, -(-int(max_weight).bit_length() // _DIGIT_BITS))
+
+
+def whole_weights(row_weights, n: int) -> np.ndarray:
+    """Row weights as the int32 counts the level pass adds up. A weight is
+    a count (1, or how often a bootstrap sample drew the row): fractions,
+    negatives and counts past int32 are refused, because the histogram
+    counts in integers and would otherwise be silently wrong."""
+    if row_weights is None:
+        return np.ones(n, np.int32)
+    w = np.asarray(row_weights)
+    if w.shape != (n,):
+        raise ValueError(f"row_weights wants one weight a row ({n}), "
+                         f"got shape {w.shape}")
+    if w.size and not (np.all(w == np.floor(w)) and w.min() >= 0
+                       and w.max() <= MAX_WEIGHT):
+        raise ValueError(
+            "row_weights are counts: whole numbers from 0 to 2^31 - 1 "
+            "(the level histogram counts in integers)")
+    return w.astype(np.int32)
+
+
+def _block_counts(leaf_ids, seg_matrix, labels, weights,
+                  n_leaves: int, smax: int, k: int, digits: int):
+    """One block's [T, L*K, NS*S] int32 counts, the block being b lines of
+    every argument. The row side is the one-hot of (leaf, class) carrying
+    the weight's base-128 digits, the split side the one-hot of each
+    split's segment, both int8; their contraction over the block's rows
+    accumulates in int32 on the MXU, so nothing is rounded anywhere."""
+    ns, b, lanes = seg_matrix.shape
+    lk = leaf_ids * k + labels[None]                              # [T, b, C]
+    cells = jnp.arange(n_leaves * k, dtype=jnp.int32)
+    hot = lk[:, None] == cells[None, :, None, None]               # [T, LK, b, C]
+    shifts = _DIGIT_BITS * jnp.arange(digits, dtype=jnp.int32)
+    digit = (weights[:, None] >> shifts[None, :, None, None]) & 127
+    rows = jnp.where(hot[:, None], digit[:, :, None], 0
+                     ).astype(jnp.int8)                           # [T, D, LK, b, C]
+    segs = (seg_matrix[:, None]
+            == jnp.arange(smax, dtype=jnp.int8)[None, :, None, None]
+            ).astype(jnp.int8).reshape(ns * smax, b, lanes)
+    per_digit = jnp.einsum("tdmrc,nrc->tdmn", rows, segs,
+                           preferred_element_type=jnp.int32)
+    return jnp.sum(per_digit << shifts[None, :, None, None], axis=1)
+
+
+def _lines_of(x, lo, size):
+    """`size` lines of x from line `lo` (the axis before the lanes)."""
+    return jax.lax.dynamic_slice_in_dim(x, lo, size, axis=x.ndim - 2)
+
+
+@partial(jax.jit, static_argnames=("n_leaves", "smax", "k", "digits",
+                                   "block_lines"))
 def _level_histogram_forest(leaf_ids, seg_matrix, labels, weights,
-                            n_leaves: int, n_splits: int, smax: int, k: int):
-    """[T, L, NS, S, K]: every tree's level histogram in ONE dispatch.
+                            n_leaves: int, smax: int, k: int,
+                            digits: int = 1,
+                            block_lines: int = _BLOCK_LINES):
+    """counts[T, L, NS, S, K], int32: every tree's class histogram of all
+    leaves x splits x segments in ONE dispatch — the whole MR shuffle of
+    one tree level (detr.sh:34-54), for the whole forest.
 
-    The forest's trees differ only in leaf routing and bootstrap row
-    weights; the segment matrix and labels are shared, so vmapping over
-    (leaf_ids, weights) turns T histogram round-trips per level into one —
-    the per-level dispatch latency (the reference's one-MR-job-per-level
-    cost, detr.sh:34-54) stops multiplying by the tree count."""
-    return jax.vmap(
-        lambda lid, w: _level_histogram(lid, seg_matrix, labels, w,
-                                        n_leaves, n_splits, smax, k)
-    )(leaf_ids, weights)
+    All arguments in lines (`to_lines`): leaf_ids [T, R, LANES] int32,
+    seg_matrix [NS, R, LANES] int8, labels [R, LANES] int32, weights
+    [T, R, LANES] int32 whole numbers under 128**digits. The trees differ
+    only in leaf routing and bootstrap weights; the segment matrix and
+    the labels are shared.
+
+    The rows go through in blocks of ROW_BLOCK (`block_lines` lines; a
+    test hands a smaller one) inside this one program (a `fori_loop` over
+    slices of the arguments, then the lines past the last whole block),
+    so what the pass holds beyond its arguments does not grow with n. It accumulates in int32: counts are exact for any n
+    and any whole-number weights whose cell sums stay under 2^31. float32
+    stops counting at 2^24 (16,777,216 + 1 = 16,777,216), which a root
+    cell passes at 17M rows; a per-block float32 sum added up in integers
+    would be exact only while a block's cell stays under 2^24; and the
+    int8 contraction is the MXU's fastest form anyway."""
+    t, r, _ = leaf_ids.shape
+    ns = seg_matrix.shape[0]
+    block = max(1, min(block_lines, r))
+    whole = r // block
+
+    def counts_of(lo, size):
+        return _block_counts(
+            _lines_of(leaf_ids, lo, size), _lines_of(seg_matrix, lo, size),
+            _lines_of(labels, lo, size), _lines_of(weights, lo, size),
+            n_leaves, smax, k, digits)
+
+    acc = jnp.zeros((t, n_leaves * k, ns * smax), jnp.int32)
+    if whole:
+        acc = jax.lax.fori_loop(
+            0, whole, lambda i, a: a + counts_of(i * block, block), acc)
+    if r - whole * block:
+        acc = acc + counts_of(whole * block, r - whole * block)
+    return acc.reshape(t, n_leaves, k, ns, smax).transpose(0, 1, 3, 4, 2)
 
 
-@jax.jit
+def _level_histogram(leaf_id, seg_matrix, labels, weights,
+                     n_leaves: int, smax: int, k: int, digits: int = 1):
+    """counts[L, NS, S, K] of one tree: the forest's pass with one tree
+    (leaf_id and weights [R, LANES]); there is no second form."""
+    return _level_histogram_forest(
+        leaf_id[None], seg_matrix, labels, weights[None],
+        n_leaves=n_leaves, smax=smax, k=k, digits=digits)[0]
+
+
+def _lookup(table, index):
+    """table[t, index[t, ..]] for a small table [T, L]: a compare and a
+    sum over L (the TPU has no fast gather, and L is a handful)."""
+    slots = jnp.arange(table.shape[1], dtype=index.dtype)
+    return jnp.sum(jnp.where(index[:, None] == slots[None, :, None, None],
+                             table[:, :, None, None], 0), axis=1)
+
+
+def _advance_block(leaf_ids, seg_matrix, best_split_of_leaf, child_offset):
+    split = _lookup(best_split_of_leaf, leaf_ids)                 # [T, b, C]
+    off = _lookup(child_offset, leaf_ids)
+    splits = jnp.arange(seg_matrix.shape[0], dtype=jnp.int32)
+    seg = jnp.sum(jnp.where(split[:, None] == splits[None, :, None, None],
+                            seg_matrix[None].astype(jnp.int32), 0), axis=1)
+    return jnp.where(split >= 0, off + seg, leaf_ids)
+
+
+@partial(jax.jit, donate_argnums=(0,), static_argnames=("block_lines",))
 def _advance_leaves_forest(leaf_ids, seg_matrix, best_split_of_leaf,
-                           child_offset):
-    """Vmapped _advance_leaves over the tree axis ([T, n] leaf ids)."""
-    return jax.vmap(
-        lambda lid, b, c: _advance_leaves(lid, seg_matrix, b, c)
-    )(leaf_ids, best_split_of_leaf, child_offset)
+                           child_offset, block_lines: int = _BLOCK_LINES):
+    """new_leaf = child_offset[leaf] + segment under the leaf's chosen
+    split, for every tree (leaf ids [T, R, LANES], tables [T, L]); leaves
+    without a split (stopped/unsplit, best -1) keep their id. Written
+    over the donated leaf ids block by block, like the level pass."""
+    r = leaf_ids.shape[1]
+    block = max(1, min(block_lines, r))
+    whole = r // block
+
+    def advanced(ids, lo, size):
+        new = _advance_block(_lines_of(ids, lo, size),
+                             _lines_of(seg_matrix, lo, size),
+                             best_split_of_leaf, child_offset)
+        return jax.lax.dynamic_update_slice_in_dim(ids, new, lo, axis=1)
+
+    if whole:
+        leaf_ids = jax.lax.fori_loop(
+            0, whole, lambda i, ids: advanced(ids, i * block, block),
+            leaf_ids)
+    if r - whole * block:
+        leaf_ids = advanced(leaf_ids, whole * block, r - whole * block)
+    return leaf_ids
+
+
+def segment_matrix(splits: Sequence[CandidateSplit], ds: Dataset
+                   ) -> np.ndarray:
+    """[NS, R, LANES] int8: every row's segment under every candidate
+    split, in lines as the level pass reads them (1 B a row and split)."""
+    n = len(ds)
+    seg = np.zeros((len(splits), -(-n // LANES) * LANES), np.int8)
+    for i, sp in enumerate(splits):
+        seg[i, :n] = sp.segment_of(np.asarray(ds.column(sp.attribute)))
+    return to_lines(seg)
 
 
 # ---------------------------------------------------------------------------
@@ -555,6 +698,82 @@ class DevicePathEvaluator:
 
 
 # ---------------------------------------------------------------------------
+# the level loop
+# ---------------------------------------------------------------------------
+
+
+def _grow_forest(builders: Sequence["DecisionTreeBuilder"], seg_d, labels_d,
+                 ws_d, leaf_ids, digits: int, note: Dict,
+                 mesh=None) -> List["DecisionPathList"]:
+    """Grow the builders' trees together over shared rows: per level one
+    histogram pass for all trees, the host's split selection, one advance
+    of the [T, n] leaf ids; then a last pass for the paths' final counts.
+    `note` is the `tree.fit` span's attributes (`levels`, `leaves`)."""
+    b0 = builders[0]
+    ns, k, smax = len(b0.splits), len(b0.class_values), b0.smax
+
+    def level_counts(leaves_t) -> np.ndarray:
+        """[T, lpad, NS, S, K] int64 on the host. The leaf axis is padded
+        to the next power of two: n_leaves is a static (compile-time)
+        dimension, and letting it take every integer value would
+        recompile the pass per level and per tree; padded leaf ids
+        receive no rows."""
+        lpad = 1 << (max(len(lv) for lv in leaves_t) - 1).bit_length()
+        with obs.span("tree.level.dispatch", leaves=lpad):
+            if mesh is not None:
+                from avenir_tpu.parallel.distributed import (
+                    distributed_tree_level_fn)
+
+                out = distributed_tree_level_fn(mesh, lpad, ns, smax, k, digits)(
+                    leaf_ids[0], seg_d, labels_d, ws_d[0])[None]
+            else:
+                out = _level_histogram_forest(
+                    leaf_ids, seg_d, labels_d, ws_d,
+                    n_leaves=lpad, smax=smax, k=k, digits=digits)
+        with obs.span("tree.level.fetch"):
+            return np.asarray(out).astype(np.int64)
+
+    leaves_t: List[List[Dict]] = [
+        [{"preds": [], "used": set(), "stopped": False}] for _ in builders]
+    levels = 0
+    for _depth in range(b0.max_depth if ns else 0):
+        if not any(DecisionTreeBuilder._active_leaves(lv) for lv in leaves_t):
+            break
+        counts_all = level_counts(leaves_t)
+        levels += 1
+        lpad = counts_all.shape[1]
+        with obs.span("tree.level.select"):
+            bests, offsets = [], []
+            any_new = False
+            for t, b in enumerate(builders):
+                best, child, new_l = b._grow_level(
+                    leaves_t[t], counts_all[t][: len(leaves_t[t])], lpad)
+                if new_l:
+                    any_new = True
+                    # children get smax slots per split parent
+                    leaves_t[t] = leaves_t[t] + new_l
+                bests.append(best)
+                offsets.append(child)
+        if not any_new:
+            break
+        with obs.span("tree.level.advance"):
+            leaf_ids = _advance_leaves_forest(
+                leaf_ids, seg_d, jnp.asarray(np.stack(bests)),
+                jnp.asarray(np.stack(offsets)))
+
+    counts_fin = level_counts(leaves_t) if ns else None
+    levels += bool(ns)
+    with obs.span("tree.emit"):
+        trees = [
+            b._emit_paths(leaves_t[t],
+                          counts_fin[t][: len(leaves_t[t])]
+                          if counts_fin is not None else None)
+            for t, b in enumerate(builders)]
+    note.update(levels=levels, leaves=sum(len(tr.paths) for tr in trees))
+    return trees
+
+
+# ---------------------------------------------------------------------------
 # builder
 # ---------------------------------------------------------------------------
 
@@ -589,65 +808,38 @@ class DecisionTreeBuilder:
     # ------------------------------------------------------------------- fit
     def fit(self, ds: Dataset, row_weights: Optional[np.ndarray] = None,
             mesh=None) -> DecisionPathList:
-        """Build the tree. With `mesh`, the row tensors shard over the mesh
-        and every per-level histogram reduction runs SPMD — XLA inserts the
-        psum the reference's shuffle performed (zero-weight rows pad to
-        shard divisibility, so counts are exact)."""
+        """Build the tree. `row_weights` are counts (`whole_weights`:
+        fractions are refused). With `mesh`, the row tensors shard over the
+        mesh and every level histogram is the same pass on each shard's
+        rows, psum'd (`distributed_tree_level_fn`: the reference's
+        shuffle); zero-weight rows pad to shard divisibility, so counts
+        are exact."""
         n = len(ds)
-        k = len(self.class_values)
-        ns = len(self.splits)
-        seg = np.stack(
-            [sp.segment_of(np.asarray(ds.column(sp.attribute))) for sp in self.splits],
-            axis=1,
-        ).astype(np.int8)                                     # [n, NS]
-        labels = ds.labels()
-        w_host = (row_weights.astype(np.float32) if row_weights is not None
-                  else np.ones(n, np.float32))
-        if mesh is not None:
-            from avenir_tpu.parallel.mesh import shard_rows
+        with obs.span("tree.fit", rows=n, trees=1, splits=len(self.splits),
+                      row_block=min(ROW_BLOCK, n)) as note:
+            with obs.span("tree.segments"):
+                seg = segment_matrix(self.splits, ds)       # [NS, R, LANES]
+                labels = to_lines(ds.labels())
+                w_host = whole_weights(row_weights, n)
+            digits = _weight_digits(w_host.max(initial=1))
+            w_host = to_lines(w_host)
+            with obs.span("tree.put"):
+                if mesh is not None:
+                    from avenir_tpu.parallel.mesh import shard_rows
 
-            seg_d = shard_rows(mesh, seg)
-            labels_d = shard_rows(mesh, labels)
-            w = shard_rows(mesh, w_host)          # pad rows weigh 0
-            leaf_id = shard_rows(mesh, np.zeros(len(ds), np.int32))
-        else:
-            seg_d = jnp.asarray(seg)
-            labels_d = jnp.asarray(labels)
-            w = jnp.asarray(w_host)
-            leaf_id = jnp.zeros(n, jnp.int32)
-
-        # host-side tree state: leaf -> (predicate chain, used attrs)
-        leaves: List[Dict] = [{"preds": [], "used": set(), "stopped": False}]
-
-        for depth in range(self.max_depth):
-            if not self._active_leaves(leaves):
-                break
-            # pad the leaf axis to the next power of two: n_leaves is a
-            # static (compile-time) dimension, and letting it take every
-            # integer value would recompile the histogram per level and
-            # per tree (each compile costs tens of seconds on a remote
-            # chip); padded segment ids receive no rows
-            lpad = 1 << (len(leaves) - 1).bit_length()
-            counts = np.asarray(_level_histogram(
-                leaf_id, seg_d, labels_d, w, lpad, ns, self.smax, k
-            ))[: len(leaves)]                                 # [L, NS, S, K]
-            best_split_of_leaf, child_offset, new_leaves = self._grow_level(
-                leaves, counts, lpad)
-            if not new_leaves:
-                break
-            # materialize finished leaves for paths that stopped this level
-            leaf_id = _advance_leaves(
-                leaf_id, seg_d,
-                jnp.asarray(best_split_of_leaf), jnp.asarray(child_offset),
-            )
-            # children get smax slots per split parent; re-index leaves
-            leaves = leaves + new_leaves
-
-        counts_final = np.asarray(_level_histogram(
-            leaf_id, seg_d, labels_d, w,
-            1 << (len(leaves) - 1).bit_length(), max(ns, 1), self.smax, k
-        ))[: len(leaves)] if ns else None
-        return self._emit_paths(leaves, counts_final)
+                    # the lines shard; a line of padding weighs 0
+                    seg_d = shard_rows(mesh, seg, axis=1)
+                    labels_d = shard_rows(mesh, labels)
+                    ws_d = shard_rows(mesh, w_host[None], axis=1)
+                    leaf_ids = shard_rows(mesh, np.zeros_like(labels)[None],
+                                          axis=1)
+                else:
+                    seg_d = jnp.asarray(seg)
+                    labels_d = jnp.asarray(labels)
+                    ws_d = jnp.asarray(w_host)[None]
+                    leaf_ids = jnp.zeros((1,) + labels.shape, jnp.int32)
+            return _grow_forest([self], seg_d, labels_d, ws_d, leaf_ids,
+                                digits, note, mesh)[0]
 
     @staticmethod
     def _active_leaves(leaves: List[Dict]) -> List[int]:
@@ -783,7 +975,18 @@ class DecisionTreeBuilder:
 class RandomForestBuilder:
     """RF = trees over bootstrap row weights + random attribute selection
     (reference first-iteration sampling DecisionTreeBuilder.java:200-236 with
-    sub.sampling.strategy withReplace/withoutReplace)."""
+    sub.sampling.strategy withReplace/withoutReplace).
+
+    The sampling rule is the job's contract: **the forest is a function of
+    the input file and the seed.** One `np.random.default_rng(seed)` serves
+    all trees, in tree order. Under `withReplace` tree t's sample is
+    `rng.integers(0, n, n)`, and a row's weight is how often it was drawn
+    (`np.bincount(idx, minlength=n)`); under `withoutReplace` the weight
+    is `rng.random(n) < sample_rate`; any other strategy weighs every row
+    1 and draws nothing. Tree t picks its node attributes with a
+    generator of its own, `np.random.default_rng(seed + t)`, through
+    `choice` in `DecisionTreeBuilder._allowed_splits`. Counting, converting
+    and copying may move between threads; the draws may not change."""
 
     def __init__(
         self,
@@ -805,6 +1008,30 @@ class RandomForestBuilder:
         self.class_values = schema.class_values()
         self._evaluator: Optional[DevicePathEvaluator] = None
 
+    def _sample(self, n: int) -> np.ndarray:
+        """[T, R, LANES] int32: how often each tree's sample holds each row, by
+        the sampling rule of the class docstring. The draws stay on the
+        caller's thread, in tree order; counting a finished draw runs on
+        a worker while the next is drawn."""
+        rng = np.random.default_rng(self.seed)
+        ws = np.zeros((self.num_trees, -(-n // LANES) * LANES), np.int32)
+
+        def count(t: int, idx: np.ndarray) -> None:
+            ws[t, :n] = np.bincount(idx, minlength=n)
+
+        with ThreadPoolExecutor(_SAMPLE_WORKERS) as pool:
+            pending = []
+            for t in range(self.num_trees):
+                if self.sampling == "withReplace":
+                    pending.append(pool.submit(count, t, rng.integers(0, n, n)))
+                elif self.sampling == "withoutReplace":
+                    ws[t, :n] = rng.random(n) < self.sample_rate
+                else:
+                    ws[t, :n] = 1
+            for job in pending:
+                job.result()
+        return to_lines(ws)
+
     def fit(self, ds: Dataset) -> "RandomForestBuilder":
         """All trees grow together, one batched device call per level:
         trees share the (segment matrix, labels) upload and differ only in
@@ -812,72 +1039,32 @@ class RandomForestBuilder:
         max_depth histogram+advance dispatches instead of
         num_trees x (max_depth x 2 + 1) round trips."""
         n = len(ds)
-        rng = np.random.default_rng(self.seed)
         self.trees = []
         self._evaluator = None
-        ws = np.empty((self.num_trees, n), np.float32)
-        for t in range(self.num_trees):
-            if self.sampling == "withReplace":
-                idx = rng.integers(0, n, n)
-                ws[t] = np.bincount(idx, minlength=n).astype(np.float32)
-            elif self.sampling == "withoutReplace":
-                ws[t] = (rng.random(n) < self.sample_rate).astype(np.float32)
-            else:
-                ws[t] = 1.0
         builders = [
             DecisionTreeBuilder(self.schema, seed=self.seed + t,
                                 **self.tree_kwargs)
             for t in range(self.num_trees)
         ]
-        b0 = builders[0]
-        ns, k, smax = len(b0.splits), len(b0.class_values), b0.smax
-        seg = np.stack(
-            [sp.segment_of(np.asarray(ds.column(sp.attribute)))
-             for sp in b0.splits], axis=1,
-        ).astype(np.int8)
-        seg_d = jnp.asarray(seg)
-        labels_d = jnp.asarray(ds.labels())
-        ws_d = jnp.asarray(ws)
-        leaf_ids = jnp.zeros((self.num_trees, n), jnp.int32)
-        leaves_t: List[List[Dict]] = [
-            [{"preds": [], "used": set(), "stopped": False}]
-            for _ in range(self.num_trees)
-        ]
-
-        for depth in range(b0.max_depth):
-            if not any(DecisionTreeBuilder._active_leaves(lv)
-                       for lv in leaves_t):
-                break
-            lpad = 1 << (max(len(lv) for lv in leaves_t) - 1).bit_length()
-            counts_all = np.asarray(_level_histogram_forest(
-                leaf_ids, seg_d, labels_d, ws_d, lpad, ns, smax, k))
-            bests, offsets = [], []
-            any_new = False
-            for t, b in enumerate(builders):
-                best, child, new_l = b._grow_level(
-                    leaves_t[t], counts_all[t][: len(leaves_t[t])], lpad)
-                if new_l:
-                    any_new = True
-                    leaves_t[t] = leaves_t[t] + new_l
-                bests.append(best)
-                offsets.append(child)
-            if not any_new:
-                break
-            leaf_ids = _advance_leaves_forest(
-                leaf_ids, seg_d, jnp.asarray(np.stack(bests)),
-                jnp.asarray(np.stack(offsets)))
-
-        lpad = 1 << (max(len(lv) for lv in leaves_t) - 1).bit_length()
-        counts_fin = np.asarray(_level_histogram_forest(
-            leaf_ids, seg_d, labels_d, ws_d, lpad, max(ns, 1), smax, k
-        )) if ns else None
-        self.trees = [
-            b._emit_paths(
-                leaves_t[t],
-                counts_fin[t][: len(leaves_t[t])]
-                if counts_fin is not None else None)
-            for t, b in enumerate(builders)
-        ]
+        with obs.span("tree.fit", rows=n, trees=self.num_trees,
+                      splits=len(builders[0].splits),
+                      row_block=min(ROW_BLOCK, n)) as note:
+            with obs.span("tree.segments"):
+                seg = segment_matrix(builders[0].splits, ds)
+                labels = to_lines(ds.labels())
+            with obs.span("tree.put"):
+                seg_d = jnp.asarray(seg)
+                labels_d = jnp.asarray(labels)
+                leaf_ids = jnp.zeros((self.num_trees,) + labels.shape,
+                                     jnp.int32)
+            with obs.span("forest.sample", sampling=self.sampling):
+                ws = self._sample(n)
+            with obs.span("tree.put"):
+                ws_d = jnp.asarray(ws)
+            digits = _weight_digits(ws.max(initial=1))
+            del seg, labels, ws
+            self.trees = _grow_forest(builders, seg_d, labels_d, ws_d,
+                                      leaf_ids, digits, note)
         return self
 
     def predict(self, ds: Dataset, device: bool = False) -> np.ndarray:

@@ -10,6 +10,8 @@ k*P candidates per query instead of n_train, so the ICI traffic is tiny.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -86,26 +88,32 @@ def distributed_nb_train_fn(mesh: Mesh, num_classes: int, bmax: int):
     )
 
 
+@functools.lru_cache(maxsize=None)
 def distributed_tree_level_fn(mesh: Mesh, n_leaves: int, n_splits: int,
-                              smax: int, num_classes: int):
+                              smax: int, num_classes: int, digits: int = 1):
     """Build a jitted mesh-wide tree-level histogram step: every row shard
-    computes its [L, NS, S, K] class-histogram block locally (the
-    segment_sum that replaces one whole MR tree level, SURVEY §3.4), then a
-    psum over the mesh replicates the global histogram — the host picks
-    splits from a tensor that is tiny regardless of row count."""
+    computes its [L, NS, S, K] class-histogram block locally (the one
+    level pass of models.tree, which replaces one whole MR tree level,
+    SURVEY §3.4), then a psum over the mesh replicates the global
+    histogram — the host picks splits from a tensor that is tiny
+    regardless of row count. Arguments as the pass takes them, in lines
+    (`models.tree.to_lines`): leaf_id [R, LANES] int32, seg_matrix
+    [n_splits, R, LANES] int8, labels [R, LANES] int32, weights [R, LANES]
+    int32 whole numbers under 128**digits; the lines shard over the mesh.
+    Built once per mesh and shape."""
     from avenir_tpu.models.tree import _level_histogram
 
     axes = tuple(a for a in (DATA_AXIS, MODEL_AXIS) if a in mesh.axis_names)
 
     def kernel(leaf_id, seg_matrix, labels, weights):
         h = _level_histogram(leaf_id, seg_matrix, labels, weights,
-                             n_leaves, n_splits, smax, num_classes)
+                             n_leaves, smax, num_classes, digits)
         return lax.psum(h, axes)
 
     row = P(axes)
     return jax.jit(
         shard_map(kernel, mesh=mesh,
-                      in_specs=(row, row, row, row), out_specs=P())
+                      in_specs=(row, P(None, axes), row, row), out_specs=P())
     )
 
 

@@ -56,16 +56,20 @@ def row_spec(mesh: Mesh) -> P:
     return P(DATA_AXIS)
 
 
-def shard_rows(mesh: Mesh, arr: jax.Array, pad_value=0) -> jax.Array:
+def shard_rows(mesh: Mesh, arr: jax.Array, pad_value=0, axis: int = 0
+               ) -> jax.Array:
     """Place a host array row-sharded over the data axis, padding the row
-    count up to shard divisibility with `pad_value` rows."""
+    count up to shard divisibility with `pad_value` rows. The rows are
+    axis 0 unless `axis` says otherwise."""
     n_shards = mesh.shape[DATA_AXIS]
-    n = arr.shape[0]
-    rem = (-n) % n_shards
+    rem = (-arr.shape[axis]) % n_shards
     if rem:
-        pad_rows = np.full((rem,) + arr.shape[1:], pad_value, dtype=arr.dtype)
-        arr = np.concatenate([np.asarray(arr), pad_rows], axis=0)
-    return jax.device_put(arr, NamedSharding(mesh, P(DATA_AXIS)))
+        shape = arr.shape[:axis] + (rem,) + arr.shape[axis + 1:]
+        arr = np.concatenate(
+            [np.asarray(arr), np.full(shape, pad_value, dtype=arr.dtype)],
+            axis=axis)
+    return jax.device_put(
+        arr, NamedSharding(mesh, P(*[None] * axis, DATA_AXIS)))
 
 
 def row_mask(mesh: Mesh, n_valid: int, n_padded: int) -> jax.Array:

@@ -2058,20 +2058,25 @@ def feature_cond_prob_joiner(cfg: JobConfig, inputs: List[str], output: str
 
 
 # ======================================================================= tree
-def _tree_builder(cfg: JobConfig, schema: FeatureSchema):
-    from avenir_tpu.models.tree import DecisionTreeBuilder
-
-    strategy = cfg.get("path.stopping.strategy", "maxDepth")
-    return DecisionTreeBuilder(
-        schema,
+def _tree_arguments(cfg: JobConfig, attr_strategy: str) -> Dict:
+    """The `dtb.*` keys of a tree build, read once for `decTree` and
+    `randomForest` alike; `attr_strategy` is the job's default for
+    `dtb.split.attribute.selection.strategy`."""
+    return dict(
         split_algorithm=cfg.get("split.algorithm", "entropy"),
         max_depth=cfg.get_int("max.depth.limit", 3),
         min_info_gain=cfg.get_float("min.info.gain.limit", -1.0),
         min_population=cfg.get_int("min.population.limit", -1),
-        stopping_strategy=strategy,
+        stopping_strategy=cfg.get("path.stopping.strategy", "maxDepth"),
         attr_selection_strategy=cfg.get("split.attribute.selection.strategy",
-                                        "notUsedYet"),
+                                        attr_strategy),
     )
+
+
+def _tree_builder(cfg: JobConfig, schema: FeatureSchema):
+    from avenir_tpu.models.tree import DecisionTreeBuilder
+
+    return DecisionTreeBuilder(schema, **_tree_arguments(cfg, "notUsedYet"))
 
 
 @job("decTree", "dtb", "org.avenir.tree.DecisionTreeBuilder", "decisionTree")
@@ -2087,12 +2092,17 @@ def decision_tree(cfg: JobConfig, inputs: List[str], output: str) -> JobResult:
     builder = _tree_builder(cfg, ds.schema)
     paths = builder.fit(ds)
     out = cfg.get("decision.file.path.out") or _out_file(output, "decPathOut.txt")
-    paths.save(out)
+    with _obs.span("tree.write", files=1):
+        paths.save(out)
     return JobResult("decTree", {"Tree:Paths": len(paths.paths)}, [out], paths)
 
 
 @job("randomForest", "dtb", "org.avenir.tree.RandomForestBuilder")
 def random_forest(cfg: JobConfig, inputs: List[str], output: str) -> JobResult:
+    """The forest of resource/rafo.sh: `dtb.num.trees` trees over bootstrap
+    samples (`dtb.sub.sampling.strategy`, `.rate`), each built as `decTree`
+    builds its one from the same `dtb.*` keys, with `randomNotUsedYet`
+    the default attribute selection; one `tree-NNN.json` a tree."""
     from avenir_tpu.models.tree import RandomForestBuilder
 
     ds = _dataset(inputs[0], cfg)
@@ -2101,17 +2111,16 @@ def random_forest(cfg: JobConfig, inputs: List[str], output: str) -> JobResult:
         num_trees=cfg.get_int("num.trees", 10),
         sampling=cfg.get("sub.sampling.strategy", "withReplace"),
         sample_rate=cfg.get_float("sub.sampling.rate", 0.7),
-        split_algorithm=cfg.get("split.algorithm", "entropy"),
-        max_depth=cfg.get_int("max.depth.limit", 3),
-        stopping_strategy=cfg.get("path.stopping.strategy", "maxDepth"),
+        **_tree_arguments(cfg, "randomNotUsedYet"),
     ).fit(ds)
     outs = []
     if output:
-        os.makedirs(output, exist_ok=True)
-        for t, tree in enumerate(forest.trees):
-            p = os.path.join(output, f"tree-{t:03d}.json")
-            tree.save(p)
-            outs.append(p)
+        with _obs.span("tree.write", files=len(forest.trees)):
+            os.makedirs(output, exist_ok=True)
+            for t, tree in enumerate(forest.trees):
+                p = os.path.join(output, f"tree-{t:03d}.json")
+                tree.save(p)
+                outs.append(p)
     return JobResult("randomForest", {"Tree:Trees": len(forest.trees)},
                      outs, forest)
 

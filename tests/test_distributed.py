@@ -88,24 +88,26 @@ def test_distributed_tree_level_matches_single_device(mesh8, rng):
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from avenir_tpu.models.tree import _level_histogram
+    from avenir_tpu.models.tree import LANES, _level_histogram, to_lines
     from avenir_tpu.parallel import DATA_AXIS, distributed_tree_level_fn
 
-    n, L, NS, S, K = 256, 3, 4, 2, 2
-    leaf = rng.integers(0, L, n).astype(np.int32)
-    seg = rng.integers(0, S, (n, NS)).astype(np.int8)
-    labels = rng.integers(0, K, n).astype(np.int32)
-    w = np.ones(n, np.float32)
+    n, L, NS, S, K = 8 * LANES * 3, 3, 4, 2, 2
+    leaf = to_lines(rng.integers(0, L, n).astype(np.int32))
+    seg = to_lines(rng.integers(0, S, (NS, n)).astype(np.int8))
+    labels = to_lines(rng.integers(0, K, n).astype(np.int32))
+    w = to_lines(rng.integers(0, 4, n).astype(np.int32))
 
     single = np.asarray(_level_histogram(
         jnp.asarray(leaf), jnp.asarray(seg), jnp.asarray(labels),
-        jnp.asarray(w), L, NS, S, K))
+        jnp.asarray(w), L, S, K))
     shard = NamedSharding(mesh8, P(DATA_AXIS))
     step = distributed_tree_level_fn(mesh8, L, NS, S, K)
     dist = np.asarray(step(
-        jax.device_put(leaf, shard), jax.device_put(seg, shard),
+        jax.device_put(leaf, shard),
+        jax.device_put(seg, NamedSharding(mesh8, P(None, DATA_AXIS))),
         jax.device_put(labels, shard), jax.device_put(w, shard)))
-    np.testing.assert_allclose(dist, single, atol=1e-4)
+    assert dist.dtype == np.int32
+    np.testing.assert_array_equal(dist, single)
 
 
 def test_distributed_lr_step_matches_single_device(mesh8, rng):

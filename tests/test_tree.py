@@ -1,13 +1,21 @@
 """Decision tree / random forest: split enumeration, learning, model format."""
 
+import hashlib
 import json
+import os
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from avenir_tpu.core.dataset import Dataset
 from avenir_tpu.core.schema import FeatureSchema
 from avenir_tpu.data import generate_churn, churn_schema
+from avenir_tpu.models import tree as tree_mod
+from avenir_tpu.runner import run_job
+from avenir_tpu.utils.devices import device_report
+from chipbench import forest_reference as ref
+from chipbench import generate
 from avenir_tpu.models.tree import (
     DecisionPathList,
     DecisionTreeBuilder,
@@ -294,3 +302,217 @@ class TestDevicePathEvaluator:
             again.predict(test, ["no", "yes"]),
             DevicePathEvaluator([again], HANGUP_SCHEMA,
                                 ["no", "yes"]).predict(test))
+
+
+# ---------------------------------------------------------------------------
+# the level pass: exact counts, in row blocks, one form for every caller;
+# the forest against the plain reference (chipbench/forest_reference.py)
+# ---------------------------------------------------------------------------
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                     "chipbench")
+with open(os.path.join(BENCH, "configs", "rf-hangup.json")) as _fh:
+    RF_HANGUP = json.load(_fh)
+
+
+def call_hangup_rows(n, seed, tmp_path):
+    """(train file, schema file, codes [n, d], y [n]) of n rows of the
+    benchmark's call-hangup deployment, written by its own input module."""
+    one_csv = generate.load_module(BENCH, "inputs", "one_csv_bulk")
+    module = generate.load_module(BENCH, "generators", "call_hangup")
+    gen, schema = RF_HANGUP["generator"], RF_HANGUP["schema"]
+    fields = one_csv.feature_fields(schema)
+    codes, y = module.draw(generate.seed_for(seed, 0), n, gen, fields)
+    ids, unread = module.draw_unread(generate.seed_for(seed, 1), n, gen)
+    train = tmp_path / "train.csv"
+    train.write_bytes(one_csv.format_rows(ids, unread, codes, y, gen, fields, 6))
+    schema_path = tmp_path / "schema.json"
+    schema_path.write_text(json.dumps(schema))
+    return str(train), str(schema_path), codes, y
+
+
+def forest_properties(schema_path, **more):
+    props = {k: v.format(schema=schema_path)
+             for k, v in RF_HANGUP["properties"].items()}
+    props.update(more)
+    return props
+
+
+def paths_of(model):
+    """{predicates: (population, {class: share})} of a DecisionPathList."""
+    return {tuple(ref.predicate_key(p.to_json()) for p in path.predicates):
+            (path.population, path.class_val_pr) for path in model.paths}
+
+
+@pytest.mark.parametrize("rows, trees, depth", [
+    (16_384, 4, 2), (16_384, 10, 3), (65_536, 4, 3), (65_536, 10, 2)])
+def test_forest_agrees_with_the_plain_reference(tmp_path, rows, trees, depth):
+    """Tree by tree and path by path: the same predicates, populations
+    equal as integers, shares within 1e-6. Where two candidate splits tie
+    in gini within 1e-12 (the issue partitions that differ only in `cable`
+    tie exactly among business callers, who never have it) either is
+    right: the reference then follows the program's."""
+    train, schema_path, codes, y = call_hangup_rows(rows, rows + trees, tmp_path)
+    props = forest_properties(schema_path, **{
+        "dtb.num.trees": str(trees), "dtb.max.depth.limit": str(depth)})
+    forest = run_job("randomForest", props, [train], str(tmp_path / "out")).payload
+    got = [paths_of(tr) for tr in forest.trees]
+    sem = ref.job_semantics(props)
+    assert (sem["trees"], sem["max_depth"]) == (trees, depth)
+    weights = ref.bootstrap_weights(0, rows, trees, sem["sampling"])
+
+    def choose(t):
+        def pick(preds, allowed, scores):
+            for i, s in enumerate(allowed):
+                taken = all(preds + (p,) in
+                            {k[:len(preds) + 1] for k in got[t]}
+                            for p in s["predicates"][:1])
+                if taken and scores[i] <= min(scores) + 1e-12:
+                    return i
+            return int(np.argmin(scores))
+        return pick
+
+    want = ref.grow_forest(codes.astype(np.int64), y.astype(np.int64), weights,
+                           RF_HANGUP["schema"], 2, sem, 0, choose)
+    classes = forest.class_values
+    assert len(got) == trees
+    for t, paths in enumerate(want):
+        assert set(got[t]) == {p["predicates"] for p in paths}, t
+        for p in paths:
+            population, shares = got[t][p["predicates"]]
+            assert population == int(p["counts"].sum())
+            for c, name in enumerate(RF_HANGUP["generator"]["classes"]):
+                assert abs(shares[name] - p["counts"][c] / p["counts"].sum()) < 1e-6
+        assert sorted(classes) == ["F", "T"]
+
+
+def lines(rng, shape, high, dtype):
+    return tree_mod.to_lines(rng.integers(0, high, shape).astype(dtype))
+
+
+def host_counts(leaf, seg, labels, w, n_leaves, smax, k):
+    """[L, NS, S, K] by np.bincount in int64, one split at a time (float64
+    weights add whole numbers exactly below 2^53)."""
+    base = np.asarray(leaf, np.int64) * smax
+    weights = np.asarray(w, np.float64)
+    flat = np.stack([
+        np.bincount((base + col) * k + labels, weights=weights,
+                    minlength=n_leaves * smax * k) for col in seg])
+    return np.asarray(np.rint(flat), np.int64).reshape(
+        len(seg), n_leaves, smax, k).transpose(1, 0, 2, 3)
+
+
+def test_counts_are_exact_past_two_to_the_24th():
+    """Whole-number weights whose root cells sum above 2^25 and are odd:
+    the level pass equals np.bincount in int64 exactly; a float32
+    accumulator, which the pass had, does not."""
+    rng = np.random.default_rng(5)
+    n, ns = 20_000, 3
+    leaf = np.zeros(n, np.int32)
+    seg = rng.integers(0, 2, (ns, n)).astype(np.int8)
+    labels = rng.integers(0, 2, n).astype(np.int32)
+    w = (2 * rng.integers(3_000, 5_000, n) + 1).astype(np.int32)
+    want = host_counts(leaf, seg, labels, w, 1, 2, 2)
+    assert want.min() > 2 ** 25 and (want % 2 == 1).any()
+    got = np.asarray(tree_mod._level_histogram(
+        *(jnp.asarray(tree_mod.to_lines(a)) for a in (leaf, seg, labels, w)),
+        1, 2, 2, digits=tree_mod._weight_digits(w.max())))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
+    cell = (seg[0] == 0) & (labels == 0)
+    in_float32 = np.cumsum(w[cell].astype(np.float32), dtype=np.float32)[-1]
+    assert int(in_float32) != want[0, 0, 0, 0]
+
+
+def test_fit_counts_whole_weights_exactly_and_refuses_fractions():
+    ds = hangup_data(4_000, seed=3)
+    rng = np.random.default_rng(4)
+    w = 2 * rng.integers(4_000, 9_000, len(ds)) + 1
+    model = DecisionTreeBuilder(HANGUP_SCHEMA, "giniIndex", max_depth=1
+                                ).fit(ds, row_weights=w)
+    assert sum(p.population for p in model.paths) == int(w.sum()) > 2 ** 25
+    for p in model.paths:
+        pred = p.predicates[0]
+        col = np.asarray(ds.column(pred.attribute))
+        keep = (col < pred.value if pred.operator == "lt" else
+                col >= pred.value) if pred.operator != "in" else np.isin(
+                    col, [HANGUP_SCHEMA.field_by_ordinal(pred.attribute)
+                          .cardinality_index()[v] for v in pred.cat_values])
+        assert p.population == int(w[keep].sum())
+    for bad in (np.full(len(ds), 0.5), np.full(len(ds), -1.0),
+                np.full(len(ds), 2.0 ** 31), np.ones(3)):
+        with pytest.raises(ValueError, match="row_weights"):
+            DecisionTreeBuilder(HANGUP_SCHEMA).fit(ds, row_weights=bad)
+
+
+@pytest.mark.parametrize("n, block_lines", [
+    (128 * 37 + 5, 8),       # four whole blocks, five lines and a part-line
+    (128 * 37 + 5, 64),      # one block only, the lines fewer than a block
+    (128 * 64, 64),          # one block exactly
+    (100, 8)])               # less than a line
+def test_blocked_equals_unblocked(n, block_lines):
+    rng = np.random.default_rng(n)
+    t, ns, n_leaves, smax, k = 3, 5, 4, 3, 2
+    leaf = rng.integers(0, n_leaves, (t, n)).astype(np.int32)
+    seg = rng.integers(0, smax, (ns, n)).astype(np.int8)
+    labels = rng.integers(0, k, n).astype(np.int32)
+    w = rng.integers(0, 300, (t, n)).astype(np.int32)
+    args = [jnp.asarray(tree_mod.to_lines(a)) for a in (leaf, seg, labels, w)]
+    digits = tree_mod._weight_digits(int(w.max()))
+    assert digits == 2
+    got = np.asarray(tree_mod._level_histogram_forest(
+        *args, n_leaves=n_leaves, smax=smax, k=k, digits=digits,
+        block_lines=block_lines))
+    for i in range(t):
+        np.testing.assert_array_equal(
+            got[i], host_counts(leaf[i], seg, labels, w[i], n_leaves, smax, k))
+    # the advance, in the same blocks, against the plain gather
+    best = rng.integers(-1, ns, (t, n_leaves)).astype(np.int32)
+    off = rng.integers(4, 9, (t, n_leaves)).astype(np.int32)
+    moved = np.asarray(tree_mod._advance_leaves_forest(
+        args[0], args[1], jnp.asarray(best), jnp.asarray(off),
+        block_lines=block_lines)).reshape(t, -1)[:, :n]
+    split = np.take_along_axis(best, leaf, axis=1)
+    under = np.take_along_axis(seg, np.maximum(split, 0), axis=0)
+    want = np.where(split >= 0, np.take_along_axis(off, leaf, axis=1) + under,
+                    leaf)
+    np.testing.assert_array_equal(moved, want)
+
+
+def test_a_second_forest_job_compiles_nothing_and_repeats_its_bytes(tmp_path):
+    train, schema_path, _codes, _y = call_hangup_rows(16_384, 9, tmp_path)
+    props = forest_properties(schema_path)
+    shas = []
+    for out in ("out_a", "out_b"):
+        before = device_report()
+        res = run_job("randomForest", props, [train], str(tmp_path / out))
+        after = device_report()
+        shas.append([hashlib.sha256(open(p, "rb").read()).hexdigest()
+                     for p in res.outputs])
+    assert after["xla_compiles"] == before["xla_compiles"]
+    assert shas[0] == shas[1] and len(shas[0]) == 10
+
+
+@pytest.mark.parametrize("more, holds", [
+    ({"dtb.split.attribute.selection.strategy": "notUsedYet",
+      "dtb.sub.sampling.strategy": "none"}, "every tree is decTree's"),
+    ({"dtb.path.stopping.strategy": "minPopulation",
+      "dtb.min.population.limit": "1000000"}, "roots only"),
+    ({"dtb.path.stopping.strategy": "minInfoGain",
+      "dtb.min.info.gain.limit": "0.4"}, "roots only")])
+def test_random_forest_reads_the_keys_dec_tree_reads(tmp_path, more, holds):
+    """`dtb.split.attribute.selection.strategy`, `dtb.min.info.gain.limit`
+    and `dtb.min.population.limit`, which the forest job used to ignore."""
+    train, schema_path, _codes, _y = call_hangup_rows(16_384, 11, tmp_path)
+    props = forest_properties(schema_path, **more)
+    forest = run_job("randomForest", props, [train], str(tmp_path / "rf")).payload
+    if holds == "roots only":
+        assert all(len(tr.paths) == 1 and not tr.paths[0].predicates
+                   for tr in forest.trees)
+        plain = run_job("randomForest", forest_properties(schema_path), [train],
+                        str(tmp_path / "rf0")).payload
+        assert all(len(tr.paths) > 1 for tr in plain.trees)
+    else:
+        single = run_job("decTree", props, [train], str(tmp_path / "dt")).payload
+        assert len(single.paths) > 1
+        for tr in forest.trees:
+            assert tr.to_json() == single.to_json()
