@@ -354,18 +354,29 @@ class NearestNeighborClassifier:
         # class-conditional weighting: P(features_i | class_i) per train row,
         # the quantity jobs (2)-(4) of the reference pipeline compute + join
         # (BayesianPredictor bap.output.feature.prob.only=true mode) — the
-        # same NaiveBayesPredictor.feature_prob the file-based job emits
-        prob = None
+        # same NaiveBayesPredictor.feature_prob the file-based job emits,
+        # made on the device and left there
+        post = None
         if class_cond_weighted:
             from avenir_tpu.models.naive_bayes import NaiveBayesPredictor
 
-            model = nb_model if nb_model is not None else NaiveBayesModel.fit(train)
-            prob = NaiveBayesPredictor(model).feature_prob(train)
+            if nb_model is not None:
+                post = NaiveBayesPredictor(nb_model).feature_prob_device(train)
+            else:
+                # the train rows go to the device once, for the fold and
+                # the posterior, and are dropped on return: nothing of
+                # them stands beside the top-k kernel's scratch (a slice
+                # of the whole is the array itself, no copy)
+                _, post = NaiveBayesPredictor.fit_feature_prob(
+                    train, self.train_labels[:n_valid])
         with obs.span("knn.index.put") as note:
-            post = np.ones((pad,), np.float32)
-            if prob is not None:
-                post[: len(train)] = prob.astype(np.float32)
-            self.train_post = jnp.asarray(post)
+            # ones where nothing weights a row, and beyond the last row
+            if post is None:
+                post = jnp.ones((pad,), jnp.float32)
+            elif n_valid < pad:
+                post = jnp.concatenate(
+                    [post, jnp.ones((pad - n_valid,), jnp.float32)])
+            self.train_post = post
             note["nbytes"] = post.nbytes
 
     # ------------------------------------------------------------- neighbors

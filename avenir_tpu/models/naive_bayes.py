@@ -385,6 +385,43 @@ def _fold_batch_kernel(acc, codes, labels, x_cont, w, k: int, bmax: int):
     return jax.tree.map(lambda a, b: a + b.astype(a.dtype), acc, batch)
 
 
+def _table_at(table, idx):
+    """table[f, idx[i, f]] as [n, F], for a table [F, J] and idx [n, F] or
+    [n, 1]: a select for each of the J columns. Exact, fused into the
+    pass over the rows, and no gather (whose index tensor alone is 10 GB
+    at 21M rows on a TPU)."""
+    out = jnp.broadcast_to(table[:, 0], idx.shape[:1] + table.shape[:1])
+    for j in range(1, table.shape[1]):
+        out = jnp.where(idx == j, table[:, j], out)
+    return out
+
+
+@jax.jit
+def _feature_prob_kernel(codes, labels, x_cont, tables):
+    """P(features | own class) of every row, float32 [n]: the product over
+    binned features of P(bin | class y) and over continuous features of
+    the Gaussian density under class y. One pass over the rows; a row's
+    own class alone, never the [n, Fc, K] densities that `predict` builds.
+    A weight under float32's least normal number reads 0.0 where the
+    device flushes subnormals, and `knn._vote` then leaves that
+    neighbour's score unweighted."""
+    own = labels[:, None]
+    logp = jnp.zeros(labels.shape, jnp.float32)
+    if codes.shape[1]:
+        log_post = tables["log_post"]                               # [F, K, B]
+        logp += _table_at(log_post.reshape(log_post.shape[0], -1),
+                          own * log_post.shape[2] + codes).sum(axis=1)
+    if x_cont.shape[1]:
+        mean, std = tables["cont_mean"], tables["cont_std"]         # [Fc, K]
+        # what does not depend on the row is summed per class first, so
+        # the per-row sum stays small and rounds little in float32
+        const = -(0.5 * math.log(2 * math.pi) * mean.shape[0]
+                  + jnp.log(std).sum(axis=0, keepdims=True))        # [1, K]
+        z = (x_cont - _table_at(mean, own)) / _table_at(std, own)   # [n, Fc]
+        logp += _table_at(const, own)[:, 0] - 0.5 * (z * z).sum(axis=1)
+    return jnp.exp(logp)
+
+
 class NaiveBayesPredictor:
     """Jitted posterior computation + arbitration over a finished model."""
 
@@ -452,41 +489,49 @@ class NaiveBayesPredictor:
         cm.add(dataset.labels(), pred)
         return cm
 
+    @classmethod
+    def fit_feature_prob(cls, dataset: Dataset,
+                         labels: Optional[jnp.ndarray] = None
+                         ) -> Tuple["NaiveBayesPredictor", jnp.ndarray]:
+        """A predictor fitted on `dataset`, and `feature_prob_device` of
+        the same rows: they go to the device once and serve the fold and
+        the posterior. `labels` are the dataset's, where the caller has
+        them on the device already."""
+        with obs.span("nb.fit", rows=len(dataset)):
+            model = NaiveBayesModel.empty(dataset.schema)
+            codes, _ = dataset.feature_codes(model.binned_fields)
+            rows = (jnp.asarray(codes),
+                    jnp.asarray(dataset.labels()) if labels is None else labels,
+                    jnp.asarray(dataset.feature_matrix(model.cont_fields)))
+            model.accumulate(*rows)
+            predictor = cls(model)
+        return predictor, predictor.feature_prob_device(dataset, rows)
+
     def feature_prob(self, dataset: Dataset) -> np.ndarray:
         """Per-row P(features | actual class): the bap.output.feature.prob.only
         mode whose output the reference's KNN pipeline joins as
-        class-conditional weights (BayesianPredictor.java:262-286)."""
+        class-conditional weights (BayesianPredictor.java:262-286).
+        float32 [n], computed on the device and fetched."""
+        return np.asarray(self.feature_prob_device(dataset))
+
+    def feature_prob_device(self, dataset: Dataset,
+                            rows: Optional[tuple] = None) -> jnp.ndarray:
+        """The same, left on the device as dispatched. `rows` are the
+        dataset's (codes, labels, x_cont) as `accumulate` takes them,
+        where the caller has them on the device already."""
         with obs.span("nb.feature_prob", rows=len(dataset),
                       binned=len(self.model.binned_fields),
                       continuous=len(self.model.cont_fields)):
-            logp = np.zeros(len(dataset), np.float64)
             with obs.span("nb.feature_prob.binned"):
-                y = dataset.labels()
-                self._add_binned(dataset, y, logp)
+                if rows is None:
+                    codes, _ = dataset.feature_codes(self.model.binned_fields)
+                    labels = dataset.labels()
+                else:
+                    codes, labels = rows[:2]
             with obs.span("nb.feature_prob.continuous"):
-                self._add_continuous(dataset, y, logp)
-                return np.exp(logp)
-
-    def _add_binned(self, dataset: Dataset, y: np.ndarray,
-                    logp: np.ndarray) -> None:
-        """Add to `logp`, per row, log P(bin | class y) of each binned
-        feature."""
-        codes, _ = dataset.feature_codes(self.model.binned_fields)
-        if codes.shape[1]:
-            lp = np.asarray(self.tables["log_post"])       # [F, K, B]
-            for f in range(codes.shape[1]):
-                logp += lp[f, y, codes[:, f]]
-
-    def _add_continuous(self, dataset: Dataset, y: np.ndarray,
-                        logp: np.ndarray) -> None:
-        """The same for each continuous feature (Gaussian density). The
-        matrix and the loop's row-long temporaries are this call's own,
-        released when it returns."""
-        x_cont = dataset.feature_matrix(self.model.cont_fields)
-        if x_cont.shape[1]:
-            mean = np.asarray(self.tables["cont_mean"])    # [Fc, K]
-            std = np.asarray(self.tables["cont_std"])
-            for f in range(x_cont.shape[1]):
-                m, s = mean[f, y], std[f, y]
-                logp += (-0.5 * np.log(2 * np.pi) - np.log(s)
-                         - 0.5 * ((x_cont[:, f] - m) / s) ** 2)
+                # a matrix made here is a temporary of the call, so that
+                # its release falls inside the span as its making does
+                return _feature_prob_kernel(
+                    jnp.asarray(codes), jnp.asarray(labels),
+                    jnp.asarray(dataset.feature_matrix(self.model.cont_fields))
+                    if rows is None else rows[2], self.tables)

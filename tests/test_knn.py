@@ -102,6 +102,38 @@ class TestClassification:
         assert (lo == 1).sum() > (hi == 1).sum()
 
 
+def test_weighted_scores_match_a_float64_vote_from_one_copy_of_the_rows(monkeypatch):
+    """The class-conditional weights are made on the device from the rows
+    the NB fold was given: the scores are those of a vote reckoned here in
+    float64 over the classifier's own neighbours, and the train matrix is
+    stacked once for the index and once for fold and posterior together."""
+    from avenir_tpu.models.naive_bayes import NaiveBayesModel, NaiveBayesPredictor
+    from tests.test_naive_bayes import feature_prob_f64, log_calls
+
+    train = generate_elearn(20_000, seed=21)
+    test = generate_elearn(200, seed=22)
+    want_post = feature_prob_f64(
+        NaiveBayesPredictor(NaiveBayesModel.fit(train)), train)
+    stacked = log_calls(monkeypatch, "feature_matrix")
+    clf = NearestNeighborClassifier(
+        train, top_match_count=5, kernel_function="gaussian", kernel_param=30.0,
+        class_cond_weighted=True, block=1024)
+    assert stacked == ["feature_matrix"] * 2
+    assert clf.train_post.shape == clf.train_labels.shape == (clf.index.n_padded,)
+    assert clf.index.n_padded > len(train)
+    np.testing.assert_array_equal(np.asarray(clf.train_post[len(train):]), 1.0)
+    np.testing.assert_allclose(np.asarray(clf.train_post[:len(train)]),
+                               want_post, rtol=2e-5)
+    _, scores = clf.predict(test)
+    dist, idx = (np.asarray(a) for a in clf.neighbors(test))
+    t = np.floor(dist.astype(np.float64) * KERNEL_SCALE) / 30.0
+    score = np.floor(KERNEL_SCALE * np.exp(-0.5 * t * t)) * want_post[idx]
+    want = np.stack([(score * (train.labels()[idx] == c)).sum(axis=1)
+                     for c in range(2)], axis=1)
+    assert (want.sum(axis=1) > 0).all()
+    np.testing.assert_allclose(scores, want, rtol=1e-4, atol=0)
+
+
 class TestRegression:
     def test_average_and_median(self, elearn_train, elearn_test):
         target = elearn_train.feature_matrix()[:, 0] * 2.0

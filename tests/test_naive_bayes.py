@@ -3,6 +3,8 @@
 import numpy as np
 import pytest
 
+from avenir_tpu.core.dataset import Dataset
+from avenir_tpu.core.schema import FeatureSchema
 from avenir_tpu.data import generate_churn, churn_schema
 from avenir_tpu.models.naive_bayes import NaiveBayesModel, NaiveBayesPredictor
 from avenir_tpu.utils.metrics import CostBasedArbitrator
@@ -88,6 +90,129 @@ class TestPredict:
         pred_def, _ = NaiveBayesPredictor(model).predict(churn)
         # heavy positive-miss cost -> at least as many positive predictions
         assert (pred_arb == 1).sum() >= (pred_def == 1).sum()
+
+
+#: the e-learning deployment's nine activity maxima (chipbench/configs)
+ACTIVITY_MAX = (600, 200, 100, 28, 100, 100, 280, 180, 26)
+
+
+def mixed_dataset(n, seed, binned=True, continuous=True):
+    """Two classes; nine whole-number activity fields on the benchmark's
+    levels (0.44 / 0.56 of each maximum, sigma 0.12 of it), a categorical
+    and a bucketed int: whichever kinds are asked for, built from columns."""
+    rng = np.random.default_rng(seed)
+    y = (rng.random(n) < 0.5).astype(np.int32)
+    fields = [{"name": "id", "ordinal": 0, "id": True, "dataType": "string"}]
+    columns = {0: np.array([f"R{i}" for i in range(n)], dtype=object)}
+    if continuous:
+        hi = np.array(ACTIVITY_MAX, np.float64)
+        x = np.clip(np.rint(rng.normal(
+            np.where(y, 0.56, 0.44)[:, None], 0.12, (n, len(hi))) * hi), 0, hi)
+        for j, top in enumerate(ACTIVITY_MAX):
+            fields.append({"name": f"a{j}", "ordinal": len(fields),
+                           "dataType": "int", "feature": True,
+                           "min": 0, "max": top})
+            columns[len(fields) - 1] = x[:, j].astype(np.float32)
+    if binned:
+        fields.append({"name": "plan", "ordinal": len(fields),
+                       "dataType": "categorical", "feature": True,
+                       "cardinality": ["a", "b", "c", "d", "e"]})
+        columns[len(fields) - 1] = np.minimum(
+            rng.integers(0, 5, n) + y * rng.integers(0, 2, n), 4).astype(np.int32)
+        fields.append({"name": "age", "ordinal": len(fields), "dataType": "int",
+                       "feature": True, "min": 0, "max": 120, "bucketWidth": 12})
+        columns[len(fields) - 1] = np.where(
+            y == 0, rng.integers(12, 120, n), rng.integers(0, 48, n)
+        ).astype(np.float32)
+    fields.append({"name": "status", "ordinal": len(fields),
+                   "dataType": "categorical", "cardinality": ["fail", "pass"]})
+    columns[len(fields) - 1] = y
+    return Dataset(FeatureSchema.from_json({"fields": fields}), columns, n)
+
+
+def feature_prob_f64(predictor, ds):
+    """P(features | own class) of every row, reckoned plainly in float64
+    numpy from the predictor's tables."""
+    log_post, mean, std = (np.float64(predictor.tables[name]) for name in (
+        "log_post", "cont_mean", "cont_std"))
+    y = ds.labels()
+    logp = np.zeros(len(ds), np.float64)
+    codes, _ = ds.feature_codes(predictor.model.binned_fields)
+    for f in range(codes.shape[1]):
+        logp += log_post[f, y, codes[:, f]]
+    x = np.float64(ds.feature_matrix(predictor.model.cont_fields))
+    for f in range(x.shape[1]):
+        m, s = mean[f, y], std[f, y]
+        logp += -0.5 * np.log(2 * np.pi) - np.log(s) - 0.5 * ((x[:, f] - m) / s) ** 2
+    return np.exp(logp)
+
+
+def log_calls(monkeypatch, *names):
+    """The list to which each call of `Dataset.<name>` appends its name."""
+    calls = []
+    for name in names:
+        def logged(self, *args, _inner=getattr(Dataset, name), _name=name):
+            calls.append(_name)
+            return _inner(self, *args)
+        monkeypatch.setattr(Dataset, name, logged)
+    return calls
+
+
+class TestFeatureProb:
+    @pytest.mark.parametrize("kinds, n", [
+        ({"binned": False}, 50_000), ({"continuous": False}, 5_000), ({}, 5_000)],
+        ids=["nine_continuous", "binned_only", "both_kinds"])
+    def test_device_program_against_float64_numpy(self, kinds, n):
+        ds = mixed_dataset(n, seed=5, **kinds)
+        pred = NaiveBayesPredictor(NaiveBayesModel.fit(ds))
+        assert (len(pred.model.cont_fields), len(pred.model.binned_fields)) == (
+            9 * kinds.get("continuous", True), 2 * kinds.get("binned", True))
+        got = pred.feature_prob(ds)
+        assert got.dtype == np.float32 and got.shape == (n,)
+        want = feature_prob_f64(pred, ds)
+        assert want.min() > 1e-36          # nothing near float32's floor
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=0)
+
+    def test_fitted_and_weighted_from_one_device_copy(self, monkeypatch):
+        """`fit_feature_prob` is `fit` and `feature_prob` of the same rows,
+        to the bit, from one stacking of the matrix and one of the codes."""
+        ds = mixed_dataset(5_000, seed=6)
+        apart = NaiveBayesPredictor(NaiveBayesModel.fit(ds)).feature_prob(ds)
+        calls = log_calls(monkeypatch, "feature_matrix", "feature_codes", "labels")
+        pred, post = NaiveBayesPredictor.fit_feature_prob(ds)
+        assert sorted(calls) == ["feature_codes", "feature_matrix", "labels"]
+        np.testing.assert_array_equal(np.asarray(post), apart)
+        np.testing.assert_array_equal(
+            pred.model.post_counts, NaiveBayesModel.fit(ds).post_counts)
+
+    def test_same_array_on_two_calls(self):
+        ds = mixed_dataset(5_000, seed=7)
+        pred = NaiveBayesPredictor(NaiveBayesModel.fit(ds))
+        first = pred.feature_prob(ds)
+        assert first.tobytes() == pred.feature_prob(ds).tobytes()
+        assert first.tobytes() == np.asarray(pred.feature_prob_device(ds)).tobytes()
+
+    def test_weight_under_float32_reads_zero_and_the_vote_falls_back(self):
+        from avenir_tpu.models.knn import _vote
+
+        ds = mixed_dataset(2_000, seed=8, binned=False)
+        pred = NaiveBayesPredictor(NaiveBayesModel.fit(ds))
+        # one row at the far corner: 3.7 sigma and more off on each of nine
+        # fields, under class 0
+        for j in range(9):
+            ds.columns[j + 1][0] = ACTIVITY_MAX[j]
+        ds.columns[10][0] = 0
+        got, want = pred.feature_prob(ds), feature_prob_f64(pred, ds)
+        assert 0 < want[0] < 1e-46 and got[0] == 0.0
+        np.testing.assert_allclose(got[1:], want[1:], rtol=2e-5)
+        # as a neighbour it votes its plain score; the others are weighted
+        labels = np.array([[0, 1, 1]], np.int32)
+        scores = np.asarray(_vote(
+            np.zeros((1, 3), np.float32), labels, got[None, :3], "none", 1.0,
+            2, True, False))
+        np.testing.assert_allclose(
+            scores[0], [1.0, float(got[1]) + float(got[2])], rtol=1e-6)
+        assert got[1] > 0 and got[2] > 0
 
 
 class TestModelFile:
