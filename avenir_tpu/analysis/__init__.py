@@ -10,8 +10,7 @@ Entry points:
     script (avenir_tpu.analysis.cli) — text or ``--json`` output;
     ``graftlint --ir`` runs the IR layer instead of source paths;
   - :func:`run_paths` — the in-process AST API (tests/test_graftlint.py
-    runs it over the whole package; bench_scaling.py tripwires on its
-    counts);
+    runs it over the whole gated surface);
   - ``avenir_tpu.analysis.ir.run_ir`` — the IR layer: jaxpr rules +
     the distributed-family collective-payload audit over the kernel
     manifest (``avenir_tpu.analysis.manifest``). Imported lazily, never

@@ -43,12 +43,13 @@ Eight modes sharing one report/baseline/exit contract, plus ``--all``:
   and is never allowlistable.
 - All (``--all``): the eight tiers in ONE process — combined JSON
   under a ``modes`` key (each tier's report carries its ``wall_s``)
-  and a single worst-of exit code (one command for CI and the bench
-  tripwire's local reproduction). ``--all --parallel`` fans the tiers
+  and a single worst-of exit code (the operator's one command for
+  the audits at full depth). ``--all --parallel`` fans the tiers
   out as subprocesses — same combined JSON, same worst-of exit, the
   wall clock of the slowest tier instead of the sum.
 
-Exit-code contract (stable — bench_scaling.py and CI tripwire on it):
+Exit-code contract (stable: the tests/test_graftlint*.py CLI tests
+hold it, tier by tier):
   0  clean: no findings, no stale baseline entries, no parse errors
   1  findings — non-allowlisted findings, stale baseline entries, or
      parse errors in the linted sources
@@ -182,8 +183,8 @@ def _bootstrap_ir_env() -> None:
     tier-1 test process — already initialized a big-enough pool).
 
     An inherited ``--xla_force_host_platform_device_count`` SMALLER than
-    the audit needs is raised, not honored: callers like bench_scaling
-    legitimately export a small pool for their own mesh, and inheriting
+    the audit needs is raised, not honored: a parent may legitimately
+    export a small pool for its own mesh, and inheriting
     it would turn a clean audit into a spurious trace error.
     ``GRAFTLINT_IR_DEVICES`` overrides the target pool size explicitly
     (the too-small-pool CLI test uses it; a real run never should)."""
@@ -397,8 +398,8 @@ def _run_all(args, baseline, wanted: Optional[List[str]]) -> int:
 
     A ``--rules`` subset skips every tier it names no rules of (its
     audit included only when the tier's audit pseudo-rule is named), so
-    fixture-level CI checks stay fast; the full run is what the bench
-    tripwire executes every round."""
+    fixture-level CI checks stay fast; the full run is the operator's
+    ``python tools/graftlint.py --all``."""
     if args.parallel:
         return _run_all_parallel(args, wanted)
     import time

@@ -86,8 +86,7 @@ class Report:
     verdict. `key_audit` is filled only by keys runs
     (analysis/keys.py): one entry per registered key site with its
     perturbation verdict. Other modes leave them empty — the keys are always
-    present in the JSON so downstream tripwires can parse one
-    schema."""
+    present in the JSON so whoever reads it parses one schema."""
 
     findings: List[Finding] = field(default_factory=list)
     suppressed: List[Finding] = field(default_factory=list)

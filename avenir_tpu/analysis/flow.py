@@ -721,8 +721,7 @@ def audit_stream(spec) -> Tuple[dict, Optional[Finding]]:
 def default_flow_paths(root: str) -> List[str]:
     """The gated repo surface, mirroring tests/test_graftlint.py: the
     package plus every host-side caller of it."""
-    names = ["avenir_tpu", "tests", "docs", "tools", "bench.py",
-             "bench_scaling.py", "__graft_entry__.py"]
+    names = ["avenir_tpu", "tests", "docs", "tools", "__graft_entry__.py"]
     return [p for p in (os.path.join(root, n) for n in names)
             if os.path.exists(p)]
 

@@ -106,7 +106,8 @@ def _op_entries() -> List[KernelSpec]:
 
     def pallas(_mesh):
         # interpret mode: the kernel traces (and its jaxpr is lintable)
-        # with no TPU attached; the compiled path is bench.py's job
+        # with no TPU attached; the compiled path is
+        # tools/tpu_kernel_check.py's job
         return (lambda q, t: pallas_knn.knn_topk_pallas(
                     q, t, k=5, block_q=128, block_t=256, interpret=True),
                 (_sds((128, 8), np.float32), _sds((256, 8), np.float32)))
@@ -411,7 +412,7 @@ def _churn_corpus(workdir: str) -> dict:
 
 def _seq_corpus(workdir: str) -> dict:
     """Markov/miner corpus: 3-state token sequences with a class column,
-    the bench_scaling.miner_tripwire shape at auditor size."""
+    at auditor size."""
     rng = np.random.default_rng(12)
     states = ["L", "M", "H"]
     csv = os.path.join(workdir, "seq.csv")

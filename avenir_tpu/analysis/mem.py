@@ -2,9 +2,8 @@
 plus the mechanical RSS/live-bytes auditor.
 
 The flow tier (analysis/flow.py) proves streamed folds *deterministic*;
-nothing yet proves them *admissible* — the 3,072MB RSS ceiling the scale
-runs assert (tools/stream_scale_check.py) is only learned after a
-100M-row scan finishes. Both framework papers this repo leans on say
+nothing yet proves them *admissible* — whether a scan stays under the
+3,072MB RSS ceiling is only learned after it finishes. Both framework papers this repo leans on say
 memory is the product once folds are vectorized: buffer sizing dominates
 on SIMD-saturated MapReduce (arXiv:1309.0215) and ingest/buffer overhead
 is the Spark-vs-MPI gap (arXiv:1811.04875). A resident multi-tenant job
@@ -40,8 +39,8 @@ Tolerance policy (documented in docs/graftlint.md): at auditor scale
 (about a 1MB proxy corpus) the band's job is to catch order-of-magnitude
 model breakage and keep the oracle's mechanics proven every round; the
 true model error is recorded at real scale by the
-``Mem:PredictedPeakBytes`` / ``Mem:PeakRSS`` counters every 100M-row
-anchor writes (tools/stream_scale_check.py).
+``Mem:PredictedPeakBytes`` / ``Mem:PeakRSS`` counters every streamed
+job's result carries.
 
 Findings flow through the shared engine (same ``path::rule::scope``
 keys, same allowlist baseline); entry points: ``graftlint --mem``
@@ -822,7 +821,7 @@ def memory_manifest(block_sizes_mb: Sequence[float] = (64.0, 8.0),
     future job server consumes: per streamed job x block size, the
     predicted peak host bytes against a nominal unbounded corpus (churn
     schema for the tabular jobs); plus the per-kernel device live bytes.
-    Written next to STREAM_SCALE_*.json by bench_scaling's tripwire."""
+    Derived live wherever it is needed; no file holds it."""
     from avenir_tpu.data import churn_schema
 
     schema = churn_schema()

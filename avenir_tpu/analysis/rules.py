@@ -156,8 +156,10 @@ class RecompileHazardRule(Rule):
     cache); (b) a jitted function using a plain parameter as a shape
     without marking it static; (c) a jitted closure using an enclosing
     function's local as a shape — re-traced for every distinct value.
-    utils.metrics.jit_cache_size is the runtime cross-check bench_scaling
-    asserts, so this rule can't silently rot."""
+    utils.metrics.jit_cache_size is the runtime cross-check
+    (tests/test_stream_jobs.py::test_streamed_miners_compile_within_their_shape_buckets,
+    tests/test_shared_scan.py::test_fused_scan_adds_no_nb_fold_variant),
+    so this rule can't silently rot."""
 
     rule_id = "recompile-hazard"
     description = "jit wrapper or shape argument that defeats the compile cache"

@@ -342,8 +342,7 @@ class SharedScan:
         """Drive the scan: one pull per chunk, every sink sees it.
         Returns the number of chunks scanned. Each sink call records a
         ``stream.fold`` span and every chunk's full fan-out feeds the
-        process-global ``chunk_latency_ms`` histogram — the per-chunk
-        telemetry the obs tripwire proves is <=3% overhead."""
+        process-global ``chunk_latency_ms`` histogram."""
         n = 0
         it = iter(self._chunks)
         try:

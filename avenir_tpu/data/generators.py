@@ -3,7 +3,7 @@
 The reference has no tests; its generators (resource/telecom_churn.py,
 resource/elearn.py, ...) produce CSV whose class correlates with feature
 distributions. These are seedable equivalents producing Datasets (and CSV)
-against reference-style schemas, used by the test suite and bench.py.
+against reference-style schemas, used by the test suite and the tools.
 """
 
 from __future__ import annotations

@@ -17,10 +17,11 @@ encoded-block caches while the coordinator only publishes candidate
 manifests and merges supports. The TPU/GPU psum merge lives behind the
 backend gate in :mod:`avenir_tpu.dist.collective`.
 
-Gated by ``bench_scaling.shard_tripwire``: 2-process byte-identity +
-capacity-scaled speedup floor (single-pass families AND the miner
-per-k leg), plus a SIGSTOP chaos leg asserting the tail completes
-redundantly with ``Shard:DedupBlocks >= 1`` and zero lost blocks.
+Held by ``tests/test_dist.py::TestRunSharded``: 2-process byte-identity
+(single-pass families AND the miner per-k leg, with
+``Shard:PerKRounds >= 1``), and a held straggler whose block is stolen,
+folded redundantly and deduped (``Shard:DedupBlocks >= 1``) to the solo
+bytes.
 """
 
 from avenir_tpu.dist.detect import (StragglerPolicy, mirror_after_s,

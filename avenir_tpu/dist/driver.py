@@ -504,8 +504,7 @@ def run_sharded(name: str, conf, inputs: Sequence[str], output: str,
             res.counters["Shard:PerKRounds"] = float(mined["rounds"])
             res.counters["Shard:PerKBlocks"] = float(mined["blocks"])
             # the distributed per-k phase's wall (pass-1 merge through
-            # final.json) — the denominator of the per-k speedup the
-            # shard_tripwire miner leg and stream_scale_check record
+            # final.json)
             res.counters["Shard:PerKSeconds"] = round(
                 mined["perk_s"], 4)
         return res

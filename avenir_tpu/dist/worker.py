@@ -7,8 +7,7 @@ The loop:
 1. **Boot barrier** — write ``ready/w<i>`` once imports and the plan
    load are done, then wait for the coordinator's ``go`` file. The
    measured sharded wall starts at ``go``, so interpreter/jax boot
-   (paid once per worker, concurrently) never skews the scan A/B — the
-   same protocol the fleet tripwire uses with its warmup requests.
+   (paid once per worker, concurrently) is no part of it.
 2. **Home blocks** — claim and fold this worker's contiguous home run
    first (disk-sequential reads).
 3. **Steal the tail** — when the home run is done, claim from the
@@ -64,8 +63,8 @@ from avenir_tpu.dist.plan import ShardBlock, ShardPlan, load_plan
 #: "worker:block:secs" makes that worker sleep that long after CLAIMING
 #: the pass-1 block and before folding it; "worker:level:block:secs"
 #: (level = "k2", "tids", ...) holds a per-k count block the same way —
-#: deterministic stragglers for the dedup tests; the SIGSTOP chaos leg
-#: in bench_scaling.shard_tripwire stays signal-driven
+#: deterministic stragglers for the dedup tests
+#: (tests/test_dist.py::TestRunSharded)
 _HOLD_ENV = "AVENIR_SHARD_TEST_HOLD"
 
 #: the fold families whose finish() re-scans their inputs (the miners'

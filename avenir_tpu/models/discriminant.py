@@ -39,8 +39,8 @@ class FisherDiscriminant:
         rows land in one chunk — at 10M-row corpora that moved the
         published boundary in the 4th decimal when the block size
         changed, breaking the chunk-invariance contract every tuned or
-        re-chunked scan relies on (caught by
-        bench_scaling.autotune_tripwire's byte-identity gate). float64
+        re-chunked scan relies on (held by
+        tests/test_tune.py::TestTunedByteIdentity). float64
         keeps the layout sensitivity ~9 orders below the artifact's
         %.6f formatting; the moment fold is O(rows x features) adds —
         never this job's bottleneck."""

@@ -534,7 +534,7 @@ class EncodedBlockCache:
         self._committed = False
         self.n_blocks = 0
         self.evicted_bytes = 0
-        self.replays = 0          # completed replay passes (bench tripwire)
+        self.replays = 0          # completed replay passes
 
     def _seg_path(self, key) -> str:
         name = ("encoded_blocks.bin" if key is self._COMBINED
@@ -1162,7 +1162,7 @@ class SpillScanMixin:
 
     @property
     def cache_replays(self) -> int:
-        """Completed encoded-block replay passes (bench tripwire hook)."""
+        """Completed encoded-block replay passes."""
         return self._cache.replays if self._cache is not None else 0
 
     def cache_ready(self) -> bool:

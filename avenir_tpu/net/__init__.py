@@ -25,7 +25,10 @@ fleet"):
   per-host ``metrics.json`` snapshots up into one fleet view through
   the additive ``LatencyHistogram.merge`` algebra. Surfaced as
   ``python -m avenir_tpu fleet``; load-tested open-loop by
-  ``tools/fleet_load.py``; gated by ``bench_scaling.fleet_tripwire``.
+  ``tools/fleet_load.py``; byte-identity, affinity and the budget
+  vector are held by ``tests/test_net.py``
+  (``test_fleet_two_hosts_round_trip``,
+  ``test_fleet_hosts_keep_their_admission_peak_inside_the_budget_vector``).
 """
 
 from avenir_tpu.net.fleet import Fleet, fleet_main

@@ -45,11 +45,12 @@ Four pieces, policy here, mechanism in :mod:`avenir_tpu.net.fleet` and
   least-loaded rule, counted as ``failovers``); a recovered host
   re-earns affinity through hits, never through a map reset.
 
-Everything is deterministic under test: the chaos harness
-(``bench_scaling.fleet_fault_tripwire``) SIGKILLs a host mid-batch and
-asserts zero lost and zero conflicting results, byte-identical to solo
-twins; the hedging leg stalls a host and asserts the mirror fires and
-the first result wins.
+Everything is deterministic under test:
+``tests/test_net.py::test_fleet_survives_host_sigkill`` SIGKILLs a host
+mid-batch and asserts zero lost and zero conflicting results,
+byte-identical to solo twins;
+``tests/test_net.py::test_fleet_hedges_stalled_host`` stalls a host and
+asserts the mirror fires and the first result wins.
 """
 
 from __future__ import annotations

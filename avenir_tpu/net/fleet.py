@@ -44,8 +44,9 @@ duplicate write, never a conflict. When a healthy host's queue-wait
 tail runs hot past the fleet median, its queued requests are MIRRORED
 to the least-loaded compatible host (hedged dispatch, charged against
 the budget vector) and the first result to land wins. All of it is
-policy-driven by :class:`~avenir_tpu.net.fault.FaultPolicy` and gated
-by ``bench_scaling.fleet_fault_tripwire``.
+policy-driven by :class:`~avenir_tpu.net.fault.FaultPolicy` and held
+by ``tests/test_net.py::test_fleet_survives_host_sigkill`` and
+``::test_fleet_hedges_stalled_host``.
 """
 
 from __future__ import annotations
@@ -291,8 +292,7 @@ class Fleet:
         shared box an UNPINNED single process borrows every core
         through XLA's intra-op threads, so a same-box fleet-vs-one
         comparison measures nothing — pinning one core per host is
-        what makes a single machine a faithful proxy for N hosts
-        (``bench_scaling.fleet_tripwire`` relies on it).
+        what makes a single machine a faithful proxy for N hosts.
 
         ``listen_addresses``: base URL per host index (e.g.
         ``{0: "http://127.0.0.1:8191"}``) for hosts that run a

@@ -269,7 +269,8 @@ class AffinityRouter:
 
     def affinity_hit_rate(self) -> float:
         """Fraction of ROUTED placements that landed on their sticky
-        warm host (the fleet tripwire's warm-locality gate). Pinned
+        warm host (``tests/test_net.py::test_fleet_two_hosts_round_trip``
+        holds it to the count of repeats). Pinned
         placements (``assign_to`` warmups) are not routing decisions
         and do not dilute the rate."""
         with self._lock:

@@ -28,12 +28,14 @@ so nothing here may import jax/numpy at module scope):
 - **Span-coverage auditor** (:mod:`avenir_tpu.obs.coverage`): runs every
   registered stream entry (analysis/manifest.stream_entries) and
   asserts it emits the mandatory span set (read/parse/fold/finish) —
-  instrumentation can never silently rot; gated 8/8 by
-  ``bench_scaling.graftlint_tripwire``.
+  instrumentation can never silently rot; held for every entry by
+  ``tests/test_obs.py::test_every_stream_entry_emits_the_mandatory_spans``.
 
-Overhead contract: ``bench_scaling.obs_tripwire`` asserts a fused
-10M-row proxy run with tracing ON stays within 3% of the wall clock
-with tracing OFF, with byte-identical artifacts. Tracing is ON by
+Contract: tracing is observation only, so a fused run with tracing ON
+writes the bytes of the run with tracing OFF
+(``tests/test_shared_scan.py::test_fused_outputs_byte_identical_under_tracing``).
+What the spans cost on the chip is in ``PERF.md`` (PR 25: nothing one
+can measure). Tracing is ON by
 default (``AVENIR_TRACE=0`` or :func:`set_enabled` turns it off); every
 record call is one enabled-flag load away from free when off.
 """

@@ -15,9 +15,10 @@ assert the MANDATORY span set showed up:
 - ``stream.fold``  — a sink/device fold consumed a chunk;
 - ``job.finish``   — the job sealed its fold and wrote the artifact.
 
-``bench_scaling.graftlint_tripwire`` gates this 8/8 every round next to
-the invariance/footprint/merge legs; a deliberately de-instrumented
-fold (tests/test_obs.py) must fail it.
+``tests/test_obs.py::test_every_stream_entry_emits_the_mandatory_spans``
+holds this for every entry, a case each; a deliberately de-instrumented
+fold (``::test_coverage_fails_deliberately_deinstrumented_fold``) must
+fail it.
 """
 
 from __future__ import annotations

@@ -130,8 +130,9 @@ def enabled() -> bool:
 
 
 def set_enabled(on: bool) -> bool:
-    """Toggle recording; returns the previous state. The bench overhead
-    tripwire uses this for its ON/OFF A/B; production leaves it on."""
+    """Toggle recording; returns the previous state. Tests use it for
+    their traced-against-untraced byte comparison; production leaves it
+    on."""
     global _ENABLED
     prev, _ENABLED = _ENABLED, bool(on)
     return prev

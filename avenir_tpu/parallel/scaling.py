@@ -85,8 +85,7 @@ def collective_payload_model(family: str, mesh_shape: Dict[str, int],
 def nb_payload_bytes() -> int:
     """All-reduce payload of the weak-scaling NB step: the [F, K, B] count
     tensor + [K] class counts in f32. The single source of the number the
-    compiled-HLO check validates and the projections consume (bench.py,
-    tests)."""
+    compiled-HLO check validates and the projections consume."""
     return collective_payload_model(
         "nb_train", {}, n_feat=_NB_FEAT, num_classes=_NB_CLASSES,
         bmax=_NB_BMAX)
@@ -96,7 +95,7 @@ def _timed_scalar(many_fn, *args) -> float:
     """Best-of-2 wall clock of the jitted scalar-reducing many_fn, warmup
     excluded, result forced to host with float(): every measurement runs
     its iterations inside one program and fetches the scalar, so the clock
-    stops when the device has finished (bench.py's timing note)."""
+    stops when the device has finished."""
     import jax.numpy as jnp
 
     _ = float(many_fn(*args))

@@ -116,8 +116,7 @@ def run_job(name: str, conf, inputs: Sequence[str], output: str = "") -> JobResu
     Every streamed job's result additionally carries the memory-oracle
     counter pair: `Mem:PredictedPeakBytes` (the analysis/mem analytic
     footprint model at the job's block size and corpus) next to the
-    measured `Mem:PeakRSS` — so long-running anchors (the 100M-row
-    stream_scale_check children run one job per process) record the
+    measured `Mem:PeakRSS`, so a long-running process records the
     model's error over time."""
     canonical, _prefix, cfg = _job_cfg(name, conf)
     fn = _REGISTRY[canonical][2]
@@ -2661,7 +2660,7 @@ def gsp_job(cfg: JobConfig, inputs: List[str], output: str) -> JobResult:
               and total_bytes < (256 << 20))
     # timer starts BEFORE the in-RAM probe reads the file: RowsPerSec
     # must price the whole job's I/O identically on both paths, or the
-    # tripwire mis-alarms when a corpus crosses the in-RAM gate
+    # rate steps when a corpus crosses the in-RAM gate
     t0 = time.perf_counter()
     if in_ram:
         rows = [[t.strip(" \t\r") for t in ln.split(cfg.field_delim_regex)]

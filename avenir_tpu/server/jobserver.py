@@ -39,10 +39,9 @@ Three layers, each consuming machinery earlier PRs proved correct:
   stay resident and get reused, not returned), so gating on live RSS
   would double-count every completed job and eventually hold or
   reject everything. Live RSS is still sampled and reported
-  (``stats()["rss_bytes"]``), and ``bench_scaling.server_tripwire``
-  asserts the measured served-phase peak stays under budget + slack —
-  the empirical check that the model-priced gate actually bounds the
-  process.
+  (``stats()["rss_bytes"]``); that the priced peak never passes the
+  budget is held by
+  ``tests/test_server.py::test_admission_holds_until_inflight_releases``.
 
 Thread shape (the graftlint --flow contract): one scheduler thread +
 ``workers`` executor threads, all bound and joined on ``shutdown()``
@@ -74,7 +73,6 @@ from avenir_tpu.core.atomic import publish_json
 from avenir_tpu.obs.histogram import LatencyHistogram
 
 #: default admission ceiling: the repo's standing 3GB RSS budget
-#: (tools/stream_scale_check.py asserts it at every 100M-row anchor)
 DEFAULT_BUDGET_BYTES = 3 << 30
 #: default byte budget of the pinned miner-source caches
 DEFAULT_WARM_BUDGET_BYTES = 256 << 20

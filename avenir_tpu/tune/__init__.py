@@ -8,8 +8,10 @@ JobResult — and this package is the actuator that reads those signals
 and moves the knobs they implicate. Chunk invariance (graftlint --flow,
 8/8 byte-identity under adversarial chunkings) means a tuner can NEVER
 change results, only speed, so the policies are aggressive by design;
-``bench_scaling.autotune_tripwire`` re-proves both halves (tuned beats
-static, artifacts byte-identical) every full round.
+``tests/test_tune.py::TestTunedByteIdentity`` holds that half (the
+artifacts under the chosen knobs are the static default's bytes, solo
+and fused). Whether the chosen knobs are faster is a question for a chip
+cell, and none asks it yet.
 
 Four pieces:
 

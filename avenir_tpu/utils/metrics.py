@@ -129,7 +129,8 @@ def jit_cache_size(fn) -> int:
     Growth across calls == compile-cache misses == recompiles. This is
     the runtime cross-check for graftlint's `recompile-hazard` rule: the
     static analyzer promises a shape-stable fold never recompiles, and
-    bench_scaling.py asserts this counter stays at the shape-bucket bound
+    tests/test_stream_jobs.py::test_streamed_miners_compile_within_their_shape_buckets
+    asserts this counter stays at the shape-bucket bound
     (pow2-quantized block/candidate axes → logarithmically many entries)
     instead of growing per block. If the two ever disagree, trust this
     counter and tighten the rule."""
@@ -140,11 +141,12 @@ def jit_cache_size(fn) -> int:
 
 
 def throughput_counters(records: int, seconds: float) -> Dict[str, float]:
-    """The regression-tripwire pair every streamed job should report:
-    the Hadoop-style Basic:Records plus a derived Basic:RowsPerSec, so
-    scale harnesses (tools/stream_scale_check.py, bench_scaling.py) get a
-    non-null rows figure AND a rate to alarm on without re-deriving
-    either. A non-positive wall clock (mocked timers) yields rate 0
+    """The pair every streamed job should report: the Hadoop-style
+    Basic:Records plus a derived Basic:RowsPerSec, so whoever runs a job
+    at scale gets a non-null rows figure AND a rate without re-deriving
+    either
+    (tests/test_stream_jobs.py::test_miner_jobs_report_throughput_counters).
+    A non-positive wall clock (mocked timers) yields rate 0
     rather than inf/ZeroDivision."""
     rate = records / seconds if seconds > 0 else 0.0
     return {"Basic:Records": int(records),

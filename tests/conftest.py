@@ -48,7 +48,10 @@ _SKIP_REASON_ALLOWLIST = (
     "reference checkout not present",       # tests/test_core.py: same tree
     "g++ unavailable; native ingest not built",   # test_native_ingest.py
     "native encoder unavailable",           # tests/test_bitset.py
-    "no native lib",                        # test_native_ingest.py
+        "no native lib",                        # test_native_ingest.py
+    # test_stream_jobs.py, test_shared_scan.py: a jax whose jitted
+    # functions have no `_cache_size()` (`jit_cache_size` returns -1)
+    "this jax does not expose a jitted function's cache size",
 )
 
 

@@ -225,9 +225,6 @@ def test_chip_smoke_dry_run_cpu_end_to_end(tmp_path):
     byte-identical, the placed compile cache found by a fresh process —
     and a summary that cannot be read as a pass on the chip."""
     cache = tmp_path / "cache"
-    default_cache = os.path.join(REPO, ".jax_cache")
-    before = (sorted(os.listdir(default_cache))
-              if os.path.isdir(default_cache) else None)
     env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(cache))
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py"),
@@ -258,12 +255,13 @@ def test_chip_smoke_dry_run_cpu_end_to_end(tmp_path):
     assert phases["kernels"]["interpreted"] is True
     assert phases["server"]["jobs_byte_identical"] == [
         "bayesianDistr", "nearestNeighbor"]
-    # the cache: env set -> that directory fills, and no other
+    # the cache: env set -> that directory fills, and no phase names
+    # another (what <checkout>/.jax_cache holds is its neighbours' doing:
+    # any test that has placed the default cache in its worker writes there)
     assert phases["knn_warm"]["compile_cache_hits"] > 0
     assert os.listdir(cache)
-    after = (sorted(os.listdir(default_cache))
-             if os.path.isdir(default_cache) else None)
-    assert after == before
+    assert [row["compile_cache_dir"] for row in phases.values()
+            if "compile_cache_dir" in row] == [str(cache)]
 
 
 def test_chip_smoke_without_a_chip_fails_and_prints_no_result(tmp_path):

@@ -5,8 +5,7 @@ one layer over:
 1. Gate — the gated repo surface lints clean under the flow rules and
    every streamed fold kernel in the manifest reports
    invariance_validated under >= 3 chunk layouts + the adversarial
-   scheduler (the acceptance invariant bench_scaling re-checks every
-   round).
+   scheduler (the tier's acceptance invariant, held here).
 2. Corpus — every flow rule has a bad fixture that MUST fire and a good
    twin that MUST stay silent.
 3. Contract — the invariance auditor catches drift, kernel run failures

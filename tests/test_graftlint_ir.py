@@ -3,8 +3,7 @@
 Three jobs, mirroring tests/test_graftlint.py one layer down:
 1. Gate — every manifest entry traces clean against the baseline and all
    8 distributed families report payload_model_validated on the virtual
-   8-device mesh (the acceptance invariant bench_scaling re-checks every
-   round).
+   8-device mesh (the tier's acceptance invariant, held here).
 2. Corpus — every IR rule has a hand-traced bad fixture that MUST fire
    and a good twin that MUST stay silent.
 3. Contract — the payload auditor catches drift, trace failures surface
@@ -219,18 +218,18 @@ def test_cli_ir_usage_and_trace_errors_exit_2():
     assert _cli(["--ir", "--rules", "nope"]).returncode == 2
     # a too-small device pool is a trace error, not a clean/finding run:
     # pin 1 virtual device (via the explicit test override — a merely
-    # INHERITED small XLA flag is raised to the audit size, so e.g.
-    # bench_scaling's own pool exports can't spuriously fail the audit)
+    # INHERITED small XLA flag is raised to the audit size, so a
+    # parent's own pool exports can't spuriously fail the audit)
     proc = _cli(["--ir"], env={"GRAFTLINT_IR_DEVICES": "1"})
     assert proc.returncode == 2, proc.stdout + proc.stderr
     assert "trace error" in proc.stderr
 
 
 def test_cli_ir_raises_inherited_small_device_flag():
-    """bench_scaling exports --xla_force_host_platform_device_count=<n>
-    for its own mesh before spawning the tripwire subprocesses; the
-    graftlint --ir bootstrap must bump an inherited smaller count to the
-    audit size instead of failing on it."""
+    """Any parent may export --xla_force_host_platform_device_count=<n>
+    for its own mesh before it starts the audit; the graftlint --ir
+    bootstrap must bump an inherited smaller count to the audit size
+    instead of failing on it."""
     proc = _cli(["--ir", "--json"], env={
         "XLA_FLAGS": "--xla_force_host_platform_device_count=4"})
     assert proc.returncode == 0, proc.stdout + proc.stderr

@@ -3,8 +3,7 @@
 Three jobs, mirroring the other analyzer test modules one layer over:
 1. Gate — the gated repo surface lints clean under the mem rules and
    every streamed job in the manifest reports footprint_model_validated
-   at >= 2 block sizes (the acceptance invariant bench_scaling re-checks
-   every round).
+   at >= 2 block sizes (the tier's acceptance invariant, held here).
 2. Corpus — every mem rule has a bad fixture that MUST fire and a good
    twin that MUST stay silent.
 3. Contract — the footprint auditor catches a wrong model (finding under
@@ -497,8 +496,7 @@ def test_streamed_jobs_carry_the_memory_oracle_counters(tmp_path):
     assert res.counters["Mem:PredictedPeakBytes"] > 0
     assert res.counters["Mem:PeakRSS"] > 0
     # the measured peak is a whole-process number; the prediction is the
-    # job's incremental footprint — both present is the contract, the
-    # delta column lives in tools/stream_scale_check.py
+    # job's incremental footprint — both present is the contract
 
 
 # -------------------------------------------------------------------- CLI

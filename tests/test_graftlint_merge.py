@@ -4,8 +4,8 @@ Three jobs, mirroring the other analyzer test modules one layer over:
 1. Gate — the gated repo surface lints clean under the merge rules and
    every streamed fold kernel in the manifest reports merge_validated:
    shard-merge byte-identical at P=2 AND P=4, checkpoint-resume
-   byte-identical, overlap contract recorded (the acceptance invariant
-   bench_scaling re-checks every round).
+   byte-identical, overlap contract recorded (the tier's acceptance invariant,
+   held here).
 2. Corpus — every merge rule has a bad fixture that MUST fire and a
    good twin that MUST stay silent.
 3. Contract — the auditor turns a too-small corpus into a
@@ -409,8 +409,8 @@ def test_cli_merge_exit_code_contract_and_schema(tmp_path):
 def test_cli_all_worst_of_exit_and_combined_schema(tmp_path):
     # --all with a cross-tier rule subset: the bad fixture fires the
     # merge rule (exit 1), tiers with no selected rules are skipped —
-    # the fast CI shape; the full --all is what the bench tripwire's
-    # per-tier runs add up to
+    # the fast CI shape; the full depth is the operator's
+    # `python tools/graftlint.py --all`
     (tmp_path / "bad.py").write_text(_MISSING_BAD)
     proc = _cli(["--all", "bad.py", "--rules",
                  "merge-missing-op,default-int64", "--no-baseline",

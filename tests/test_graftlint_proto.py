@@ -5,8 +5,8 @@ Three jobs, mirroring the other analyzer test modules one layer over:
    proto rules and every registered commit site reports
    commit_point_validated: hard-killed at before-rename AND
    after-rename, recovery (re-run + startup sweep) byte-identical to
-   the uncrashed run with no stranded tmp (the acceptance invariant
-   bench_scaling re-checks every round).
+   the uncrashed run with no stranded tmp (the tier's acceptance invariant,
+   held here).
 2. Corpus — every proto rule has a bad fixture that MUST fire and a
    good twin that MUST stay silent.
 3. Contract — the auditor fails a deliberately NON-atomic site (the

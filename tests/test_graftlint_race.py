@@ -4,8 +4,8 @@ Four layers, mirroring the other tier test suites:
 
 - the GATE: the real protocol surface is race-clean and every
   registered interleave site validates under schedule exploration
-  (reduced depth/seeds here for suite wall time; the bench tripwire
-  runs the full configuration every round);
+  (reduced depth/seeds here for suite wall time; the full
+  configuration is the operator's `python tools/graftlint.py --all`);
 - the REGISTRY: sched_point call sites and INTERLEAVE_SITES agree in
   both directions, and a mismatch in either direction fails loudly;
 - the RULES: one bad/good fixture pair per static rule;

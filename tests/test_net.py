@@ -779,6 +779,43 @@ def test_fleet_blocking_submit_sweeps_its_own_capacity(tmp_path):
     assert snap["hosts"][0]["assigned_bytes"] == 0   # all released
 
 
+def test_fleet_hosts_keep_their_admission_peak_inside_the_budget_vector(
+        tmp_path):
+    """The budget vector holds on both sides of the spool: the router
+    never assigns a host past its entry, and no host's own admission
+    ever prices more in flight than that entry, by the host's own
+    account (its last `metrics.json`). The entry fits one request."""
+    a = _seq(tmp_path, seed=1, name="a.csv")
+    b = _seq(tmp_path, seed=2, name="b.csv")
+    probe = Fleet(str(tmp_path / "probe"), hosts=1, env=_SUB_ENV)
+    _req, priced, _cost = probe.price(_req_obj(a, "x"))
+    budget_mb = priced * 1.5 / (1 << 20)
+    fleet = Fleet(str(tmp_path / "fleet"), hosts=2, workers=1,
+                  budget_mb=budget_mb, env=_SUB_ENV,
+                  fault_policy=FaultPolicy(lease_ttl_s=3600.0,
+                                           heartbeat_timeout_s=3600.0,
+                                           hedge=False))
+    fleet.start()
+    try:
+        names = [fleet.submit(_req_obj(corpus,
+                                       str(tmp_path / f"bv{i}.txt"),
+                                       tenant=f"t{i}"), timeout=240)
+                 for i, corpus in enumerate([a, b, a, b])]
+        rows = fleet.collect(names, timeout=240)
+        router = fleet.router.snapshot()
+    finally:
+        codes = fleet.stop()
+    assert codes == [0, 0]
+    assert all(r["ok"] for r in rows.values())
+    for h in router["hosts"]:
+        assert h["peak_assigned_bytes"] <= h["budget_bytes"]
+    for i in range(2):
+        with open(tmp_path / "fleet" / f"host{i}" / "metrics.json") as fh:
+            inflight = json.load(fh)["inflight"]
+        assert inflight["budget_bytes"] == int(budget_mb * (1 << 20))
+        assert 0 < inflight["peak_priced_bytes"] <= inflight["budget_bytes"]
+
+
 def test_fleet_cli_once(tmp_path):
     """`python -m avenir_tpu fleet --root R --hosts 1 --once`: requests
     spooled into the FLEET root are routed, served, and answered in

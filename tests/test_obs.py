@@ -19,6 +19,7 @@ import threading
 
 import pytest
 
+from avenir_tpu.analysis.manifest import stream_kernel_names
 from avenir_tpu.obs import trace
 from avenir_tpu.obs.histogram import LatencyHistogram
 from avenir_tpu.obs.trace import SpanRecorder
@@ -312,6 +313,20 @@ def test_coverage_passes_on_real_stream_entry():
         assert row["span_counts"][name] >= 1
     # the tiny audit layout chunks the corpus: per-chunk spans repeat
     assert row["span_counts"]["stream.read"] > 1
+
+
+@pytest.mark.parametrize("name", stream_kernel_names())
+def test_every_stream_entry_emits_the_mandatory_spans(name):
+    """The coverage gate over the whole manifest, a case an entry: the
+    six one-job-one-scan folds and the two fused scans each leave at
+    least one read, parse, fold and finish span."""
+    from avenir_tpu.analysis.manifest import stream_entries
+    from avenir_tpu.obs.coverage import audit_span_coverage
+
+    spec = next(s for s in stream_entries() if s.name == name)
+    (row,) = audit_span_coverage([spec])
+    assert row["kernel"] == name
+    assert row["span_coverage_validated"] and row["missing"] == [], row
 
 
 def test_coverage_fails_deliberately_deinstrumented_fold():

@@ -1,7 +1,8 @@
 """Pallas fused distance+top-k kernel vs the jnp reference path.
 
 Runs in pallas interpret mode on the CPU test mesh; the compiled path is
-exercised on real TPU by bench.py and the driver."""
+exercised on real TPU by tools/tpu_kernel_check.py, chip_smoke.py and
+the benchmark's kNN cells."""
 
 import numpy as np
 import pytest

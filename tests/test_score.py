@@ -523,6 +523,71 @@ def test_score_front_mints_reward_nonce_and_closes_all_threads(tmp_path):
         srv.shutdown()
 
 
+def test_score_front_pins_each_model_and_the_merge_keeps_both(tmp_path):
+    """Two hosts behind a ScoreFront, two models queried side by side
+    over keep-alive sockets: each model misses once and then returns to
+    the host that holds it loaded, every wire answer equals its solo
+    twin, and the merged snapshot counts every score and keeps both
+    models' end-to-end histograms."""
+    from avenir_tpu.net.fleet import ScoreFront
+    from avenir_tpu.net.listener import NetListener
+    from avenir_tpu.obs.report import merge_snapshots
+    from avenir_tpu.server import JobServer
+
+    models = []
+    for m, seed in enumerate((12, 13)):
+        train = _seq_csv(tmp_path, seed=seed, name=f"train_{m}.csv")
+        model = str(tmp_path / f"mst_model_{m}.txt")
+        run_job("markovStateTransitionModel", dict(MST_CONF), [train], model)
+        models.append(model)
+    rows = open(_seq_csv(tmp_path, rows=20, seed=9, name="q.csv")
+                ).read().splitlines()
+    solo = {m: [score_once("markov", m, r, dict(MARKOV_SCORE_CONF))
+                for r in rows] for m in models}
+    servers = [JobServer(state_root=str(tmp_path / f"h{i}"),
+                         workers=1).start() for i in range(2)]
+    listeners = [NetListener(s, port=0).start() for s in servers]
+    wire = {m: [] for m in models}
+    try:
+        front = ScoreFront([f"http://127.0.0.1:{lis.port}"
+                            for lis in listeners])
+
+        def client(model):
+            for r in rows:
+                wire[model].append(front.score(
+                    "markov", model, r, conf=dict(MARKOV_SCORE_CONF),
+                    timeout=60.0)["row"])
+
+        threads = [threading.Thread(target=client, args=(m,))
+                   for m in models]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        routed = dict(front.router.stats)
+        front.close()
+        snaps = [s.metrics_snapshot() for s in servers]
+    finally:
+        for lis in listeners:
+            lis.stop()
+        for srv in servers:
+            srv.shutdown()
+    assert wire == solo
+    total = len(models) * len(rows)
+    assert routed["affinity_misses"] == len(models)
+    assert routed["affinity_hits"] == total - len(models)
+    # one load a model over the whole fleet: no model was scored on two hosts
+    assert sum((s.get("score") or {}).get("stats", {}).get("model_loads", 0)
+               for s in snaps) == len(models)
+    merged = merge_snapshots(snaps)
+    assert merged["score"]["stats"]["scores"] == total
+    for m in models:
+        name = os.path.splitext(os.path.basename(m))[0]
+        assert merged["hists"][f"score_{name}_total_ms"]["count"] \
+            == len(rows)
+        assert merged["score"]["per_model_predicts"][name] >= 1
+
+
 def test_metrics_snapshot_and_fleet_merge_carry_score(tmp_path):
     from avenir_tpu.obs.report import merge_snapshots
     from avenir_tpu.server import JobServer

@@ -125,6 +125,49 @@ def test_bytes_fused_outputs_byte_identical(tmp_path):
         assert _read_outputs(fused[name]) == _read_outputs(seq[name]), name
 
 
+def test_fused_scan_adds_no_nb_fold_variant(tmp_path):
+    """The sinks of a fused scan see the chunk shapes the solo jobs saw,
+    so fan-out compiles no further variant of the NB fold kernel. The
+    account age is declared without a bucket width: with a continuous
+    feature the fold takes `_fold_batch_kernel` on every backend (the
+    all-binned churn schema folds by host `bincount` on the CPU and
+    would make this vacuous)."""
+    import json
+
+    from avenir_tpu.models.naive_bayes import _fold_batch_kernel
+    from avenir_tpu.utils.metrics import jit_cache_size
+
+    if jit_cache_size(_fold_batch_kernel) < 0:
+        pytest.skip("this jax does not expose a jitted function's cache size")
+    csv, schema = _churn(tmp_path, rows=6000)
+    with open(schema) as fh:
+        fields = json.load(fh)
+    for field in fields["fields"]:
+        if field["name"] == "acctAge":
+            del field["bucketWidth"]
+    with open(schema, "w") as fh:
+        json.dump(fields, fh)
+    conf = lambda p: {f"{p}.feature.schema.file.path": schema,  # noqa: E731
+                      f"{p}.stream.block.size.mb": "0.1"}
+    mi_conf = {**conf("mut"),
+               "mut.mutual.info.score.algorithms": "mutual.info.maximization"}
+    trio = [("bayesianDistr", conf("bad"), "nb"),
+            ("mutualInformation", mi_conf, "mi"),
+            ("fisherDiscriminant", conf("fid"), "fd")]
+    # growth, not the absolute size: other tests of this worker compile
+    # the same kernel at their own shapes
+    before = jit_cache_size(_fold_batch_kernel)
+    solo = {job: run_job(job, c, [csv], str(tmp_path / f"solo_{o}"))
+            for job, c, o in trio}
+    after_solo = jit_cache_size(_fold_batch_kernel)
+    assert after_solo > before, "the solo NB fold never ran the kernel"
+    fused = run_shared([(job, c, str(tmp_path / f"fused_{o}"))
+                        for job, c, o in trio], [csv])
+    assert jit_cache_size(_fold_batch_kernel) == after_solo
+    for job in solo:
+        assert _read_outputs(fused[job]) == _read_outputs(solo[job]), job
+
+
 def test_pipeline_fuse_groups_and_agrees(tmp_path):
     from avenir_tpu.core import stream
     from avenir_tpu.pipelines import profile_pipeline
@@ -208,8 +251,8 @@ def test_fused_outputs_byte_identical_under_tracing(tmp_path):
     """avenir-trace is observation-only: the fused scan with the span
     recorder capturing must produce byte-identical artifacts to the
     same scan with tracing disabled, and the capture must hold the
-    per-chunk read/parse/fold span set for every sink (the obs
-    tripwire's correctness gate at unit scale)."""
+    per-chunk read/parse/fold span set for every sink of the churn
+    trio."""
     from collections import Counter
 
     from avenir_tpu.obs import trace
@@ -220,8 +263,11 @@ def test_fused_outputs_byte_identical_under_tracing(tmp_path):
     conf = lambda p: {f"{p}.feature.schema.file.path": schema,  # noqa: E731
                       f"{p}.stream.block.size.mb": "0.005",
                       f"{p}.stream.sidecar": "false"}
+    mi_conf = {**conf("mut"),
+               "mut.mutual.info.score.algorithms": "mutual.info.maximization"}
     specs = lambda tag: [  # noqa: E731
         ("bayesianDistr", conf("bad"), str(tmp_path / f"nb_{tag}")),
+        ("mutualInformation", mi_conf, str(tmp_path / f"mi_{tag}")),
         ("fisherDiscriminant", conf("fid"), str(tmp_path / f"fd_{tag}"))]
     prev = trace.set_enabled(False)
     try:
@@ -241,9 +287,8 @@ def test_fused_outputs_byte_identical_under_tracing(tmp_path):
     assert names["stream.parse"] >= chunks
     folds = Counter(sp.attrs["sink"] for sp in spans
                     if sp.name == "stream.fold")
-    assert folds["bayesianDistr"] == chunks
-    assert folds["fisherDiscriminant"] == chunks
-    assert names["job.finish"] == 2
+    assert folds == {job: chunks for job in untraced}
+    assert names["job.finish"] == 3
     # every chunk's fan-out also fed the process-global latency histogram
     h = trace.hist("chunk_latency_ms")
     assert h is not None and h.count >= chunks

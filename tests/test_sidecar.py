@@ -155,6 +155,97 @@ def test_warm_replay_is_parse_free(tmp_path, family):
     assert len(replay) == _sc(warm, "HitBlocks") >= 1
 
 
+_TRIO = [("bayesianDistr", "bad"), ("mutualInformation", "mut"),
+         ("fisherDiscriminant", "fid")]
+
+
+@pytest.mark.parametrize("surface",
+                         ["fused", "sharded", "incremental", "served"])
+def test_packed_corpus_replays_parse_free_on_every_surface(tmp_path,
+                                                           surface):
+    """One fused pass of the churn trio packs the sidecar; every other
+    way of scanning the same corpus then replays it without parsing a
+    block, to the cold scan's bytes: the fused scan again, two sharded
+    workers, the incremental driver's cold seed, and a job-server batch,
+    which also pins the sidecar and leaves it on disk at shutdown."""
+    from avenir_tpu.obs import trace
+    from avenir_tpu.runner import run_incremental, run_shared
+
+    csv, schema = _churn(tmp_path, rows=3000)
+    # some twelve blocks: the sharded planner snaps its cuts onto the
+    # sidecar's verified offsets only when there are procs * factor of them
+    block = f"{os.path.getsize(csv) / (1 << 20) / 12:.4f}"
+
+    def conf(prefix, **extra):
+        if prefix == "mut":
+            return _mi_conf(tmp_path, schema, block=block, **extra)
+        return _conf(prefix, tmp_path, schema, block=block, **extra)
+
+    def specs(tag, **extra):
+        return [(job, conf(p, **extra), str(tmp_path / f"{tag}_{p}"))
+                for job, p in _TRIO]
+
+    def parse_free(rec):
+        names = [s.name for s in rec.spans()]
+        return ("stream.parse" not in names
+                and "stream.sidecar.replay" in names)
+
+    pack = run_shared(specs("pack"), [csv])
+    cold = run_shared(specs("cold", **{"stream.sidecar": "false"}), [csv])
+    for job, _p in _TRIO:
+        assert _bytes_of(pack[job]) == _bytes_of(cold[job]), job
+        assert _sc(pack[job], "DeltaBlocks") >= 8, pack[job].counters
+    mi_cold = _bytes_of(cold["mutualInformation"])
+
+    if surface == "fused":
+        with trace.capture() as rec:
+            warm = run_shared(specs("warm"), [csv])
+        for job, _p in _TRIO:
+            assert _bytes_of(warm[job]) == _bytes_of(cold[job]), job
+            assert _sc(warm[job], "HitBlocks") >= 1, warm[job].counters
+            assert _sc(warm[job], "DeltaBlocks") == 0, warm[job].counters
+        assert parse_free(rec)
+    elif surface == "sharded":
+        from avenir_tpu.dist import run_sharded
+
+        # the workers' own captures come home through the stats files
+        shard = run_sharded("mutualInformation", conf("mut"), [csv],
+                            str(tmp_path / "shard_out.txt"), procs=2)
+        assert _bytes_of(shard) == mi_cold
+        assert shard.counters["Shard:ParseSpans"] == 0, shard.counters
+        assert shard.counters["Shard:ReplaySpans"] >= 1, shard.counters
+        assert _sc(shard, "HitBlocks") >= 1, shard.counters
+    elif surface == "incremental":
+        with trace.capture() as rec:
+            seed = run_incremental(
+                "mutualInformation", conf("mut"), [csv],
+                str(tmp_path / "incr_out.txt"),
+                state_dir=str(tmp_path / "incr_state"))
+        assert _bytes_of(seed) == mi_cold
+        assert parse_free(rec)
+        assert _sc(seed, "HitBlocks") >= 1, seed.counters
+    else:
+        from avenir_tpu.server import JobRequest, JobServer
+
+        with trace.capture() as rec:
+            with JobServer(workers=1,
+                           state_root=str(tmp_path / "srv_state")) as srv:
+                tickets = [srv.submit(JobRequest(
+                    job, conf(p), [csv], str(tmp_path / f"srv_{p}")))
+                    for job, p in _TRIO]
+                served = {job: t.result(timeout=300)
+                          for (job, _p), t in zip(_TRIO, tickets)}
+                pinned = srv.warm.stats()["pinned_sources"]
+        for job, _p in _TRIO:
+            assert _bytes_of(served[job]) == _bytes_of(cold[job]), job
+            assert _sc(served[job], "HitBlocks") >= 1, served[job].counters
+        assert parse_free(rec)
+        assert pinned >= 1
+        # shutdown drops the pins, not the cache on disk
+        assert sum(sidecar.sidecar_nbytes(d)
+                   for d in _manifest_dirs(tmp_path)) > 0
+
+
 # --------------------------------------------- 3. torn writes and drift
 def test_torn_write_never_commits(tmp_path):
     """The manifest is written LAST: a truncated segment (crash between

@@ -409,14 +409,65 @@ def test_fisher_chunked_close_to_whole(churn, tmp_path):
     np.testing.assert_allclose(parse(whole), parse(chunked), atol=1e-4)
 
 
-def test_baseline_anchor_measures_positive_rates():
-    """bench.measure_baseline_anchor returns finite, positive per-node
-    native rates (the measured half of vs_baseline_measured_anchor)."""
-    import bench
+def _state_walk_file(tmp_path, rows):
+    """`rows` lines of `c<i>,<T|F>,` and six L/M/H states of a clipped
+    random walk that drifts up for T and down for F."""
+    rng = np.random.default_rng(12)
+    up = np.arange(rows) % 2 == 0
+    steps = np.where(up[:, None],
+                     rng.choice([-1, 0, 1], (rows, 6), p=[0.1, 0.3, 0.6]),
+                     rng.choice([-1, 0, 1], (rows, 6), p=[0.6, 0.3, 0.1]))
+    state, cols = np.ones(rows, np.int64), []
+    for j in range(6):
+        state = np.clip(state + steps[:, j], 0, 2)
+        cols.append(state)
+    toks = np.array(["L", "M", "H"])[np.stack(cols, axis=1)]
+    path = str(tmp_path / "walk.csv")
+    with open(path, "w") as fh:
+        fh.write("".join(
+            f"c{i},{'T' if up[i] else 'F'}," + ",".join(toks[i]) + "\n"
+            for i in range(rows)))
+    return path
 
-    nb, pp = bench.measure_baseline_anchor()
-    assert np.isfinite(nb) and nb > 1e4
-    assert np.isfinite(pp) and pp > 1e5
+
+def test_streamed_miners_compile_within_their_shape_buckets(tmp_path):
+    """Both streamed miners over a corpus of several 1 MB blocks compile
+    the GSP support kernels once per shape bucket (block and candidate
+    axes are padded to powers of two), not once per block: no more than
+    16 variants, and none on a second pass over the same file. It is the
+    runtime check behind graftlint's `recompile-hazard` rule."""
+    from avenir_tpu.models.sequence import (_subseq_fold_kernel,
+                                            _subseq_support_kernel)
+    from avenir_tpu.utils.metrics import jit_cache_size
+
+    def variants():
+        return (jit_cache_size(_subseq_support_kernel)
+                + jit_cache_size(_subseq_fold_kernel))
+
+    if jit_cache_size(_subseq_support_kernel) < 0:
+        pytest.skip("this jax does not expose a jitted function's cache size")
+    rows = 150_000
+    path = _state_walk_file(tmp_path, rows)
+    assert os.path.getsize(path) > 3 << 20          # four blocks of 1 MB
+
+    def both(tag):
+        for job, p in (("frequentItemsApriori", "fia"),
+                       ("candidateGenerationWithSelfJoin", "cgs")):
+            res = run_job(job, {f"{p}.support.threshold": "0.3",
+                                f"{p}.item.set.length": "2",
+                                f"{p}.skip.field.count": "2",
+                                f"{p}.stream.block.size.mb": "1"},
+                          [path], str(tmp_path / f"{job}_{tag}"))
+            assert res.counters["Basic:Records"] == rows
+
+    before = variants()
+    both("first")
+    first = variants()
+    # growth, not the absolute size: other tests of this worker compile
+    # the same kernels at their own shapes
+    assert 1 <= first - before <= 16
+    both("second")
+    assert variants() == first
 
 
 def test_markov_per_entity_native_and_python_agree(tmp_path, monkeypatch):

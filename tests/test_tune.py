@@ -395,6 +395,45 @@ class TestTunedByteIdentity:
         assert _bytes_of(tuned) == _bytes_of(static) == _bytes_of(first)
 
 
+    def test_fused_churn_trio(self, tmp_path):
+        """The same through `run_shared`: one profile for the batch,
+        under the jobs' joined name, chosen from the fused pass's own
+        telemetry; the trio under those knobs writes the static bytes."""
+        from avenir_tpu.runner import run_shared
+
+        csv, schema = _churn(tmp_path)
+        prefixes = {"bayesianDistr": "bad", "mutualInformation": "mut",
+                    "fisherDiscriminant": "fid"}
+        base = {job: {f"{p}.feature.schema.file.path": schema,
+                      f"{p}.stream.block.size.mb": "0.01"}
+                for job, p in prefixes.items()}
+        base["mutualInformation"]["mut.mutual.info.score.algorithms"] = \
+            "mutual.info.maximization"
+        tune_dir = str(tmp_path / "t")
+
+        def fused(tag, overlay):
+            return run_shared(
+                [(job, {**base[job], **overlay(prefixes[job])},
+                  str(tmp_path / f"{tag}_{prefixes[job]}"))
+                 for job in prefixes], [csv])
+
+        static = fused("static", lambda p: {})
+        first = fused("first", lambda p: {
+            f"{p}.stream.autotune": "true",
+            f"{p}.stream.autotune.dir": tune_dir})
+        prof = ProfileStore(tune_dir).load("+".join(sorted(prefixes)),
+                                           corpus_digest([csv]))
+        knobs = dict((prof or {}).get("knobs") or {})
+        assert knobs, f"no knobs chosen for the batch (profile={prof})"
+        assert (prof or {}).get("reasons"), prof
+        tuned = fused("tuned", lambda p: {
+            f"{p}.{key}": f"{val:g}" for key, val in knobs.items()})
+        for job in prefixes:
+            assert len(tuned[job].outputs) == len(static[job].outputs)
+            assert _bytes_of(tuned[job]) == _bytes_of(static[job]) \
+                == _bytes_of(first[job]), job
+
+
 # ============================================== incremental checkpoint knob
 class TestIncrementalCheckpointKnob:
     def test_checkpoint_rule_fires_on_incremental_run(self, tmp_path):

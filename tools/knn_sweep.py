@@ -1,8 +1,9 @@
 """On-chip block/dtype sweep for the pallas KNN kernels.
 
 Usage: python tools/knn_sweep.py [d]
-Prints qps + TF/s per config using the memoization-safe timing methodology
-from bench.py (lax.map over rolled inputs, scalar-forced).
+Prints qps + TF/s per config; the timing is memoization-safe (lax.map over
+rolled inputs, scalar-forced). It times private kernels and is no benchmark:
+the benchmark is chipbench/run.py.
 """
 
 import sys
