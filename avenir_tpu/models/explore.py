@@ -420,7 +420,7 @@ class ClassPartitionGenerator:
         labels = to_lines(self.ds.labels())
         hists = np.asarray(_level_histogram(
             jnp.zeros(labels.shape, jnp.int32),
-            jnp.asarray(segment_matrix(self.splits, self.ds)),
+            segment_matrix(self.splits, self.ds),
             jnp.asarray(labels), jnp.asarray(to_lines(np.ones(n, np.int32))),
             1, smax, self.k,
         ))[0].astype(np.float64)                             # [NS, smax, k]

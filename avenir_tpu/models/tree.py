@@ -19,7 +19,8 @@ Reference semantics (org.avenir.tree, SURVEY §2.3/§3.4):
   (DecisionTreeBuilder.java:200-236, :353-369).
 
 TPU design: candidate splits are static (schema-driven), so each split is a
-record->segment mapping computed ONCE as an int8 matrix [n_splits, n], held
+record->segment mapping computed ONCE, on the device from the feature
+columns as parsed (`segment_matrix`), as an int8 matrix [n_splits, n], held
 in lines of 128 rows (`to_lines`: 1 B a row and split on the device, where
 a row-major [n, n_splits] is tiled to 128 lanes a row); a tree level is then one pass
 over the rows in blocks, each block a one-hot int8 contraction into the
@@ -416,15 +417,100 @@ def _advance_leaves_forest(leaf_ids, seg_matrix, best_split_of_leaf,
     return leaf_ids
 
 
-def segment_matrix(splits: Sequence[CandidateSplit], ds: Dataset
-                   ) -> np.ndarray:
-    """[NS, R, LANES] int8: every row's segment under every candidate
-    split, in lines as the level pass reads them (1 B a row and split)."""
+#: a categorical column's group is read by a chain of selects over its
+#: codes while it has at most this many, by a gather of the table past it.
+#: On a TPU v5e the chain costs 1.6 ps a row, split and code and the
+#: gather 7.8 ns a row and split whatever the codes (from 128 on; under
+#: that XLA turns the gather into the same selects), so the gather wins
+#: nowhere under some 5,000 codes; but the chain is unrolled into the
+#: program, which compiles 10 ms a select (2.6 s a split at 256 codes, the
+#: most measured: PERF.md, PR 32), and that is what bounds it here
+_SELECT_CODES = 256
+
+
+def up32(bounds) -> np.ndarray:
+    """The smallest float32 not below each float64 bound: for a float32
+    `x`, `float64(x) >= b` exactly when `x >= up32(b)`, so the device
+    compares the column as parsed and agrees with `segment_of` on every
+    value, NaN among them (segment 0 on both sides)."""
+    b = np.asarray(bounds, np.float64)
+    with np.errstate(over="ignore"):
+        f = b.astype(np.float32)
+    return np.where(f < b, np.nextafter(f, np.float32(np.inf)), f)
+
+
+def _segment_tables(splits: Sequence[CandidateSplit]):
+    """What `_segment_lines` takes: the columns some split reads, as
+    (attribute, dtype as parsed) in attribute order; per split (its column
+    among them, numeric or not, its bounds or its column's codes), which
+    is the schema's and static; and the tables it takes as arguments,
+    bounds [NS, KB] float32 rounded up once (`up32`) and padded with +inf,
+    groups [NS, V] int8."""
+    attrs = sorted({sp.attribute for sp in splits})
+    plan = tuple(
+        (attrs.index(sp.attribute), sp._kind == "numeric",
+         len(sp._bounds if sp._kind == "numeric" else sp._group_of))
+        for sp in splits)
+    dtypes = {c: np.float32 if numeric else np.int32 for c, numeric, _ in plan}
+    bounds = np.full((len(splits), max([w for _, num, w in plan if num],
+                                       default=1)), np.inf, np.float32)
+    groups = np.zeros((len(splits), max([w for _, num, w in plan if not num],
+                                        default=1)), np.int8)
+    for i, (sp, (_, numeric, width)) in enumerate(zip(splits, plan)):
+        if numeric:
+            bounds[i, :width] = up32(sp._bounds)
+        else:
+            groups[i, :width] = sp._group_of
+    columns = [(a, dtypes[c]) for c, a in enumerate(attrs)]
+    return columns, plan, bounds, groups
+
+
+@partial(jax.jit, static_argnames=("plan",))
+def _segment_lines(cols, bounds, groups, n, plan):
+    """[NS, R, LANES] int8: `CandidateSplit.segment_of` of every split over
+    its column, elementwise, on feature columns [R, LANES] as the parser
+    left them (float32 numeric, int32 codes). Rows from `n` on read 0."""
+    r, lanes = cols[0].shape
+    line = jnp.arange(r, dtype=jnp.int32)[:, None]
+    lane = jnp.arange(lanes, dtype=jnp.int32)[None]
+    real = (line < n // lanes) | ((line == n // lanes) & (lane < n % lanes))
+    segs = []
+    for s, (c, numeric, width) in enumerate(plan):
+        x = cols[c]
+        if numeric:
+            seg = sum((x >= bounds[s, k]).astype(jnp.int8)
+                      for k in range(width))
+        elif width <= _SELECT_CODES:
+            seg = sum(jnp.where(x == v, groups[s, v], 0)
+                      for v in range(width))
+        else:
+            seg = jnp.take(groups[s], x, mode="clip")
+        segs.append(jnp.where(real, seg, 0).astype(jnp.int8))
+    return jnp.stack(segs)
+
+
+def _segments_note(splits: Sequence[CandidateSplit]) -> Dict:
+    """The `tree.segments` span's attributes."""
+    return {"columns": len({sp.attribute for sp in splits}),
+            "splits": len(splits), "device": True}
+
+
+def segment_matrix(splits: Sequence[CandidateSplit], ds: Dataset,
+                   put=jnp.asarray) -> jax.Array:
+    """[NS, R, LANES] int8 on the device: every row's segment under every
+    candidate split, in lines as the level pass reads them (1 B a row and
+    split), computed there by `_segment_lines` from the columns some split
+    reads. `put` places a column's lines (a mesh shards them, and the
+    program, being elementwise, runs on each shard). Nothing is waited
+    for, and the columns' device buffers go when the program has run."""
     n = len(ds)
-    seg = np.zeros((len(splits), -(-n // LANES) * LANES), np.int8)
-    for i, sp in enumerate(splits):
-        seg[i, :n] = sp.segment_of(np.asarray(ds.column(sp.attribute)))
-    return to_lines(seg)
+    if not splits:
+        return jnp.zeros((0, -(-n // LANES), LANES), jnp.int8)
+    columns, plan, bounds, groups = _segment_tables(splits)
+    cols = tuple(put(to_lines(np.asarray(ds.column(a), dtype)))
+                 for a, dtype in columns)
+    return _segment_lines(cols, jnp.asarray(bounds), jnp.asarray(groups),
+                          np.int32(n), plan=plan)
 
 
 # ---------------------------------------------------------------------------
@@ -817,25 +903,26 @@ class DecisionTreeBuilder:
         n = len(ds)
         with obs.span("tree.fit", rows=n, trees=1, splits=len(self.splits),
                       row_block=min(ROW_BLOCK, n)) as note:
-            with obs.span("tree.segments"):
-                seg = segment_matrix(self.splits, ds)       # [NS, R, LANES]
+            if mesh is not None:
+                from avenir_tpu.parallel.mesh import shard_rows
+
+                # the lines shard; a line of padding weighs 0
+                put = partial(shard_rows, mesh)
+            else:
+                put = jnp.asarray
+            with obs.span("tree.segments", **_segments_note(self.splits)):
+                seg_d = segment_matrix(self.splits, ds, put)
                 labels = to_lines(ds.labels())
                 w_host = whole_weights(row_weights, n)
             digits = _weight_digits(w_host.max(initial=1))
             w_host = to_lines(w_host)
             with obs.span("tree.put"):
+                labels_d = put(labels)
                 if mesh is not None:
-                    from avenir_tpu.parallel.mesh import shard_rows
-
-                    # the lines shard; a line of padding weighs 0
-                    seg_d = shard_rows(mesh, seg, axis=1)
-                    labels_d = shard_rows(mesh, labels)
                     ws_d = shard_rows(mesh, w_host[None], axis=1)
                     leaf_ids = shard_rows(mesh, np.zeros_like(labels)[None],
                                           axis=1)
                 else:
-                    seg_d = jnp.asarray(seg)
-                    labels_d = jnp.asarray(labels)
                     ws_d = jnp.asarray(w_host)[None]
                     leaf_ids = jnp.zeros((1,) + labels.shape, jnp.int32)
             return _grow_forest([self], seg_d, labels_d, ws_d, leaf_ids,
@@ -1049,11 +1136,11 @@ class RandomForestBuilder:
         with obs.span("tree.fit", rows=n, trees=self.num_trees,
                       splits=len(builders[0].splits),
                       row_block=min(ROW_BLOCK, n)) as note:
-            with obs.span("tree.segments"):
-                seg = segment_matrix(builders[0].splits, ds)
+            with obs.span("tree.segments",
+                          **_segments_note(builders[0].splits)):
+                seg_d = segment_matrix(builders[0].splits, ds)
                 labels = to_lines(ds.labels())
             with obs.span("tree.put"):
-                seg_d = jnp.asarray(seg)
                 labels_d = jnp.asarray(labels)
                 leaf_ids = jnp.zeros((self.num_trees,) + labels.shape,
                                      jnp.int32)
@@ -1062,7 +1149,7 @@ class RandomForestBuilder:
             with obs.span("tree.put"):
                 ws_d = jnp.asarray(ws)
             digits = _weight_digits(ws.max(initial=1))
-            del seg, labels, ws
+            del labels, ws
             self.trees = _grow_forest(builders, seg_d, labels_d, ws_d,
                                       leaf_ids, digits, note)
         return self

@@ -516,3 +516,198 @@ def test_random_forest_reads_the_keys_dec_tree_reads(tmp_path, more, holds):
         assert len(single.paths) > 1
         for tr in forest.trees:
             assert tr.to_json() == single.to_json()
+
+
+# ---------------------------------------------------------------------------
+# the segment matrix, made on the device from the parser's columns, against
+# `CandidateSplit.segment_of` stacked on the host: byte for byte
+# ---------------------------------------------------------------------------
+def host_segment_matrix(splits, ds):
+    """What the host made until PR 32: `segment_of` of every split over
+    its whole column, the pad rows 0, in lines."""
+    n = len(ds)
+    seg = np.zeros((len(splits), -(-n // tree_mod.LANES) * tree_mod.LANES),
+                   np.int8)
+    for i, sp in enumerate(splits):
+        seg[i, :n] = sp.segment_of(np.asarray(ds.column(sp.attribute)))
+    return tree_mod.to_lines(seg)
+
+
+def one_feature(field, column):
+    """A dataset of one feature column as the parser leaves it (float32
+    numeric, int32 codes) and a binary class."""
+    schema = FeatureSchema.from_json({"fields": [
+        dict(field, name="x", ordinal=0, feature=True),
+        {"name": "y", "ordinal": 1, "dataType": "categorical",
+         "cardinality": ["no", "yes"]}]})
+    n = len(column)
+    return Dataset(schema, {0: column, 1: np.zeros(n, np.int32)}, n)
+
+
+TENTHS = {"dataType": "double", "min": 0.0, "max": 1.0, "maxSplit": 3,
+          "splitScanInterval": 0.1}
+
+
+def around_the_bounds(field):
+    """Each bound's float32 neighbours (the nearest float32 on either
+    side of a bound that is none itself), and the values one and two ulps
+    under and over each of them."""
+    bounds = np.unique(np.concatenate([
+        s._bounds for s in enumerate_splits(one_feature(
+            field, np.zeros(1, np.float32)).schema)]))
+    near = bounds.astype(np.float32)
+    vals = [near]
+    for toward in (-np.inf, np.inf):
+        step = near
+        for _ in range(3):
+            step = np.nextafter(step, np.float32(toward))
+            vals.append(step)
+    return bounds, np.concatenate(vals).astype(np.float32)
+
+
+def segment_case(name, tmp_path):
+    rng = np.random.default_rng(len(name))
+    if name == "call_hangup_18":
+        train, schema_path, _codes, _y = call_hangup_rows(16_384, 3, tmp_path)
+        full = json.loads(json.dumps(RF_HANGUP["schema"]))
+        full["fields"][-1]["cardinality"] = ["F", "T"]
+        return Dataset.from_csv(train, FeatureSchema.from_json(full)), {}
+    if name == "tenths_on_and_an_ulp_off":
+        bounds, vals = around_the_bounds(TENTHS)
+        # 0.1 is no float32: its neighbours lie on both sides of it
+        assert (vals.astype(np.float64)[:, None] == bounds[None]).sum() < len(bounds)
+        return one_feature(TENTHS, vals), {}
+    if name == "missing_value":
+        col = rng.random(300).astype(np.float32)
+        col[::7] = np.nan
+        return one_feature(TENTHS, col), {}
+    if name == "rows_not_whole_lines":
+        col = rng.random(128 * 5 + 77).astype(np.float32)
+        return one_feature(dict(TENTHS, min=-1.0), col - 0.5), {}
+    cardinality = [f"v{i}" for i in range(
+        5 if name == "three_groups" else tree_mod._SELECT_CODES + 8)]
+    field = {"dataType": "categorical", "cardinality": cardinality,
+             "maxSplit": 3}
+    col = rng.integers(0, len(cardinality), 1_000).astype(np.int32)
+    return one_feature(field, col), {"cat_partition_cap": 40}
+
+
+@pytest.mark.parametrize("name", [
+    "call_hangup_18", "tenths_on_and_an_ulp_off", "missing_value",
+    "rows_not_whole_lines", "three_groups", "codes_past_the_select_chain"])
+def test_device_segment_matrix_is_segment_of_stacked(name, tmp_path):
+    ds, more = segment_case(name, tmp_path)
+    splits = enumerate_splits(ds.schema, **more)
+    want = host_segment_matrix(splits, ds)
+    got = np.asarray(tree_mod.segment_matrix(splits, ds))
+    assert got.dtype == np.int8 and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+    smax = max(s.n_segments for s in splits)
+    if name == "call_hangup_18":
+        assert len(splits) == 18 and got.shape[0] == 18
+    elif name in ("three_groups", "codes_past_the_select_chain"):
+        assert smax == 3 and want.max() == 2
+        past = len(splits[0]._group_of) > tree_mod._SELECT_CODES
+        assert past == (name == "codes_past_the_select_chain")
+    elif name == "missing_value":
+        assert (want.reshape(len(splits), -1)[:, :len(ds):7] == 0).all()
+    elif name == "rows_not_whole_lines":
+        assert len(ds) % tree_mod.LANES
+        assert (got.reshape(len(splits), -1)[:, len(ds):] == 0).all()
+        assert want.max() == 2
+
+
+def test_up32_is_the_least_float32_not_below_the_bound():
+    bounds = np.array([0.1, 1 / 3, 60.0, 16_777_217.0, -0.1, 1e-50, 1e39,
+                       -1e39, 0.0])
+    up = tree_mod.up32(bounds)
+    assert up.dtype == np.float32
+    assert (up.astype(np.float64) >= bounds).all()
+    with np.errstate(over="ignore"):
+        under = np.nextafter(up, np.float32(-np.inf)).astype(np.float64)
+    assert (under < bounds).all()
+    assert up[2] == 60.0 and up[3] == 16_777_218.0 and np.isinf(up[6])
+
+
+def test_the_forest_job_writes_the_bytes_of_a_forest_from_the_host_matrix(
+        tmp_path, monkeypatch):
+    """The size of tests/chipbench/test_forest.py: ten trees over 16,384
+    call-hangup rows, once from the device program and once with the
+    host's stacked `segment_of` handed to the same level passes."""
+    train, schema_path, _codes, _y = call_hangup_rows(16_384, 17, tmp_path)
+    props = forest_properties(schema_path)
+
+    def model_bytes(out):
+        res = run_job("randomForest", props, [train], str(tmp_path / out))
+        assert len(res.outputs) == 10
+        return [open(p, "rb").read() for p in res.outputs]
+
+    device = model_bytes("device")
+    monkeypatch.setattr(
+        tree_mod, "segment_matrix",
+        lambda splits, ds, put=jnp.asarray: put(host_segment_matrix(splits, ds)))
+    assert model_bytes("host") == device
+
+
+def test_the_segment_program_has_a_name_the_level_metric_does_not_read(
+        tmp_path):
+    """The forest job compiles `_segment_lines` as a program of its own
+    name; `forest_level_ms_per_job` counts the level programs by theirs
+    and must not count this one (its roofline reckons the level passes'
+    work only)."""
+    import re
+
+    def module_name(jitted, *args, **static):
+        return re.search(r"module @(\S+)",
+                         jitted.lower(*args, **static).as_text()).group(1)
+
+    train, schema_path, _codes, _y = call_hangup_rows(2_048, 19, tmp_path)
+    tree_mod._segment_lines.clear_cache()
+    run_job("randomForest", forest_properties(schema_path), [train],
+            str(tmp_path / "out"))
+    assert tree_mod._segment_lines._cache_size() == 1
+    col = jnp.zeros((1, tree_mod.LANES), jnp.float32)
+    mine = module_name(
+        tree_mod._segment_lines, (col,), jnp.zeros((1, 1), jnp.float32),
+        jnp.zeros((1, 1), jnp.int8), np.int32(1), plan=((0, True, 1),))
+    ids = jnp.zeros((1, 1, tree_mod.LANES), jnp.int32)
+    seg = jnp.zeros((1, 1, tree_mod.LANES), jnp.int8)
+    level = [
+        module_name(tree_mod._level_histogram_forest, ids, seg, ids[0], ids,
+                    n_leaves=1, smax=2, k=2),
+        module_name(tree_mod._advance_leaves_forest, ids, seg,
+                    jnp.zeros((1, 1), jnp.int32), jnp.zeros((1, 1), jnp.int32))]
+    assert mine == "jit__segment_lines"
+    with open(os.path.join(BENCH, "metrics", "forest_level_ms_per_job.json")) as fh:
+        patterns = json.load(fh)["params"]["patterns"]
+    assert not any(re.search(p, mine) for p in patterns)
+    assert all(any(re.search(p, name) for p in patterns) for name in level)
+    assert not any(re.search("_segment_lines", name) for name in level)
+
+
+def test_under_a_mesh_the_segment_program_runs_on_each_shard(mesh8):
+    """The column lines shard over the mesh and the program is
+    elementwise: the matrix comes out sharded by lines, equal to the
+    host's (lines of padding read 0), and compiles to no collective."""
+    from functools import partial
+
+    from avenir_tpu.parallel.mesh import shard_rows
+
+    ds = hangup_data(128 * 13 + 5, seed=1)
+    splits = enumerate_splits(HANGUP_SCHEMA)
+    put = partial(shard_rows, mesh8)
+    seg = tree_mod.segment_matrix(splits, ds, put)
+    assert tuple(seg.sharding.spec) == (None, "data")
+    want = host_segment_matrix(splits, ds)
+    got = np.asarray(seg)
+    assert got.shape[1] % 8 == 0 and got.shape[1] >= want.shape[1]
+    np.testing.assert_array_equal(got[:, :want.shape[1]], want)
+    assert not got[:, want.shape[1]:].any()
+    columns, plan, bounds, groups = tree_mod._segment_tables(splits)
+    cols = tuple(put(tree_mod.to_lines(np.asarray(ds.column(a), dtype)))
+                 for a, dtype in columns)
+    hlo = tree_mod._segment_lines.lower(
+        cols, jnp.asarray(bounds), jnp.asarray(groups), np.int32(len(ds)),
+        plan=plan).compile().as_text()
+    assert not any(op in hlo for op in (
+        "all-reduce", "all-gather", "all-to-all", "collective-permute"))
