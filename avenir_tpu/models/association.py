@@ -13,17 +13,30 @@ arm.max.ante.size) of each frequent itemset and keeps rules whose
 confidence = support(itemset) / support(antecedent) exceeds
 arm.conf.threshold.
 
-TPU-native design: transactions are multi-hot rows of an [N, V] matrix over
-the item vocabulary (dictionary-encoded at ingest, like every other
-categorical in this framework). Candidate k-itemsets are an [C, V] multi-hot
-matrix; "transaction contains candidate" is exactly
-`(T @ C.T) == k` — one blocked matmul on the MXU per transaction tile
-replaces the Hadoop shuffle. Candidate *generation* stays on the host
-(classical Apriori join + subset prune over the frequent (k-1) sets): it is
-tiny, irregular, and data-dependent — the wrong shape for XLA — while the
-support counting it gates is the N-proportional work and runs on device.
-The per-k loop of the reference's driver survives as a host loop; the
-frequent-itemset state between rounds stays as a plain file via save/load
+TPU-native design: items are dictionary-encoded at ingest, like every other
+categorical in this framework, and after the k=1 round only the frequent
+ones are kept (InfrequentItemMarker applied at ingest). The support
+counting is the N-proportional work and runs on device, by one of two
+routes that write the same bytes:
+
+- resident (FrequentItemsApriori.mine_whole, and mine() for rows already in
+  memory): the file is read whole and tokenised by two native passes
+  (native.ingest: the vocabulary and every item's count, then the baskets
+  packed over the frequent items into bit columns, one row an item, one
+  bit a basket); the columns are put on the chip once and stay there for
+  every round. Pair supports are one Gram matrix, G = sum_t x_t x_t^T,
+  blocked matmuls on the MXU that replace the Hadoop shuffle; a longer
+  candidate's support is the popcount of the AND of its items' columns.
+- re-scan (FrequentItemsApriori.mine_stream: what does not fit the device
+  or the host, a stated block size, exact transaction ids): one streamed
+  scan per itemset length over bit-packed row blocks, counted by a
+  popcount containment fold.
+
+Candidate *generation* stays on the host (classical Apriori join + subset
+prune over the frequent (k-1) sets): it is tiny, irregular, and
+data-dependent — the wrong shape for XLA. The per-k loop of the
+reference's driver survives as a host loop; the frequent-itemset state
+between rounds stays as a plain file via save/load
 (the reference's "model = file between steps" property, SURVEY §5).
 """
 
@@ -97,10 +110,9 @@ def stream_candidate_support(src: "StreamingTransactionSource",
     for packed in double_buffered(src.packed_chunks(block)):
         # host-side span: the donated fold dispatches async, so the
         # duration is dispatch+transfer time, not device occupancy
-        t0 = _obs.now()
-        counts_d = bitset_fold_counts(
-            counts_d, jnp.asarray(packed), cand_d)
-        _obs.record("stream.fold", t0, sink="apriori_support")
+        with _obs.span("stream.fold", sink="apriori_support"):
+            counts_d = bitset_fold_counts(
+                counts_d, jnp.asarray(packed), cand_d)
     return np.asarray(counts_d, np.int64)
 
 
@@ -596,26 +608,6 @@ def _contain_mask(trans: jnp.ndarray, cand: jnp.ndarray, k: int):
     return (trans @ cand.T) >= k                   # [B, C] bool
 
 
-@partial(jax.jit, static_argnames=("k", "block"), donate_argnums=())
-def _contain_counts_resident(trans: jnp.ndarray, cand: jnp.ndarray,
-                             k: int, block: int):
-    """One-call support count over a DEVICE-RESIDENT uint8 multi-hot
-    matrix (rows padded to a multiple of `block`): the per-tile loop runs
-    as a lax.scan inside the executable, so the whole per-k round costs
-    one dispatch instead of N/block host->device transfers — the
-    difference between dispatch-latency-bound and MXU-bound mining."""
-    n, v = trans.shape
-    tiles = trans.reshape(n // block, block, v)
-
-    def step(acc, tile):
-        overlap = tile.astype(jnp.float32) @ cand.T        # [B, C]
-        return acc + jnp.sum(overlap >= k, axis=0, dtype=jnp.int32), None
-
-    counts, _ = jax.lax.scan(
-        step, jnp.zeros((cand.shape[0],), jnp.int32), tiles)
-    return counts
-
-
 def _count_support(multihot: np.ndarray, cand_rows: np.ndarray, k: int,
                    block: int = 8192,
                    want_mask: bool = False):
@@ -678,43 +670,198 @@ class FrequentItemsApriori:
         self.block = block
 
     def mine(self, tx: TransactionSet) -> List[ItemSetList]:
+        """The in-memory form of the resident route: the multi-hot rows
+        are packed over the frequent items into the same bit columns
+        (`ops.bitset`) and counted by the same programs as a file's."""
+        from avenir_tpu.ops.bitset import (columns_from_multihot,
+                                           slab_words_for)
+
         n = len(tx)
         min_count = self.support_threshold * n
-        out: List[ItemSetList] = []
-
-        # k = 1: column sums of the multi-hot matrix
         col_counts = self.multihot_item_counts(tx)
-        freq_ids: List[Tuple[int, ...]] = [
-            (i,) for i in range(len(tx.vocab)) if col_counts[i] > min_count
-        ]
-        out.append(self._pack(
-            tx, freq_ids, 1, [int(col_counts[i]) for (i,) in freq_ids]))
+        freq1 = [i for i in range(len(tx.vocab)) if col_counts[i] > min_count]
+        cols_d = jnp.asarray(columns_from_multihot(
+            tx.multihot[:, freq1], slab_words_for(n)))
+        rounds = [(1, [(i,) for i in freq1],
+                   [int(col_counts[i]) for i in freq1])]
+        rounds += [(k, [tuple(freq1[m] for m in ids_t) for ids_t in ids_k],
+                    counts_k)
+                   for k, ids_k, counts_k in self._resident_rounds(
+                       cols_d, len(freq1), min_count)]
+        return [self._pack(tx, ids_k, k, counts_k)
+                for k, ids_k, counts_k in rounds]
 
-        # one upload, device-resident across all k rounds; zero-padded
-        # rows contain no candidate (overlap 0 < k), so they never count
-        pad_n = (-n) % self.block
-        trans_dev = jnp.asarray(np.pad(tx.multihot, ((0, pad_n), (0, 0))))
+    # ------------------------------------------------- the resident route
+    #: the share of the device's memory the packed baskets may take: the
+    #: rest is head-room for the Gram's unpacked block, its [V, V] sums
+    #: and whatever else the process holds on the chip
+    RESIDENT_SHARE = 0.6
+    #: what a backend that states no limit (the CPU) is held to
+    UNSTATED_LIMIT_BYTES = 2 << 30
+    #: the share of the host's memory the file may take, read whole: the
+    #: packed columns stand beside it until the file is let go
+    HOST_SHARE = 0.25
 
+    @staticmethod
+    def device_bytes_limit() -> int:
+        stats = jax.devices()[0].memory_stats() or {}
+        return int(stats.get("bytes_limit",
+                             FrequentItemsApriori.UNSTATED_LIMIT_BYTES))
+
+    @staticmethod
+    def host_bytes() -> int:
+        return os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
+
+    def resident_words(self, n: int, frequent: int) -> Optional[Tuple[int, int]]:
+        """(slab words, slabs) of the resident bit columns of `n` baskets
+        over `frequent` items, or nothing where they do not fit: the
+        packed bytes (4 a word and item row, `column_rows(frequent)` rows)
+        against `RESIDENT_SHARE` of the device's `bytes_limit`."""
+        from avenir_tpu.ops.bitset import column_rows, slab_words_for
+
+        slab = slab_words_for(n)
+        slabs = -(-max(n, 1) // (slab * 32))
+        fits = (4 * slab * slabs * column_rows(frequent)
+                <= self.RESIDENT_SHARE * self.device_bytes_limit())
+        return (slab, slabs) if fits else None
+
+    def mine_whole(self, paths: Sequence[str], delim: str = ",",
+                   skip_field_count: int = 1, marker: Optional[str] = None
+                   ) -> Optional[Tuple[List[ItemSetList], int]]:
+        """The resident route over a file: read whole, tokenised by two
+        native passes that are each one call (`native.ingest.
+        basket_scan_native`: the vocabulary in order of first appearance
+        and every item's count; `basket_pack_native`: the baskets as bit
+        columns over the frequent items), the columns put on the chip
+        once, every later round one program over them
+        (`_resident_rounds`). No block loop, no sort, no cache. Returns
+        the itemset lists and the number of baskets.
+
+        Returns nothing where the route is not to be taken, and the
+        caller mines the stream instead (`mine_stream` writes the same
+        bytes): exact transaction ids, no native parser for this
+        delimiter, a file over `HOST_SHARE` of the host's memory, or
+        packed baskets that do not fit the device (`resident_words`; known
+        only after pass 1, whose work is then lost)."""
+        from avenir_tpu.native.ingest import (basket_pack_native,
+                                              basket_scan_native,
+                                              native_seq_ready,
+                                              read_files_native)
+        from avenir_tpu.ops.bitset import column_rows
+
+        if (self.emit_trans_id or not native_seq_ready(delim)
+                or sum(os.path.getsize(p) for p in paths)
+                > self.HOST_SHARE * self.host_bytes()):
+            return None
+        with _obs.span("fia.mine", resident=False) as note:
+            with _obs.span("fia.read", files=len(paths)) as read:
+                data = read_files_native(paths)
+                read["nbytes"] = int(data.shape[0])
+            with _obs.span("fia.scan", nth=1) as scanned:
+                scan = basket_scan_native(data, delim, skip_field_count,
+                                          marker)
+                scanned.update(threads=scan.threads, tokens=scan.tokens)
+            n = scan.rows
+            min_count = self.support_threshold * n
+            freq1 = np.flatnonzero(scan.counts > min_count)
+            vm = int(freq1.shape[0])
+            note.update(rows=n, vocab=len(scan.vocab), frequent=vm,
+                        words=-(-vm // 32))
+            fits = self.resident_words(n, vm)
+            if fits is None:
+                return None
+            rounds: List[Tuple[int, List[Tuple[int, ...]], List[int]]] = [
+                (1, [(m,) for m in range(vm)], scan.counts[freq1].tolist())]
+            if self.max_length > 1 and vm:
+                # masked ids are ranks of the ascending original ids, as
+                # the stream's mask_items numbers them
+                item_row = np.full(len(scan.vocab), -1, np.int32)
+                item_row[freq1] = np.arange(vm, dtype=np.int32)
+                with _obs.span("fia.scan", nth=2, threads=scan.threads,
+                               tokens=scan.tokens):
+                    slabs = basket_pack_native(
+                        data, delim, skip_field_count, marker, scan,
+                        item_row, column_rows(vm), fits[0])
+                del data
+                cols_d = self._put_resident(slabs)
+                del slabs
+                rounds += self._resident_rounds(cols_d, vm, min_count)
+                del cols_d
+            note.update(rounds=len(rounds), resident=True)
+            kept = [scan.vocab[i] for i in freq1.tolist()]
+            return [self._item_sets(n, kept.__getitem__, ids_k, k, counts_k)
+                    for k, ids_k, counts_k in rounds], n
+
+    @staticmethod
+    def _put_resident(slabs: np.ndarray) -> jnp.ndarray:
+        """The packed slabs [slabs, rows, words] put on the chip once, a
+        slab at a time into one array that is built in place; it stays
+        there for every later round."""
+        from avenir_tpu.ops.bitset import place_columns
+
+        n_slabs, v_rows, words = slabs.shape
+        with _obs.span("fia.put", nbytes=slabs.nbytes, slabs=n_slabs):
+            cols_d = jnp.zeros((v_rows, n_slabs * words), jnp.uint32)
+            for at in range(n_slabs):
+                # the put is this route's fold of the stream: a slab of it
+                # goes into the resident state (the coverage auditor's name)
+                with _obs.span("stream.fold", sink="apriori_resident"):
+                    cols_d = place_columns(cols_d, jnp.asarray(slabs[at]),
+                                           jnp.int32(at * words))
+            return jax.block_until_ready(cols_d)
+
+    def _resident_rounds(self, cols_d: jnp.ndarray, frequent: int,
+                         min_count: float):
+        """Rounds 2 and up over the resident bit columns: [(k, sets as
+        tuples of masked item ids, counts)]. Round 2 is one Gram matrix
+        (`_pair_gram`): support({a, b}) = G[a, b], and no candidate list
+        exists. A later round's candidates are the host's join and prune;
+        their supports are one program (`_set_supports`), the candidate
+        axis padded to a bucket size so that a recurring round compiles
+        nothing."""
+        from avenir_tpu.ops.bitset import (GRAM_BLOCK_WORDS, _pair_gram,
+                                           _set_supports)
+
+        rounds = []
+        freq_ids: List[Tuple[int, ...]] = [(m,) for m in range(frequent)]
         for k in range(2, self.max_length + 1):
-            cands = _generate_candidates(freq_ids, k)
-            if not cands:
+            with _obs.span("fia.round.candidates", k=k) as note:
+                if k == 2:
+                    n_cands = frequent * (frequent - 1) // 2
+                else:
+                    cands = _generate_candidates(freq_ids, k)
+                    n_cands = len(cands)
+                    c_pad = max(64, 1 << max(n_cands - 1, 0).bit_length())
+                    cand_rows = np.zeros((c_pad, k), np.int32)
+                    cand_rows[:n_cands] = np.asarray(
+                        cands, np.int32).reshape(n_cands, k)
+                note["candidates"] = n_cands
+            if not n_cands:
                 break
-            # pad the candidate axis to a bucket size so recurring rounds
-            # reuse the compiled executable; zero candidate rows count 0
-            c_pad = max(64, 1 << (len(cands) - 1).bit_length())
-            cand_rows = np.zeros((c_pad, tx.multihot.shape[1]),
-                                 dtype=np.float32)
-            for ci, items in enumerate(cands):
-                cand_rows[ci, list(items)] = 1.0
-            counts = np.asarray(_contain_counts_resident(
-                trans_dev, jnp.asarray(cand_rows), k, self.block))[:len(cands)]
-            kept = [(c, int(cnt)) for c, cnt in zip(cands, counts)
-                    if cnt > min_count]
-            if not kept:
+            with _obs.span("fia.round.dispatch", k=k, candidates=n_cands):
+                if k == 2:
+                    block = min(GRAM_BLOCK_WORDS, cols_d.shape[1])
+                    while cols_d.shape[1] % block:
+                        block //= 2
+                    out_d = _pair_gram(cols_d, block)
+                else:
+                    out_d = _set_supports(cols_d, jnp.asarray(cand_rows))
+            with _obs.span("fia.round.fetch", k=k, candidates=n_cands) as note:
+                out = np.asarray(out_d)
+                if k == 2:
+                    a, b = np.nonzero(
+                        np.triu(out[:frequent, :frequent], 1) > min_count)
+                    freq_ids = list(zip(a.tolist(), b.tolist()))
+                    counts = out[a, b].tolist()
+                else:
+                    keep = np.flatnonzero(out[:n_cands] > min_count)
+                    freq_ids = [cands[i] for i in keep]
+                    counts = out[keep].tolist()
+                note["kept"] = len(freq_ids)
+            if not freq_ids:
                 break
-            freq_ids = [c for c, _ in kept]
-            out.append(self._pack(tx, freq_ids, k, [cnt for _, cnt in kept]))
-        return out
+            rounds.append((k, freq_ids, counts))
+        return rounds
 
     def mine_stream(self, src: StreamingTransactionSource
                     ) -> List[ItemSetList]:
@@ -735,45 +882,52 @@ class FrequentItemsApriori:
         dispatch asynchronously with one host pull at the end. Per-k
         re-scans replay the pass-1 encoded-block cache when the sources
         are unchanged (see EncodedBlockCache) instead of re-parsing."""
-        vocab, col_counts, n = src.scan_items()
-        min_count = self.support_threshold * n
+        with _obs.span("fia.mine", resident=False) as note:
+            with _obs.span("fia.scan"):
+                vocab, col_counts, n = src.scan_items()
+            min_count = self.support_threshold * n
 
-        # k = 1 from the scan; install the frequent-item mask so every
-        # later block encodes over the surviving vocabulary only.
-        # Masked ids are ranks of the ascending original ids, so sorted
-        # candidate tuples stay sorted under the remap.
-        freq1 = [i for i in range(len(vocab)) if col_counts[i] > min_count]
-        vm = src.mask_items(freq1)
-        rounds: List[Tuple[int, List[Tuple[int, ...]], List[int]]] = [
-            (1, [(m,) for m in range(vm)],
-             [int(col_counts[i]) for i in freq1])]
+            # k = 1 from the scan; install the frequent-item mask so every
+            # later block encodes over the surviving vocabulary only.
+            # Masked ids are ranks of the ascending original ids, so sorted
+            # candidate tuples stay sorted under the remap.
+            freq1 = [i for i in range(len(vocab))
+                     if col_counts[i] > min_count]
+            vm = src.mask_items(freq1)
+            rounds: List[Tuple[int, List[Tuple[int, ...]], List[int]]] = [
+                (1, [(m,) for m in range(vm)],
+                 [int(col_counts[i]) for i in freq1])]
 
-        freq_ids: List[Tuple[int, ...]] = rounds[0][1]
-        for k in range(2, self.max_length + 1):
-            cands = _generate_candidates(freq_ids, k)
-            if not cands:
-                break
-            # pad the candidate axis to a bucket size so recurring rounds
-            # reuse the compiled executable; zero candidate rows count 0
-            c_pad = max(64, 1 << (len(cands) - 1).bit_length())
-            counts = self._stream_support(src, cands, c_pad)
-            kept = [(c, int(cnt)) for c, cnt in zip(cands, counts[:len(cands)])
-                    if cnt > min_count]
-            if not kept:
-                break
-            freq_ids = [c for c, _ in kept]
-            rounds.append((k, freq_ids, [cnt for _, cnt in kept]))
+            freq_ids: List[Tuple[int, ...]] = rounds[0][1]
+            for k in range(2, self.max_length + 1):
+                cands = _generate_candidates(freq_ids, k)
+                if not cands:
+                    break
+                # pad the candidate axis to a bucket size so recurring
+                # rounds reuse the compiled executable; zero candidate
+                # rows count 0
+                c_pad = max(64, 1 << (len(cands) - 1).bit_length())
+                counts = self._stream_support(src, cands, c_pad)
+                kept = [(c, int(cnt))
+                        for c, cnt in zip(cands, counts[:len(cands)])
+                        if cnt > min_count]
+                if not kept:
+                    break
+                freq_ids = [c for c, _ in kept]
+                rounds.append((k, freq_ids, [cnt for _, cnt in kept]))
+            note.update(rows=n, vocab=len(vocab), frequent=vm,
+                        words=-(-vm // 32), rounds=len(rounds))
 
-        tids = self._collect_trans_ids(src, rounds) \
-            if self.emit_trans_id else None
-        out: List[ItemSetList] = []
-        at = 0
-        for k, ids_k, counts_k in rounds:
-            out.append(self._pack_stream(
-                src, ids_k, k, counts_k,
-                tids[at:at + len(ids_k)] if tids is not None else None))
-            at += len(ids_k)
-        return out
+            tids = self._collect_trans_ids(src, rounds) \
+                if self.emit_trans_id else None
+            out: List[ItemSetList] = []
+            at = 0
+            for k, ids_k, counts_k in rounds:
+                out.append(self._item_sets(
+                    src.n_trans, src.masked_token, ids_k, k, counts_k,
+                    tids[at:at + len(ids_k)] if tids is not None else None))
+                at += len(ids_k)
+            return out
 
     def _stream_support(self, src: StreamingTransactionSource,
                         cand_ids: List[Tuple[int, ...]], c_pad: int
@@ -915,16 +1069,16 @@ class FrequentItemsApriori:
                     tids[ci].append(str(ids[r]))
         return tids
 
-    def _pack_stream(self, src: StreamingTransactionSource,
-                     freq_ids: List[Tuple[int, ...]], k: int,
-                     counts: List[int],
-                     tids: Optional[List[List[str]]] = None) -> ItemSetList:
-        if not freq_ids:
-            return ItemSetList(k, [])
-        n = src.n_trans
+    @staticmethod
+    def _item_sets(n: int, token_of, freq_ids: List[Tuple[int, ...]], k: int,
+                   counts: List[int],
+                   tids: Optional[List[List[str]]] = None) -> ItemSetList:
+        """One length's kept sets as the file holds them: `token_of` turns
+        a masked item id into its token; tokens ascending in a set, sets
+        ascending in the list, support the count over `n`."""
         sets = []
         for ci, ids_t in enumerate(freq_ids):
-            tokens = tuple(sorted(src.masked_token(i) for i in ids_t))
+            tokens = tuple(sorted(token_of(i) for i in ids_t))
             sets.append(ItemSet(tokens, counts[ci] / n, int(counts[ci]),
                                 tids[ci] if tids is not None else None))
         sets.sort(key=lambda s: s.items)

@@ -14,6 +14,9 @@
 // Exposed via ctypes (no pybind11 in the image); see
 // avenir_tpu/native/ingest.py for the Python contract.
 
+#include <fcntl.h>
+#include <unistd.h>
+
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
@@ -589,6 +592,339 @@ int64_t seq_encode(const char* buf, int64_t len, char delim,
         p = nl ? nl + 1 : end;
     }
     return rows;
+}
+
+}  // extern "C"
+
+// --------------------------------------------------------------------------
+// The itemset miner's whole-file scan (models/association.py, the resident
+// route): baskets "id,meta...,item,item,..." of unequal length, tokenised
+// in stripes over lines, an item's token looked up through a flat table
+// keyed on its bytes. Two entry points, each called once a job over the
+// whole buffer: fia_scan finds the vocabulary and every item's basket
+// count, fia_pack sets the baskets' bits in the resident bit columns.
+// Row-ness and token identity are seq_encode's: a line of space, tab and
+// CR alone is no row; a token is trimmed of those three; an empty token
+// and the infrequent-item marker are no items.
+// --------------------------------------------------------------------------
+namespace {
+
+// Open addressing over (key, len): a token of up to 8 bytes IS its key
+// (zero-padded), so a hit compares two integers and touches no string; a
+// longer token's key is its FNV-1a hash and a hit compares the bytes in
+// the arena. A slot is 16 bytes: a thousand items probe inside the L1.
+struct TokenTable {
+    struct Slot { uint64_t key; uint32_t len; int32_t code; };
+    std::vector<Slot> slots;
+    std::vector<char> arena;          // the tokens, back to back, by code
+    std::vector<uint64_t> offs;       // offs[code]: the token's first byte
+    std::vector<uint32_t> lens;
+    int shift = 64;
+
+    static uint64_t key_of(const char* b, size_t n, const char* buf_end) {
+        if (n > 8) return Vocab::hash(b, n);
+        uint64_t k = 0;
+        if (b + 8 <= buf_end) {
+            memcpy(&k, b, 8);
+            if (n < 8) k &= (1ull << (8 * n)) - 1;
+        } else {
+            memcpy(&k, b, n);
+        }
+        return k;
+    }
+
+    size_t home(uint64_t key, uint32_t n) const {
+        return static_cast<size_t>(
+            ((key ^ (n * 0xff51afd7ed558ccdull)) * 0x9e3779b97f4a7c15ull)
+            >> shift);
+    }
+
+    void grow() {
+        size_t cap = slots.empty() ? 64 : slots.size() * 2;
+        std::vector<Slot> old;
+        old.swap(slots);
+        slots.assign(cap, Slot{0, 0, -1});
+        shift = 64 - __builtin_ctzll(cap);
+        for (const Slot& s : old) {
+            if (s.code < 0) continue;
+            size_t h = home(s.key, s.len);
+            while (slots[h].code >= 0) h = (h + 1) & (cap - 1);
+            slots[h] = s;
+        }
+    }
+
+    int32_t size() const { return static_cast<int32_t>(lens.size()); }
+
+    // The token's code, or -1; with `add`, a token not seen yet takes the
+    // next code (only then are its bytes copied).
+    template <bool add>
+    int32_t code_of(const char* b, size_t n, const char* buf_end) {
+        if (slots.empty()) {
+            if (!add) return -1;
+            grow();
+        }
+        const uint64_t key = key_of(b, n, buf_end);
+        const size_t m = slots.size() - 1;
+        size_t h = home(key, static_cast<uint32_t>(n));
+        for (;; h = (h + 1) & m) {
+            const Slot& s = slots[h];
+            if (s.code < 0) break;
+            if (s.key == key && s.len == n
+                && (n <= 8 || memcmp(arena.data() + offs[s.code], b, n) == 0))
+                return s.code;
+        }
+        if (!add) return -1;
+        int32_t code = size();
+        offs.push_back(arena.size());
+        lens.push_back(static_cast<uint32_t>(n));
+        arena.insert(arena.end(), b, b + n);
+        slots[h] = Slot{key, static_cast<uint32_t>(n), code};
+        if (static_cast<size_t>(code + 1) * 2 > slots.size()) grow();
+        return code;
+    }
+};
+
+// on_item(b, n) on every item token of every row of [p, end) and
+// on_row() after each row's items, in file order: the fields from `skip`
+// on, trimmed, the empty token and the marker left out. Returns the rows.
+template <typename OnItem, typename OnRow>
+int64_t for_each_basket(const char* p, const char* end, char delim,
+                        int32_t skip, const char* marker, int64_t marker_len,
+                        OnItem on_item, OnRow on_row) {
+    int64_t rows = 0;
+    while (p < end) {
+        const char* nl = static_cast<const char*>(
+            memchr(p, '\n', static_cast<size_t>(end - p)));
+        const char* e = nl ? nl : end;
+        const char* s = p;
+        while (s < e && (*s == ' ' || *s == '\t' || *s == '\r')) ++s;
+        if (s < e) {                              // not whitespace alone
+            int32_t f = 0;
+            const char* ts = p;
+            for (s = p;; ++s) {
+                if (s == e || *s == delim) {
+                    if (f >= skip) {
+                        const char* a = ts;
+                        const char* b = s;
+                        trim(a, b);
+                        int64_t n = b - a;
+                        if (n > 0 && !(n == marker_len
+                                       && memcmp(a, marker, n) == 0))
+                            on_item(a, static_cast<size_t>(n));
+                    }
+                    ++f;
+                    ts = s + 1;
+                    if (s == e) break;
+                }
+            }
+            on_row();
+            ++rows;
+        }
+        p = nl ? nl + 1 : end;
+    }
+    return rows;
+}
+
+}  // namespace
+
+extern "C" {
+
+// One file read whole into buf[0, len), striped over byte ranges: every
+// stripe preads its own range, so the copy out of the page cache and the
+// first touch of the buffer's pages are the stripes' work and not one
+// thread's. Returns len, or -1 where the file could not be opened or is
+// shorter than len.
+int64_t file_read_mt(const char* path, char* buf, int64_t len,
+                     int32_t n_threads) {
+    int fd = open(path, O_RDONLY);
+    if (fd < 0) return -1;
+    n_threads = stripe_count(len, n_threads);
+    std::vector<char> ok(n_threads, 1);
+    auto read_range = [&](int32_t i) {
+        int64_t at = len * i / n_threads;
+        const int64_t stop = len * (i + 1) / n_threads;
+        while (at < stop) {
+            ssize_t got = pread(fd, buf + at, static_cast<size_t>(stop - at),
+                                static_cast<off_t>(at));
+            if (got <= 0) { ok[i] = 0; return; }
+            at += got;
+        }
+    };
+    if (n_threads == 1 || !run_threads(n_threads, read_range))
+        for (int32_t i = 0; i < n_threads; ++i) { ok[i] = 1; read_range(i); }
+    close(fd);
+    for (char good : ok)
+        if (!good) return -1;
+    return len;
+}
+
+// Pass 1 of the resident route. Every stripe keeps a table of its own
+// and counts, for each of its items, the baskets that hold it (an item
+// twice in a basket counts once: the last basket that counted it is
+// remembered). The stripes' tables are merged in file order, so an
+// item's code is its rank by first appearance in the file whatever the
+// thread count. *out takes one malloc'd buffer the caller hands back to
+// csv_free: n_items int64 counts, then the tokens each followed by '\n'
+// (*token_bytes of them). Returns the number of items, or -1 when the
+// buffer could not be allocated.
+int64_t fia_scan(const char* buf, int64_t len, char delim, int32_t skip,
+                 const char* marker, int64_t marker_len, int32_t n_threads,
+                 char** out, int64_t* token_bytes, int64_t* n_rows,
+                 int64_t* n_tokens, int32_t* threads_used) {
+    // a stripe's state is its thread's alone while it scans: it is built
+    // on the thread's stack and moved here at the end, so that no two
+    // threads write one cache line a token
+    struct Stripe {
+        TokenTable table;
+        std::vector<int64_t> count;
+        int64_t rows = 0, tokens = 0;
+    };
+    const char* end = buf + len;
+    auto scan = [&](Stripe& out, const char* b, const char* e) {
+        Stripe st;
+        std::vector<int64_t> last;
+        int64_t row = 0, tokens = 0;
+        st.rows = for_each_basket(
+            b, e, delim, skip, marker, marker_len,
+            [&](const char* tb, size_t n) {
+                int32_t c = st.table.code_of<true>(tb, n, end);
+                if (static_cast<size_t>(c) == st.count.size()) {
+                    st.count.push_back(0);
+                    last.push_back(-1);
+                }
+                if (last[c] != row) {
+                    last[c] = row;
+                    ++st.count[c];
+                }
+                ++tokens;
+            },
+            [&] { ++row; });
+        st.tokens = tokens;
+        out = std::move(st);
+    };
+    n_threads = stripe_count(len, n_threads);
+    std::vector<Stripe> stripes(n_threads);
+    if (n_threads > 1) {
+        std::vector<const char*> bounds = stripe_bounds(buf, len, n_threads);
+        if (!run_threads(n_threads, [&](int32_t i) {
+                scan(stripes[i], bounds[i], bounds[i + 1]);
+            }))
+            n_threads = 1;                  // a failed spawn: start over
+    }
+    if (n_threads == 1) {
+        stripes.assign(1, Stripe());
+        scan(stripes[0], buf, end);
+    }
+    *threads_used = n_threads;
+    TokenTable all;
+    std::vector<int64_t> count;
+    *n_rows = 0;
+    *n_tokens = 0;
+    for (const Stripe& st : stripes) {
+        for (int32_t c = 0; c < st.table.size(); ++c) {
+            const char* tb = st.table.arena.data() + st.table.offs[c];
+            // the arena's end bounds the 8-byte load here
+            int32_t g = all.code_of<true>(
+                tb, st.table.lens[c],
+                st.table.arena.data() + st.table.arena.size());
+            if (static_cast<size_t>(g) == count.size()) count.push_back(0);
+            count[g] += st.count[c];
+        }
+        *n_rows += st.rows;
+        *n_tokens += st.tokens;
+    }
+    const int64_t v = all.size();
+    *token_bytes = static_cast<int64_t>(all.arena.size()) + v;
+    char* w = static_cast<char*>(
+        malloc(static_cast<size_t>(8 * v + *token_bytes + 1)));
+    if (!w) return -1;
+    *out = w;
+    memcpy(w, count.data(), static_cast<size_t>(8 * v));
+    w += 8 * v;
+    for (int64_t c = 0; c < v; ++c) {
+        memcpy(w, all.arena.data() + all.offs[c], all.lens[c]);
+        w += all.lens[c];
+        *w++ = '\n';
+    }
+    return v;
+}
+
+// Pass 2 of the resident route: every basket's bits set in the packed
+// columns. `vocab` holds n_vocab tokens each followed by '\n' (fia_scan's
+// order), item_row[code] the item's row in the columns or -1 for an item
+// that is not kept. `cols` is uint32 [n_slabs, v_rows, slab_words], all
+// zero on entry: basket t is bit t % 32 of word (t / 32) % slab_words of
+// its item's row in slab t / 32 / slab_words. A token twice in a basket
+// sets one bit twice. The stripes are csv_parse_mt's: counted, prefix-
+// summed into a first basket each, then packed in parallel; two stripes
+// share at most the word their edge falls in, which they OR atomically.
+// Returns the baskets packed, -1 when the file holds another number of
+// rows than n_rows, more than the slabs have room for, or `vocab` fewer
+// tokens than n_vocab.
+int64_t fia_pack(const char* buf, int64_t len, char delim, int32_t skip,
+                 const char* marker, int64_t marker_len,
+                 const char* vocab, int64_t vocab_bytes, int32_t n_vocab,
+                 const int32_t* item_row, uint32_t* cols, int64_t v_rows, int64_t slab_words,
+                 int64_t n_slabs, int64_t n_rows, int32_t n_threads) {
+    if (n_rows > n_slabs * slab_words * 32) return -1;
+    const char* end = buf + len;
+    TokenTable table;
+    {
+        const char* v = vocab;
+        const char* v_end = vocab + vocab_bytes;
+        for (int32_t i = 0; i < n_vocab; ++i) {
+            const char* nl = static_cast<const char*>(
+                memchr(v, '\n', static_cast<size_t>(v_end - v)));
+            if (!nl) return -1;
+            table.code_of<true>(v, static_cast<size_t>(nl - v), nl);
+            v = nl + 1;
+        }
+    }
+    n_threads = stripe_count(len, n_threads);
+    std::vector<const char*> bounds = stripe_bounds(buf, len, n_threads);
+    std::vector<int64_t> base(n_threads + 1, 0);
+    {
+        std::vector<int64_t> rows(n_threads, 0);
+        auto count = [&](int32_t i) {
+            rows[i] = count_range(bounds[i], bounds[i + 1]);
+        };
+        if (n_threads == 1 || !run_threads(n_threads, count))
+            for (int32_t i = 0; i < n_threads; ++i) count(i);
+        for (int32_t i = 0; i < n_threads; ++i) base[i + 1] = base[i] + rows[i];
+    }
+    if (base[n_threads] != n_rows) return -1;
+    auto pack = [&](int32_t i) {
+        if (base[i + 1] == base[i]) return;
+        int64_t t = base[i];
+        const int64_t w_first = t >> 5, w_last = (base[i + 1] - 1) >> 5;
+        uint32_t* at = nullptr;       // the basket's word in item row 0
+        uint32_t bit = 0;
+        bool edge = false;
+        auto place = [&] {
+            const int64_t w = t >> 5;
+            at = cols + (w / slab_words) * v_rows * slab_words
+                 + w % slab_words;
+            bit = 1u << (t & 31);
+            edge = w == w_first || w == w_last;
+        };
+        place();
+        for_each_basket(
+            bounds[i], bounds[i + 1], delim, skip, marker, marker_len,
+            [&](const char* tb, size_t n) {
+                int32_t c = table.code_of<false>(tb, n, end);
+                if (c < 0 || item_row[c] < 0) return;
+                uint32_t* word = at + item_row[c] * slab_words;
+                if (edge) __atomic_fetch_or(word, bit, __ATOMIC_RELAXED);
+                else *word |= bit;
+            },
+            [&] { if (++t < base[i + 1]) place(); });
+    };
+    if (n_threads == 1 || !run_threads(n_threads, pack)) {
+        // a failed spawn may have packed some stripes: setting a bit
+        // again changes nothing, so the whole file is packed in turn
+        for (int32_t i = 0; i < n_threads; ++i) pack(i);
+    }
+    return n_rows;
 }
 
 }  // extern "C"

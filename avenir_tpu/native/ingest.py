@@ -19,7 +19,7 @@ import os
 import platform
 import subprocess
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -116,6 +116,18 @@ def _build() -> Optional[ctypes.CDLL]:
     lib.seq_encode.restype = i64
     lib.seq_encode.argtypes = [c_char_p, i64, ctypes.c_char,
                                c_char_p, i32, p_i32, i64, p_i64_arr, i64]
+    lib.file_read_mt.restype = i64
+    lib.file_read_mt.argtypes = [c_char_p, np.ctypeslib.ndpointer(
+        np.uint8, flags="C_CONTIGUOUS"), i64, i32]
+    lib.fia_scan.restype = i64
+    lib.fia_scan.argtypes = [c_char_p, i64, ctypes.c_char, i32, c_char_p, i64,
+                             i32, ctypes.POINTER(ctypes.c_void_p), p_i64,
+                             p_i64, p_i64, ctypes.POINTER(i32)]
+    lib.fia_pack.restype = i64
+    lib.fia_pack.argtypes = [
+        c_char_p, i64, ctypes.c_char, i32, c_char_p, i64, c_char_p, i64, i32,
+        p_i32, np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS"),
+        i64, i64, i64, i64, i32]
     return lib
 
 
@@ -320,6 +332,112 @@ def seq_encode_native(data: bytes, delim: str, vocab: List[str]
     if got != n_rows:
         raise RuntimeError(f"seq_encode row mismatch: {got} != {n_rows}")
     return codes[: int(offsets[n_rows])], offsets
+
+
+class BasketScan(NamedTuple):
+    """What `basket_scan_native` found in one file of baskets."""
+    vocab: List[str]        # item tokens, in order of first appearance
+    blob: bytes             # the same tokens as read, each followed by \n
+    counts: np.ndarray      # int64 [V]: the baskets that hold each item
+    rows: int
+    tokens: int             # item tokens read (repeats included)
+    threads: int            # stripes the library cut
+
+
+def read_files_native(paths: Sequence[str], threads: int = 0) -> np.ndarray:
+    """The files read whole into one uint8 buffer, one after the other, a
+    file that ends inside a line ended with a newline: each by one native
+    call whose stripes pread their own byte ranges (`file_read_mt`), so
+    the copy and the first touch of the buffer's pages are spread over
+    the library's threads."""
+    lib = _get_lib()
+    if lib is None:
+        raise RuntimeError("native CSV ingest unavailable (no g++?)")
+    sizes = [os.path.getsize(p) for p in paths]
+    data = np.empty(sum(sizes) + len(sizes), np.uint8)
+    at = 0
+    for path, size in zip(paths, sizes):
+        if size and int(lib.file_read_mt(os.fsencode(path), data[at:at + size],
+                                         size, np.int32(threads))) != size:
+            raise OSError(f"could not read {size} bytes of {path!r}")
+        at += size
+        if size and data[at - 1] != 10:
+            data[at] = 10
+            at += 1
+    return data[:at]
+
+
+def _buffer_args(data) -> Tuple[object, int]:
+    """(pointer, length) of a text buffer held as bytes or as a uint8
+    array (what `read_files_native` returns)."""
+    if isinstance(data, np.ndarray):
+        return data.ctypes.data_as(ctypes.c_char_p), int(data.shape[0])
+    return data, len(data)
+
+
+def _marker_args(marker: Optional[str]) -> Tuple[Optional[bytes], int]:
+    if marker is None:
+        return None, -1
+    m = marker.encode()
+    return m, len(m)
+
+
+def basket_scan_native(data, delim: str, skip: int,
+                       marker: Optional[str] = None,
+                       threads: int = 0) -> BasketScan:
+    """Pass 1 of the itemset miner's resident route over a whole file in
+    memory (bytes, or the uint8 buffer of `read_files_native`): one native
+    call, striped over lines inside (`fia_scan`). The
+    fields from `skip` on are items; token identity is `seq_encode`'s
+    (space, tab and CR trimmed, the empty token and `marker` dropped). An
+    item's code is its rank by first appearance, whatever `threads` is."""
+    lib = _get_lib()
+    if lib is None:
+        raise RuntimeError("native CSV ingest unavailable (no g++?)")
+    out = ctypes.c_void_p()
+    tok_bytes, rows, tokens = (ctypes.c_int64(0) for _ in range(3))
+    used = ctypes.c_int32(0)
+    v = int(lib.fia_scan(
+        *_buffer_args(data), delim.encode()[0:1], np.int32(skip),
+        *_marker_args(marker), np.int32(threads), ctypes.byref(out),
+        ctypes.byref(tok_bytes), ctypes.byref(rows), ctypes.byref(tokens),
+        ctypes.byref(used)))
+    if v < 0:
+        raise MemoryError("fia_scan could not allocate its result")
+    try:
+        raw = ctypes.string_at(out, 8 * v + tok_bytes.value)
+    finally:
+        lib.csv_free(out)
+    blob = raw[8 * v:]
+    return BasketScan(blob.decode("utf-8", "replace").split("\n")[:-1], blob,
+                      np.frombuffer(raw, np.int64, v).copy(), rows.value,
+                      tokens.value, used.value)
+
+
+def basket_pack_native(data, delim: str, skip: int,
+                       marker: Optional[str], scan: BasketScan,
+                       item_row: np.ndarray, v_rows: int, slab_words: int,
+                       threads: int = 0) -> np.ndarray:
+    """Pass 2 of the resident route: the file's baskets as packed bit
+    columns, uint32 [slabs, v_rows, slab_words], made by one native call
+    (`fia_pack`) into one array. Basket t is bit t % 32 of word
+    (t // 32) % slab_words of row `item_row[code]` of slab
+    t // 32 // slab_words; an item whose `item_row` is -1 is left out and
+    the baskets past the file's last are all zero. The slabs are
+    contiguous, so each goes to the device with no copy."""
+    lib = _get_lib()
+    if lib is None:
+        raise RuntimeError("native CSV ingest unavailable (no g++?)")
+    n_slabs = max(-(-scan.rows // (slab_words * 32)), 1)
+    cols = np.zeros((n_slabs, v_rows, slab_words), np.uint32)
+    got = int(lib.fia_pack(
+        *_buffer_args(data), delim.encode()[0:1], np.int32(skip),
+        *_marker_args(marker), scan.blob, len(scan.blob), len(scan.vocab),
+        np.ascontiguousarray(item_row, np.int32), cols, v_rows, slab_words,
+        n_slabs, scan.rows, np.int32(threads)))
+    if got != scan.rows:
+        raise RuntimeError(f"fia_pack row mismatch: {got} != {scan.rows}")
+    return cols
 
 
 def native_seq_ready(delim: str) -> bool:

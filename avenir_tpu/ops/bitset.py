@@ -20,6 +20,12 @@ single fused [C_total, W] candidate matrix per chunk. Blocks shrink ~8x
 (uint32 bitset vs uint8 multi-hot), which is what keeps the 100M-row
 streamed Apriori inside its RSS budget. `jnp`-portable: population_count
 lowers to the VPU on TPU and to vectorized code on CPU.
+
+The second half of the module is the miners' resident route: the same
+baskets item-major, as bit columns that stay on the chip across the
+rounds (`place_columns`), the pairs' Gram matrix on the MXU
+(`_pair_gram`) and the longer sets' supports as popcounts of ANDed
+columns (`_set_supports`).
 """
 
 from __future__ import annotations
@@ -129,3 +135,96 @@ def packed_block_nbytes(block_rows: int, n_bits: int) -> Tuple[int, int]:
     """(packed, dense) block byte sizes — the ~8x RSS headroom the packed
     path buys; surfaced so benches can report it without re-deriving."""
     return (block_rows * words_for(n_bits) * 4, block_rows * max(n_bits, 1))
+
+
+# --------------------------------------------------------------------------
+# Resident bit columns: the baskets kept on the chip across the rounds
+# --------------------------------------------------------------------------
+# The miners' resident route holds the baskets item-major: uint32
+# [words_for(V) * 32, n_pad / 32], bit t % 32 of word t // 32 of row i says
+# whether basket t holds item i. A basket costs words_for(V) * 4 bytes
+# and nothing else: both axes are whole tiles (the item axis a multiple of
+# 32, the word axis of SLAB_ALIGN), so the chip pads neither. Rows past V
+# and baskets past n are all zero and count nowhere.
+SLAB_ALIGN = 128                 # words: a slab is whole lanes
+GRAM_BLOCK_WORDS = 8192          # 262,144 baskets a block of the Gram
+_F32_EXACT = 1 << 24             # a float32 sum of ones is exact up to here
+
+
+def column_rows(n_bits: int) -> int:
+    """Rows of the resident column array for n_bits items."""
+    return words_for(n_bits) * WORD_BITS
+
+
+def slab_words_for(n_rows: int, most_rows: int = 1 << 17) -> int:
+    """Words (of 32 baskets) in one slab of the resident columns, which
+    is what one put carries to the chip: whole lanes, at most `most_rows`
+    baskets, no more than the file needs."""
+    words = -(-max(n_rows, 1) // WORD_BITS)
+    words = -(-words // SLAB_ALIGN) * SLAB_ALIGN
+    return min(words, most_rows // WORD_BITS)
+
+
+def columns_from_multihot(multihot: np.ndarray, words: int) -> np.ndarray:
+    """uint8 multi-hot [N, V] -> resident columns uint32
+    [column_rows(V), words] (words * 32 >= N)."""
+    n, v = multihot.shape
+    out = np.zeros((column_rows(v), words * 4), np.uint8)
+    packed = np.packbits(np.ascontiguousarray(multihot.T, dtype=np.uint8),
+                         axis=1, bitorder="little")
+    out[:v, :packed.shape[1]] = packed
+    return out.view(np.uint32)
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def place_columns(cols: jnp.ndarray, slab: jnp.ndarray,
+                  word_at: jnp.ndarray) -> jnp.ndarray:
+    """`cols` with `slab` written at word `word_at`; `cols` is DONATED, so
+    the resident array is built in place, a slab at a time."""
+    return jax.lax.dynamic_update_slice_in_dim(cols, slab, word_at, axis=1)
+
+
+@partial(jax.jit, static_argnames=("block_words",))
+def _pair_gram(cols: jnp.ndarray, block_words: int) -> jnp.ndarray:
+    """G[a, b] = #baskets that hold items a and b, int32 [V_rows, V_rows],
+    over the resident columns: the sum over baskets of x x^T as matmuls on
+    the MXU. A block of `block_words` words is unpacked one bit plane at a
+    time (32 baskets a word, in any order: a sum over baskets) to 0/1 in
+    bfloat16; the planes of a block add up in float32, exact while the
+    block holds at most 2^24 baskets, and the blocks add up in int32. The
+    diagonal is every item's own count."""
+    v_rows, n_words = cols.shape
+    assert n_words % block_words == 0
+    assert block_words * WORD_BITS <= _F32_EXACT
+
+    def block(i, total):
+        words = jax.lax.dynamic_slice_in_dim(
+            cols, i * block_words, block_words, axis=1)
+
+        def plane(j, acc):
+            x = ((words >> j.astype(jnp.uint32)) & 1).astype(jnp.bfloat16)
+            return acc + jax.lax.dot_general(
+                x, x, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        part = jax.lax.fori_loop(
+            0, WORD_BITS, plane, jnp.zeros((v_rows, v_rows), jnp.float32))
+        return total + part.astype(jnp.int32)
+
+    return jax.lax.fori_loop(0, n_words // block_words, block,
+                             jnp.zeros((v_rows, v_rows), jnp.int32))
+
+
+@jax.jit
+def _set_supports(cols: jnp.ndarray, cands: jnp.ndarray) -> jnp.ndarray:
+    """counts[c] = #baskets that hold every item of cands[c], int32 [C]:
+    the popcount of the AND of the candidate's columns, a candidate at a
+    time over the resident columns. cands int32 [C, k]; a padding row
+    names item 0 k times and its count is dropped by the caller."""
+    def one(items):
+        both = cols[items[0]]
+        for j in range(1, cands.shape[1]):
+            both = both & cols[items[j]]
+        return jnp.sum(jax.lax.population_count(both).astype(jnp.int32))
+
+    return jax.lax.map(one, cands)

@@ -792,11 +792,12 @@ def _write_apriori_outputs(cfg: JobConfig, output: str, levels) -> List[str]:
     # emits job.finish from one place
     t0 = _obs.now()
     outs = []
-    os.makedirs(output or ".", exist_ok=True)
-    for k, isl in enumerate(levels, start=1):
-        p = os.path.join(output, f"itemsets-{k}.txt")
-        isl.save(p, delim=cfg.field_delim)
-        outs.append(p)
+    with _obs.span("fia.output.write", files=len(levels)):
+        os.makedirs(output or ".", exist_ok=True)
+        for k, isl in enumerate(levels, start=1):
+            p = os.path.join(output, f"itemsets-{k}.txt")
+            isl.save(p, delim=cfg.field_delim)
+            outs.append(p)
     _obs.record("job.finish", t0, job="frequentItemsApriori")
     return outs
 
@@ -2799,50 +2800,38 @@ def apriori_job(cfg: JobConfig, inputs: List[str], output: str) -> JobResult:
     """All k-rounds internal; per-k itemset files written like the
     reference's per-round outputs (FrequentItemsApriori.java:123-126)."""
     from avenir_tpu.models.association import (FrequentItemsApriori,
-                                               StreamingTransactionSource,
-                                               TransactionSet)
+                                               StreamingTransactionSource)
 
     miner = FrequentItemsApriori(
         support_threshold=cfg.assert_float("support.threshold"),
         max_length=cfg.get_int("item.set.length", 3),
         emit_trans_id=cfg.get_bool("emit.trans.id", False),
     )
-    trans_id_ord = cfg.get_int("tans.id.ord", 0)
     skip = cfg.get_int("skip.field.count", 1)
     marker = cfg.get("infreq.item.marker")
-    total_bytes = sum(os.path.getsize(p) for p in inputs
-                      if os.path.exists(p))
-    in_ram = (cfg.get("stream.block.size.mb") is None
-              and total_bytes < (256 << 20))
-    # timer before the in-RAM probe's file read: RowsPerSec must price
-    # both paths' I/O identically (see gsp_job)
     t0 = time.perf_counter()
-    if in_ram:
-        # space/tab/CR trim: both apriori entry points and the native
-        # counting pass must agree on token identity
-        rows = [[t.strip(" \t\r") for t in ln.split(cfg.field_delim_regex)]
-                for path in inputs for ln in _read_lines(path)]
-        # the in-RAM cost is the [N, V] multi-hot matrix, which can dwarf
-        # the file bytes for a wide item catalog — gate on its footprint
-        vocab = {tok for row in rows for tok in row[skip:]
-                 if tok and tok != marker}
-        in_ram = len(rows) * max(len(vocab), 1) < (2 << 30)
-    if in_ram:
-        # in-RAM input: one upload, device-resident across all k rounds
-        # (_contain_counts_resident — one dispatch per k, not per block)
-        levels = miner.mine(TransactionSet.from_rows(
-            rows, trans_id_ord=trans_id_ord, skip_field_count=skip,
-            marker=marker))
-        n_rows = len(rows)
+    # two routes that write the same bytes. Resident: the file read whole,
+    # two native passes, the packed baskets on the chip from the scan to
+    # the last round (FrequentItemsApriori.mine_whole says when it cannot
+    # be). A conf that states a block size asks for the block scan, and
+    # gets the streamed route with its cache, sidecar and O(block) RSS.
+    whole = None
+    if cfg.get("stream.block.size.mb") is None:
+        whole = miner.mine_whole(inputs, delim=cfg.field_delim_regex,
+                                 skip_field_count=skip, marker=marker)
+    cache_counters = {}
+    if whole is not None:
+        levels, n_rows = whole
     else:
-        # beyond-RAM (or explicitly chunked): one streamed scan per
-        # itemset length — the reference's per-k MR jobs over the same
-        # HDFS input, bit-packed over the frequent vocabulary after k=1,
-        # and per-k re-scans replay the pass-1 encoded-block cache
-        # instead of re-parsing CSV; host RSS stays O(block) at any size
+        # one streamed scan per itemset length — the reference's per-k MR
+        # jobs over the same HDFS input, bit-packed over the frequent
+        # vocabulary after k=1, and per-k re-scans replay the pass-1
+        # encoded-block cache instead of re-parsing CSV; host RSS stays
+        # O(block) at any size
         src = StreamingTransactionSource(
             inputs, delim=cfg.field_delim_regex,
-            trans_id_ord=trans_id_ord, skip_field_count=skip, marker=marker,
+            trans_id_ord=cfg.get_int("tans.id.ord", 0),
+            skip_field_count=skip, marker=marker,
             block_bytes=int(cfg.get_float("stream.block.size.mb", 64.0)
                             * (1 << 20)),
             spill_cache=cfg.get_bool("stream.encoded.cache", True),
@@ -2854,7 +2843,7 @@ def apriori_job(cfg: JobConfig, inputs: List[str], output: str) -> JobResult:
         src.close()
     counters = {"Apriori:MaxLength": len(levels),
                 **throughput_counters(n_rows, time.perf_counter() - t0),
-                **(cache_counters if not in_ram else {})}
+                **cache_counters}
     outs = _write_apriori_outputs(cfg, output, levels)
     return JobResult("frequentItemsApriori", counters, outs, levels)
 
