@@ -53,6 +53,7 @@ import jax.numpy as jnp
 from avenir_tpu import obs
 from avenir_tpu.core.dataset import Dataset
 from avenir_tpu.core.schema import FeatureField, FeatureSchema
+from avenir_tpu.native import ingest
 from avenir_tpu.utils.metrics import ConfusionMatrix
 
 ROOT_PATH = "$root"
@@ -1066,14 +1067,24 @@ class RandomForestBuilder:
 
     The sampling rule is the job's contract: **the forest is a function of
     the input file and the seed.** One `np.random.default_rng(seed)` serves
-    all trees, in tree order. Under `withReplace` tree t's sample is
-    `rng.integers(0, n, n)`, and a row's weight is how often it was drawn
-    (`np.bincount(idx, minlength=n)`); under `withoutReplace` the weight
-    is `rng.random(n) < sample_rate`; any other strategy weighs every row
-    1 and draws nothing. Tree t picks its node attributes with a
-    generator of its own, `np.random.default_rng(seed + t)`, through
-    `choice` in `DecisionTreeBuilder._allowed_splits`. Counting, converting
-    and copying may move between threads; the draws may not change."""
+    all trees, in tree order. Under `withReplace` tree t's sample is the
+    values of the t-th `rng.integers(0, n, n)`, and a row's weight is how
+    often it was drawn (`np.bincount(idx, minlength=n)`); under
+    `withoutReplace` the weight is `rng.random(n) < sample_rate`; any
+    other strategy weighs every row 1 and draws nothing. Tree t picks its
+    node attributes with a generator of its own,
+    `np.random.default_rng(seed + t)`, through `choice` in
+    `DecisionTreeBuilder._allowed_splits`. The contract is those values,
+    not who computes them or in what order: the numpy loop in `_sample` is
+    the written rule, and where it can the native library walks the same
+    stream by position on every core (`ingest.bootstrap_counts_native`). That walk
+    rests on three facts of numpy that no interface states: `default_rng`
+    is PCG64, whose 64-bit outputs `integers` takes as two 32-bit values,
+    the low half first, while `n - 1 < 2^32 - 1`; each value x gives the
+    draw `(x * n) >> 32` by Lemire's rule; and x is thrown away when
+    `uint32(x * n) < (2^32 - n) mod n`. A numpy that changes one of them
+    is noticed by the test that holds the walk equal to the loop, element
+    for element (`tests/test_tree.py`), and by nothing else."""
 
     def __init__(
         self,
@@ -1095,17 +1106,26 @@ class RandomForestBuilder:
         self.class_values = schema.class_values()
         self._evaluator: Optional[DevicePathEvaluator] = None
 
-    def _sample(self, n: int) -> np.ndarray:
-        """[T, R, LANES] int32: how often each tree's sample holds each row, by
-        the sampling rule of the class docstring. The draws stay on the
-        caller's thread, in tree order; counting a finished draw runs on
-        a worker while the next is drawn."""
+    def _sample(self, n: int) -> Tuple[np.ndarray, int, Dict]:
+        """([T, R, LANES] int32: how often each tree's sample holds each row,
+        by the sampling rule of the class docstring; the largest of them;
+        what the `forest.sample` span says of how they were made: `native`,
+        `threads` that drew and, where the walk counted them, `rejected`)."""
         rng = np.random.default_rng(self.seed)
         ws = np.zeros((self.num_trees, -(-n // LANES) * LANES), np.int32)
+        walk = (ingest.bootstrap_counts_native(rng, n, ws)
+                if self.sampling == "withReplace" and ingest.native_available()
+                else None)
+        if walk is not None:
+            return to_lines(ws), walk.max_weight, dict(
+                native=True, threads=walk.threads, rejected=walk.rejected)
 
         def count(t: int, idx: np.ndarray) -> None:
             ws[t, :n] = np.bincount(idx, minlength=n)
 
+        # the written rule: the draws stay on the caller's thread, in tree
+        # order; counting a finished draw runs on a worker while the next
+        # is drawn
         with ThreadPoolExecutor(_SAMPLE_WORKERS) as pool:
             pending = []
             for t in range(self.num_trees):
@@ -1117,7 +1137,8 @@ class RandomForestBuilder:
                     ws[t, :n] = 1
             for job in pending:
                 job.result()
-        return to_lines(ws)
+        return (to_lines(ws), int(ws.max(initial=1)),
+                dict(native=False, threads=1))
 
     def fit(self, ds: Dataset) -> "RandomForestBuilder":
         """All trees grow together, one batched device call per level:
@@ -1144,11 +1165,12 @@ class RandomForestBuilder:
                 labels_d = jnp.asarray(labels)
                 leaf_ids = jnp.zeros((self.num_trees,) + labels.shape,
                                      jnp.int32)
-            with obs.span("forest.sample", sampling=self.sampling):
-                ws = self._sample(n)
+            with obs.span("forest.sample", sampling=self.sampling) as how:
+                ws, heaviest, made = self._sample(n)
+                how.update(made)
             with obs.span("tree.put"):
                 ws_d = jnp.asarray(ws)
-            digits = _weight_digits(ws.max(initial=1))
+            digits = _weight_digits(heaviest)
             del labels, ws
             self.trees = _grow_forest(builders, seg_d, labels_d, ws_d,
                                       leaf_ids, digits, note)

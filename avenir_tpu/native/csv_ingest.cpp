@@ -17,6 +17,7 @@
 #include <fcntl.h>
 #include <unistd.h>
 
+#include <algorithm>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
@@ -925,6 +926,203 @@ int64_t fia_pack(const char* buf, int64_t len, char delim, int32_t skip,
         for (int32_t i = 0; i < n_threads; ++i) pack(i);
     }
     return n_rows;
+}
+
+}  // extern "C"
+
+// --------------------------------------------------------------------------
+// The forest's bootstrap counts (models/tree.py, RandomForestBuilder): how
+// often each tree's sample holds each row, where tree t's sample is the
+// t-th `integers(0, n, n)` of one numpy `default_rng`. The values are the
+// job's contract, so this walks numpy's own stream, PCG64, and maps it by
+// numpy's own rule; what it does not keep is numpy's order of work. The
+// stream is a 128-bit LCG (output XSL-RR of the state after the step), so
+// any position is reached in O(log) steps; `integers` below 2^32 takes
+// 32-bit values from it, the low half of a 64-bit output and then the
+// high half, and maps each by Lemire's rule on its own: m = x * n, the
+// draw is m >> 32, and x is thrown away when uint32(m) < (2^32 - n) mod n.
+// So the k-th draw of the forest is the k-th kept value of the stream,
+// whoever generates it.
+// --------------------------------------------------------------------------
+namespace {
+
+typedef unsigned __int128 u128;
+
+struct Pcg64 {
+    u128 state, inc;
+
+    static u128 mult() {
+        return (static_cast<u128>(0x2360ED051FC65DA4ull) << 64)
+               | 0x4385DF649FCCF645ull;
+    }
+
+    // the generator `steps` outputs further on
+    void advance(uint64_t steps) {
+        u128 acc_mult = 1, acc_plus = 0, cur_mult = mult(), cur_plus = inc;
+        for (; steps; steps >>= 1) {
+            if (steps & 1) {
+                acc_mult *= cur_mult;
+                acc_plus = acc_plus * cur_mult + cur_plus;
+            }
+            cur_plus *= cur_mult + 1;
+            cur_mult *= cur_mult;
+        }
+        state = acc_mult * state + acc_plus;
+    }
+
+    uint64_t next() {
+        state = state * mult() + inc;
+        const uint64_t hi = static_cast<uint64_t>(state >> 64);
+        const uint64_t x = hi ^ static_cast<uint64_t>(state);
+        const unsigned rot = static_cast<unsigned>(hi >> 58);
+        return (x >> rot) | (x << ((64 - rot) & 63));
+    }
+};
+
+// The 32-bit values of 64-bit outputs [q0, q1) of g's stream in numpy's
+// order, each mapped by Lemire's rule for [0, n): kept(draw) on every one
+// that gives a draw, until it returns false. Returns the 32-bit values
+// read, the last kept one included.
+template <typename Kept>
+int64_t walk_draws(Pcg64 g, uint64_t q0, uint64_t q1, uint64_t n,
+                   uint32_t threshold, Kept kept) {
+    g.advance(q0);
+    int64_t read = 0;
+    for (uint64_t q = q0; q < q1; ++q) {
+        const uint64_t out = g.next();
+        const uint64_t lo = (out & 0xFFFFFFFFull) * n;
+        ++read;
+        if (static_cast<uint32_t>(lo) >= threshold && !kept(lo >> 32))
+            return read;
+        const uint64_t hi = (out >> 32) * n;
+        ++read;
+        if (static_cast<uint32_t>(hi) >= threshold && !kept(hi >> 32))
+            return read;
+    }
+    return read;
+}
+
+}  // namespace
+
+extern "C" {
+
+// ws[t, r] += how often the t-th `integers(0, n, n)` of the PCG64 stream
+// (state, inc: the halves of numpy's `bit_generator.state`, no 32-bit
+// value buffered) draws row r, for trees t < n_trees; ws is int32
+// [n_trees, stride], zeroed by the caller, 1 <= n <= stride, n < 2^32.
+// The stream is cut into stretches of 64-bit outputs and a stretch into
+// stripes, a thread each. Pass 1: a stripe jumps to its outputs and
+// counts the values it keeps; a prefix sum then gives every stripe the
+// ordinal of its first kept value, hence its tree. Pass 2: the stripe
+// generates again and adds 1 to ws[ordinal / n, draw], atomically, as
+// two stripes may draw one row; a batch of draws is prefetched before it
+// is added, since every add is a cache miss of its own. The first stretch
+// is as long as n_trees * n draws need on average, so about every other
+// call takes a second, short one for what is still missing (the tests run
+// that path). Returns the 32-bit values thrown away before the last draw,
+// which is what numpy's own walk throws away; *max_weight takes the
+// largest count written and *threads_used the stripes of the first
+// stretch.
+int64_t pcg64_bootstrap_counts(uint64_t state_hi, uint64_t state_lo,
+                               uint64_t inc_hi, uint64_t inc_lo, int64_t n,
+                               int32_t n_trees, int64_t stride, int32_t* ws,
+                               int32_t n_threads, int32_t* max_weight,
+                               int32_t* threads_used) {
+    const Pcg64 gen{(static_cast<u128>(state_hi) << 64) | state_lo,
+                    (static_cast<u128>(inc_hi) << 64) | inc_lo};
+    const uint64_t un = static_cast<uint64_t>(n);
+    const uint32_t threshold =
+        static_cast<uint32_t>(((1ull << 32) - un) % un);
+    const int64_t wanted = static_cast<int64_t>(n_trees) * n;
+    constexpr int kBatch = 64;        // draws prefetched before they are added
+    uint64_t q_at = 0;                // 64-bit outputs of earlier stretches
+    int64_t kept_at = 0;              // draws they gave
+    int64_t read_to_last = 0;
+    int32_t largest = 0;
+    *threads_used = 0;
+    while (kept_at < wanted) {
+        const u128 need = static_cast<u128>(wanted - kept_at);
+        const uint64_t values = static_cast<uint64_t>(
+            need + need * threshold / ((1ull << 32) - threshold)
+            + (q_at ? 64 : 0));
+        const uint64_t q_n = (values + 1) / 2;
+        // a stripe of under 2^16 outputs is not worth its thread, unless
+        // the caller asked for that many
+        int64_t stripes = n_threads > 0 ? n_threads : std::min<int64_t>(
+            std::thread::hardware_concurrency(), q_n >> 16);
+        stripes = std::max<int64_t>(1, std::min<int64_t>(stripes, q_n));
+        if (!*threads_used) *threads_used = static_cast<int32_t>(stripes);
+        auto first = [&](int64_t i) {
+            return q_at + static_cast<uint64_t>(
+                static_cast<u128>(q_n) * i / stripes);
+        };
+        auto in_turn_or_threads = [&](auto fn, std::vector<char>& done) {
+            if (stripes == 1
+                || !run_threads(static_cast<int32_t>(stripes), fn))
+                for (int64_t i = 0; i < stripes; ++i)
+                    if (!done[i]) fn(static_cast<int32_t>(i));
+        };
+
+        std::vector<int64_t> base(stripes + 1, kept_at);
+        {
+            std::vector<int64_t> kept(stripes, 0);
+            std::vector<char> done(stripes, 0);
+            in_turn_or_threads([&](int32_t i) {
+                int64_t k = 0;
+                walk_draws(gen, first(i), first(i + 1), un, threshold,
+                           [&](uint64_t) { ++k; return true; });
+                kept[i] = k;
+                done[i] = 1;
+            }, done);
+            for (int64_t i = 0; i < stripes; ++i)
+                base[i + 1] = base[i] + kept[i];
+        }
+
+        std::vector<int32_t> most(stripes, 0);
+        std::vector<int64_t> read(stripes, 0);
+        std::vector<char> done(stripes, 0);
+        in_turn_or_threads([&](int32_t i) {
+            int64_t todo = std::min(base[i + 1], wanted) - base[i];
+            if (todo > 0) {
+                int32_t* row = ws + base[i] / n * stride;
+                int64_t left_in_tree = n - base[i] % n;
+                int32_t* at[kBatch];
+                int filled = 0;
+                int32_t top = 0;
+                auto add = [&] {
+                    for (int k = 0; k < filled; ++k)
+                        top = std::max(top, 1 + __atomic_fetch_add(
+                            at[k], 1, __ATOMIC_RELAXED));
+                    filled = 0;
+                };
+                read[i] = walk_draws(
+                    gen, first(i), first(i + 1), un, threshold,
+                    [&](uint64_t draw) {
+                        at[filled] = row + draw;
+                        __builtin_prefetch(at[filled], 1);
+                        if (++filled == kBatch) add();
+                        if (--left_in_tree == 0) {
+                            row += stride;
+                            left_in_tree = n;
+                        }
+                        return --todo > 0;
+                    });
+                add();
+                most[i] = top;
+            }
+            done[i] = 1;
+        }, done);
+
+        for (int64_t i = 0; i < stripes; ++i) {
+            largest = std::max(largest, most[i]);
+            if (base[i] < wanted && base[i + 1] >= wanted)
+                read_to_last = static_cast<int64_t>(2 * first(i)) + read[i];
+        }
+        q_at += q_n;
+        kept_at = base[stripes];
+    }
+    *max_weight = largest;
+    return read_to_last - wanted;
 }
 
 }  // extern "C"

@@ -128,6 +128,11 @@ def _build() -> Optional[ctypes.CDLL]:
         c_char_p, i64, ctypes.c_char, i32, c_char_p, i64, c_char_p, i64, i32,
         p_i32, np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS"),
         i64, i64, i64, i64, i32]
+    u64 = ctypes.c_uint64
+    lib.pcg64_bootstrap_counts.restype = i64
+    lib.pcg64_bootstrap_counts.argtypes = [
+        u64, u64, u64, u64, i64, i32, i64, p_i32, i32,
+        ctypes.POINTER(i32), ctypes.POINTER(i32)]
     return lib
 
 
@@ -438,6 +443,48 @@ def basket_pack_native(data, delim: str, skip: int,
     if got != scan.rows:
         raise RuntimeError(f"fia_pack row mismatch: {got} != {scan.rows}")
     return cols
+
+
+class BootstrapCounts(NamedTuple):
+    """What `bootstrap_counts_native` says of the walk it made."""
+    rejected: int           # 32-bit values thrown away before the last draw
+    max_weight: int         # the largest count written
+    threads: int            # stripes the library cut
+
+
+#: `Generator.integers` takes 32-bit values while its largest draw is
+#: under this; from here on it takes 64-bit ones, another rule
+UINT32_DRAWS = (1 << 32) - 1
+
+
+def bootstrap_counts_native(rng: np.random.Generator, n: int,
+                            ws: np.ndarray, threads: int = 0
+                            ) -> Optional[BootstrapCounts]:
+    """`ws[t, :n] += np.bincount(rng.integers(0, n, n), minlength=n)` for
+    every row t of the zeroed int32 `ws`, in row order, as one native call
+    that walks the generator's stream by position on every core
+    (`pcg64_bootstrap_counts`). None, and nothing written, where that walk
+    is not numpy's: a generator that is not PCG64 or holds a buffered
+    32-bit value, or an `n` whose draws numpy does not take from 32-bit
+    values. `rng` itself is not advanced."""
+    lib = _get_lib()
+    if lib is None:
+        raise RuntimeError("native CSV ingest unavailable (no g++?)")
+    state = rng.bit_generator.state
+    if (state["bit_generator"] != "PCG64" or state["has_uint32"]
+            or not 0 <= n - 1 < UINT32_DRAWS):
+        return None
+    if (ws.dtype != np.int32 or ws.ndim != 2 or ws.shape[1] < n
+            or not ws.flags.c_contiguous):
+        raise ValueError("ws wants a C-contiguous int32 [trees, >= n] array")
+    mask = (1 << 64) - 1
+    lcg = state["state"]
+    most, used = ctypes.c_int32(0), ctypes.c_int32(0)
+    rejected = int(lib.pcg64_bootstrap_counts(
+        lcg["state"] >> 64, lcg["state"] & mask, lcg["inc"] >> 64,
+        lcg["inc"] & mask, n, ws.shape[0], ws.shape[1], ws,
+        np.int32(threads), ctypes.byref(most), ctypes.byref(used)))
+    return BootstrapCounts(rejected, most.value, used.value)
 
 
 def native_seq_ready(delim: str) -> bool:

@@ -8,10 +8,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from avenir_tpu import obs
 from avenir_tpu.core.dataset import Dataset
 from avenir_tpu.core.schema import FeatureSchema
 from avenir_tpu.data import generate_churn, churn_schema
 from avenir_tpu.models import tree as tree_mod
+from avenir_tpu.native import ingest
 from avenir_tpu.runner import run_job
 from avenir_tpu.utils.devices import device_report
 from chipbench import forest_reference as ref
@@ -711,3 +713,162 @@ def test_under_a_mesh_the_segment_program_runs_on_each_shard(mesh8):
         plan=plan).compile().as_text()
     assert not any(op in hlo for op in (
         "all-reduce", "all-gather", "all-to-all", "collective-permute"))
+
+
+# ---------------------------------------------------------------------------
+# the bootstrap draws: the native walk of numpy's stream against numpy
+# ---------------------------------------------------------------------------
+needs_native = pytest.mark.skipif(not ingest.native_available(),
+                                  reason="native library unavailable")
+#: 2^32 mod n is 296, 1,022,831 and 735,396 for the last three: of the
+#: 6.2M and 21.4M values five trees take, hundreds are thrown away there
+SAMPLE_ROWS = (1, 127, 129, 1_000, 1_245_185, 4_285_715)
+SAMPLE_TREES = 5
+
+
+def numpy_rule(seed, n, trees):
+    """[trees, n]: the sampling rule as `RandomForestBuilder` writes it."""
+    rng = np.random.default_rng(seed)
+    return np.stack([np.bincount(rng.integers(0, n, n), minlength=n)
+                     for _ in range(trees)])
+
+
+def sample_of(seed, n, trees, sampling="withReplace"):
+    """(`_sample`'s weights as [trees, n], its largest, the span's note)."""
+    forest = RandomForestBuilder(HANGUP_SCHEMA, num_trees=trees, seed=seed,
+                                 sampling=sampling)
+    ws, heaviest, how = forest._sample(n)
+    assert ws.shape == (trees, -(-n // tree_mod.LANES), tree_mod.LANES)
+    flat = ws.reshape(trees, -1)
+    assert not flat[:, n:].any()
+    return flat[:, :n], heaviest, how
+
+
+def raw_walk(seed, n, draws):
+    """(the first `draws` draws, the 32-bit values thrown away before the
+    last of them, the values kept among the first stretch the library
+    takes): numpy's raw 64-bit outputs, the low half and then the high
+    half of each, every one mapped on its own by Lemire's rule."""
+    threshold = (2**32 - n) % n
+    first_stretch = draws + draws * threshold // (2**32 - threshold)
+    raw = np.random.default_rng(seed).bit_generator.random_raw(
+        first_stretch // 2 + 4_096)
+    m = np.stack([raw & 0xFFFFFFFF, raw >> 32], axis=1).ravel() * np.uint64(n)
+    kept = (m & np.uint64(0xFFFFFFFF)) >= threshold
+    last = np.flatnonzero(kept)[draws - 1]
+    return ((m[kept][:draws] >> 32).astype(np.int64), int(last) + 1 - draws,
+            int(kept[:(first_stretch + 1) // 2 * 2].sum()))
+
+
+@needs_native
+@pytest.mark.parametrize("seed", [0, 5])
+@pytest.mark.parametrize("n", SAMPLE_ROWS)
+def test_the_native_draws_are_numpys_element_for_element(n, seed):
+    """One to five trees, the library's own thread count (`_sample`'s),
+    one thread, two, and four a core (rows shared by stripes that run at
+    once are added atomically): every count equals `np.bincount(rng.integers(0, n,
+    n))` in tree order from one `default_rng(seed)`. Five trees' rule
+    holds the rule of fewer as its first rows, the stream being one."""
+    want = numpy_rule(seed, n, SAMPLE_TREES)
+    for trees in range(1, SAMPLE_TREES + 1):
+        got, heaviest, how = sample_of(seed, n, trees)
+        np.testing.assert_array_equal(got, want[:trees])
+        assert heaviest == want[:trees].max()
+        assert how["native"] is True and how["threads"] >= 1
+        for threads in (1, 2, 4 * os.cpu_count()):
+            ws = np.zeros((trees, n + 3), np.int32)
+            walk = ingest.bootstrap_counts_native(
+                np.random.default_rng(seed), n, ws, threads)
+            np.testing.assert_array_equal(ws[:, :n], want[:trees])
+            assert not ws[:, n:].any()
+            assert walk.threads == min(threads, max(1, (trees * n + 1) // 2))
+            assert (walk.max_weight, walk.rejected) == (
+                heaviest, how["rejected"])
+
+
+@needs_native
+def test_the_walk_throws_away_what_a_plain_walk_of_the_raw_stream_does():
+    """`rejected` against numpy's raw outputs mapped one by one, which
+    also gives `integers`' own values back; and over the grid the first
+    stretch is short for some calls and long enough for others, so both
+    ways through the library's loop are run."""
+    short = []
+    for n in SAMPLE_ROWS[2:]:
+        for seed in (0, 5):
+            for trees in (1, 3):
+                draws, thrown, in_first = raw_walk(seed, n, trees * n)
+                np.testing.assert_array_equal(
+                    draws, np.random.default_rng(seed).integers(0, n, trees * n))
+                assert sample_of(seed, n, trees)[2]["rejected"] == thrown
+                short.append(in_first < trees * n)
+    assert any(short) and not all(short)
+    assert sample_of(0, SAMPLE_ROWS[-1], 3)[2]["rejected"] > 300
+
+
+@needs_native
+@pytest.mark.parametrize("n", [1_000, 1_245_185])
+@pytest.mark.parametrize("why", ["no library", "draws of 64 bits"])
+def test_the_numpy_loop_is_taken_where_the_walk_is_not_numpys(
+        monkeypatch, why, n):
+    """The native library reported absent, and an `n` past the 32-bit
+    rule (simulated where the binding decides): `_sample` runs the written
+    rule and gives the same weights as the walk does."""
+    native, heaviest, how = sample_of(3, n, 3)
+    assert how["native"] is True
+    if why == "no library":
+        monkeypatch.setattr(ingest, "native_available", lambda: False)
+    else:
+        monkeypatch.setattr(ingest, "UINT32_DRAWS", n - 1)
+    got, top, how = sample_of(3, n, 3)
+    assert how == {"native": False, "threads": 1}
+    np.testing.assert_array_equal(got, native)
+    np.testing.assert_array_equal(got, numpy_rule(3, n, 3))
+    assert top == heaviest
+
+
+@needs_native
+def test_a_generator_the_walk_cannot_follow_is_left_to_numpy():
+    """Another bit generator, and a PCG64 that holds the unread half of
+    an output: nothing is written and the caller falls back."""
+    ws = np.zeros((2, 128), np.int32)
+    philox = np.random.Generator(np.random.Philox(0))
+    assert ingest.bootstrap_counts_native(philox, 100, ws) is None
+    half = np.random.default_rng(0)
+    half.integers(0, 100, 1)
+    assert half.bit_generator.state["has_uint32"] == 1
+    assert ingest.bootstrap_counts_native(half, 100, ws) is None
+    assert ingest.bootstrap_counts_native(np.random.default_rng(0), 0, ws) is None
+    assert not ws.any()
+    rng = np.random.default_rng(0)
+    before = rng.bit_generator.state
+    assert ingest.bootstrap_counts_native(rng, 100, ws).max_weight == ws.max()
+    assert rng.bit_generator.state == before
+    with pytest.raises(ValueError, match="int32"):
+        ingest.bootstrap_counts_native(rng, 100, ws.astype(np.int64))
+
+
+@pytest.mark.parametrize("sampling", ["withoutReplace", "none"])
+def test_the_other_strategies_draw_as_they_did(sampling):
+    got, heaviest, how = sample_of(2, 1_000, 3, sampling)
+    rng = np.random.default_rng(2)
+    want = np.stack([rng.random(1_000) < 0.7 if sampling == "withoutReplace"
+                     else np.ones(1_000, bool) for _ in range(3)])
+    np.testing.assert_array_equal(got, want)
+    assert (heaviest, how) == (1, {"native": False, "threads": 1})
+
+
+@needs_native
+def test_forest_sample_says_how_the_draws_were_made(tmp_path):
+    """The span of a forest job carries `native`, `threads` and
+    `rejected` beside `sampling`."""
+    rows = 16_384
+    train, schema_path, _codes, _y = call_hangup_rows(rows, 23, tmp_path)
+    with obs.capture() as ring:
+        run_job("randomForest", forest_properties(schema_path), [train],
+                str(tmp_path / "out"))
+    (span,) = [sp for sp in ring.spans() if sp.name == "forest.sample"]
+    assert span.attrs == {
+        "sampling": "withReplace", "native": True,
+        "threads": span.attrs["threads"],
+        "rejected": raw_walk(0, rows, 10 * rows)[1]}
+    assert 1 <= span.attrs["threads"] <= os.cpu_count()
