@@ -27,6 +27,9 @@ routes that write the same bytes:
   every round. Pair supports are one Gram matrix, G = sum_t x_t x_t^T,
   blocked matmuls on the MXU that replace the Hadoop shuffle; a longer
   candidate's support is the popcount of the AND of its items' columns.
+  A job that sees several chips shards the basket axis over a mesh of
+  them: each chip holds a run of the slabs and counts its own, and one
+  all-reduce a round adds the counts (the bytes written are the same).
 - re-scan (FrequentItemsApriori.mine_stream: what does not fit the device
   or the host, a stated block size, exact transaction ids): one streamed
   scan per itemset length over bit-packed row blocks, counted by a
@@ -704,30 +707,37 @@ class FrequentItemsApriori:
 
     @staticmethod
     def device_bytes_limit() -> int:
-        stats = jax.devices()[0].memory_stats() or {}
-        return int(stats.get("bytes_limit",
-                             FrequentItemsApriori.UNSTATED_LIMIT_BYTES))
+        """The least `bytes_limit` over the devices a job's mesh is made
+        of (`utils.devices.job_mesh`): every one holds an equal share."""
+        return min(int((d.memory_stats() or {}).get(
+            "bytes_limit", FrequentItemsApriori.UNSTATED_LIMIT_BYTES))
+            for d in jax.local_devices())
 
     @staticmethod
     def host_bytes() -> int:
         return os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
 
-    def resident_words(self, n: int, frequent: int) -> Optional[Tuple[int, int]]:
-        """(slab words, slabs) of the resident bit columns of `n` baskets
-        over `frequent` items, or nothing where they do not fit: the
-        packed bytes (4 a word and item row, `column_rows(frequent)` rows)
-        against `RESIDENT_SHARE` of the device's `bytes_limit`."""
+    def resident_words(self, n: int, frequent: int, devices: int = 1
+                       ) -> Optional[Tuple[int, int]]:
+        """(slab words, slabs a device) of the resident bit columns of `n`
+        baskets over `frequent` items, or nothing where they do not fit.
+        The slabs are dealt to the `devices` in contiguous runs of equal
+        length, the last run padded with empty slabs (a basket of no bits
+        adds to no count); the test is one run's packed bytes (4 a word
+        and item row, `column_rows(frequent)` rows) against
+        `RESIDENT_SHARE` of one device's `bytes_limit`."""
         from avenir_tpu.ops.bitset import column_rows, slab_words_for
 
         slab = slab_words_for(n)
         slabs = -(-max(n, 1) // (slab * 32))
-        fits = (4 * slab * slabs * column_rows(frequent)
+        run = -(-slabs // devices)
+        fits = (4 * slab * run * column_rows(frequent)
                 <= self.RESIDENT_SHARE * self.device_bytes_limit())
-        return (slab, slabs) if fits else None
+        return (slab, run) if fits else None
 
     def mine_whole(self, paths: Sequence[str], delim: str = ",",
-                   skip_field_count: int = 1, marker: Optional[str] = None
-                   ) -> Optional[Tuple[List[ItemSetList], int]]:
+                   skip_field_count: int = 1, marker: Optional[str] = None,
+                   mesh=None) -> Optional[Tuple[List[ItemSetList], int]]:
         """The resident route over a file: read whole, tokenised by two
         native passes that are each one call (`native.ingest.
         basket_scan_native`: the vocabulary in order of first appearance
@@ -735,14 +745,21 @@ class FrequentItemsApriori:
         columns over the frequent items), the columns put on the chip
         once, every later round one program over them
         (`_resident_rounds`). No block loop, no sort, no cache. Returns
-        the itemset lists and the number of baskets.
+        the itemset lists and the number of baskets. With a `mesh`
+        (`utils.devices.job_mesh`: the job sees several chips) the
+        columns' basket axis is sharded over it, a run of slabs a chip,
+        and the rounds' counts are added across the chips; the read, the
+        two passes and the bytes written are the same.
 
         Returns nothing where the route is not to be taken, and the
         caller mines the stream instead (`mine_stream` writes the same
-        bytes): exact transaction ids, no native parser for this
-        delimiter, a file over `HOST_SHARE` of the host's memory, or
-        packed baskets that do not fit the device (`resident_words`; known
-        only after pass 1, whose work is then lost)."""
+        bytes). Of the five refusals (a stated block size, which the job
+        sees before it calls; exact transaction ids; no native parser for
+        this delimiter; a file over `HOST_SHARE` of the host's memory;
+        packed baskets that do not fit the device) further chips lift the
+        last alone: `resident_words` holds a chip to its share of the
+        slabs (known only after pass 1, whose work is lost where even
+        that does not fit). The file is still one buffer on one host."""
         from avenir_tpu.native.ingest import (basket_pack_native,
                                               basket_scan_native,
                                               native_seq_ready,
@@ -753,7 +770,8 @@ class FrequentItemsApriori:
                 or sum(os.path.getsize(p) for p in paths)
                 > self.HOST_SHARE * self.host_bytes()):
             return None
-        with _obs.span("fia.mine", resident=False) as note:
+        devices = mesh.size if mesh is not None else 1
+        with _obs.span("fia.mine", resident=False, devices=devices) as note:
             with _obs.span("fia.read", files=len(paths)) as read:
                 data = read_files_native(paths)
                 read["nbytes"] = int(data.shape[0])
@@ -767,7 +785,7 @@ class FrequentItemsApriori:
             vm = int(freq1.shape[0])
             note.update(rows=n, vocab=len(scan.vocab), frequent=vm,
                         words=-(-vm // 32))
-            fits = self.resident_words(n, vm)
+            fits = self.resident_words(n, vm, devices)
             if fits is None:
                 return None
             rounds: List[Tuple[int, List[Tuple[int, ...]], List[int]]] = [
@@ -783,9 +801,9 @@ class FrequentItemsApriori:
                         data, delim, skip_field_count, marker, scan,
                         item_row, column_rows(vm), fits[0])
                 del data
-                cols_d = self._put_resident(slabs)
+                cols_d = self._put_resident(slabs, mesh)
                 del slabs
-                rounds += self._resident_rounds(cols_d, vm, min_count)
+                rounds += self._resident_rounds(cols_d, vm, min_count, mesh)
                 del cols_d
             note.update(rounds=len(rounds), resident=True)
             kept = [scan.vocab[i] for i in freq1.tolist()]
@@ -793,35 +811,77 @@ class FrequentItemsApriori:
                     for k, ids_k, counts_k in rounds], n
 
     @staticmethod
-    def _put_resident(slabs: np.ndarray) -> jnp.ndarray:
+    def _put_resident(slabs: np.ndarray, mesh=None) -> jnp.ndarray:
         """The packed slabs [slabs, rows, words] put on the chip once, a
         slab at a time into one array that is built in place; it stays
-        there for every later round."""
-        from avenir_tpu.ops.bitset import place_columns
+        there for every later round. With a `mesh` every chip gets a
+        contiguous run of the slabs (`resident_words`) in an array of its
+        own, the chips' puts issued in turn and none waited for, and the
+        arrays are joined into one whose basket axis is sharded over the
+        mesh: nothing is staged whole on one chip."""
+        from jax.sharding import NamedSharding
+
+        from avenir_tpu.ops.bitset import BASKETS_SHARDED, place_columns
+        from avenir_tpu.utils.devices import note_devices_used
 
         n_slabs, v_rows, words = slabs.shape
-        with _obs.span("fia.put", nbytes=slabs.nbytes, slabs=n_slabs):
-            cols_d = jnp.zeros((v_rows, n_slabs * words), jnp.uint32)
-            for at in range(n_slabs):
-                # the put is this route's fold of the stream: a slab of it
-                # goes into the resident state (the coverage auditor's name)
-                with _obs.span("stream.fold", sink="apriori_resident"):
-                    cols_d = place_columns(cols_d, jnp.asarray(slabs[at]),
-                                           jnp.int32(at * words))
-            return jax.block_until_ready(cols_d)
+        chips = [None] if mesh is None else list(mesh.devices.flat)
+        run = -(-n_slabs // len(chips))
+        attrs = {"nbytes": slabs.nbytes, "slabs": n_slabs}
+        if mesh is not None:
+            attrs.update(devices=len(chips),
+                         nbytes_per_device=4 * v_rows * run * words)
+        with _obs.span("fia.put", **attrs):
+            parts = []
+            for chip in chips:
+                # made on its own chip: `jnp.zeros(device=chip)` makes the
+                # array on the first chip and copies it over
+                with jax.default_device(chip):
+                    parts.append(jnp.zeros((v_rows, run * words), jnp.uint32))
+            for j in range(run):
+                for d, chip in enumerate(chips):
+                    at = d * run + j
+                    if at >= n_slabs:
+                        continue          # padding: the slab stays empty
+                    # the put is this route's fold of the stream: a slab
+                    # of it goes into the resident state (the coverage
+                    # auditor's name)
+                    with _obs.span("stream.fold", sink="apriori_resident"):
+                        # one chip: `jnp.int32` is a device operation that
+                        # paces this loop; without it 24 more slabs are in
+                        # flight at 50M baskets (340 MB, PR 36)
+                        if chip is None:
+                            slab, word_at = (jnp.asarray(slabs[at]),
+                                             jnp.int32(j * words))
+                        else:
+                            slab, word_at = (jax.device_put(slabs[at], chip),
+                                             np.int32(j * words))
+                        parts[d] = place_columns(parts[d], slab, word_at)
+            if mesh is None:
+                return jax.block_until_ready(parts[0])
+            note_devices_used(len(chips))
+            return jax.block_until_ready(
+                jax.make_array_from_single_device_arrays(
+                    (v_rows, len(chips) * run * words),
+                    NamedSharding(mesh, BASKETS_SHARDED), parts))
 
     def _resident_rounds(self, cols_d: jnp.ndarray, frequent: int,
-                         min_count: float):
+                         min_count: float, mesh=None):
         """Rounds 2 and up over the resident bit columns: [(k, sets as
         tuples of masked item ids, counts)]. Round 2 is one Gram matrix
         (`_pair_gram`): support({a, b}) = G[a, b], and no candidate list
         exists. A later round's candidates are the host's join and prune;
         their supports are one program (`_set_supports`), the candidate
         axis padded to a bucket size so that a recurring round compiles
-        nothing."""
+        nothing. Over columns sharded on a `mesh` the two programs run on
+        every chip's own words and one all-reduce a round adds the int32
+        counts (`_pair_gram_mesh`, `_set_supports_mesh`); the host fetches
+        one replica."""
         from avenir_tpu.ops.bitset import (GRAM_BLOCK_WORDS, _pair_gram,
-                                           _set_supports)
+                                           _pair_gram_mesh, _set_supports,
+                                           _set_supports_mesh)
 
+        devices = mesh.size if mesh is not None else 1
         rounds = []
         freq_ids: List[Tuple[int, ...]] = [(m,) for m in range(frequent)]
         for k in range(2, self.max_length + 1):
@@ -838,14 +898,19 @@ class FrequentItemsApriori:
                 note["candidates"] = n_cands
             if not n_cands:
                 break
-            with _obs.span("fia.round.dispatch", k=k, candidates=n_cands):
+            with _obs.span("fia.round.dispatch", k=k, candidates=n_cands,
+                           devices=devices):
                 if k == 2:
-                    block = min(GRAM_BLOCK_WORDS, cols_d.shape[1])
-                    while cols_d.shape[1] % block:
+                    words = cols_d.shape[1] // devices       # a chip's own
+                    block = min(GRAM_BLOCK_WORDS, words)
+                    while words % block:
                         block //= 2
-                    out_d = _pair_gram(cols_d, block)
-                else:
+                    out_d = (_pair_gram(cols_d, block) if mesh is None else
+                             _pair_gram_mesh(cols_d, mesh, block))
+                elif mesh is None:
                     out_d = _set_supports(cols_d, jnp.asarray(cand_rows))
+                else:
+                    out_d = _set_supports_mesh(cols_d, cand_rows, mesh)
             with _obs.span("fia.round.fetch", k=k, candidates=n_cands) as note:
                 out = np.asarray(out_d)
                 if k == 2:

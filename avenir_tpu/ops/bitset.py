@@ -25,7 +25,10 @@ The second half of the module is the miners' resident route: the same
 baskets item-major, as bit columns that stay on the chip across the
 rounds (`place_columns`), the pairs' Gram matrix on the MXU
 (`_pair_gram`) and the longer sets' supports as popcounts of ANDed
-columns (`_set_supports`).
+columns (`_set_supports`). Where a job runs on several chips the basket
+axis is sharded over a mesh and each chip counts its own words by the
+same two programs; the int32 counts are added across the chips
+(`_pair_gram_mesh`, `_set_supports_mesh`).
 """
 
 from __future__ import annotations
@@ -36,6 +39,9 @@ from typing import Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
+
+from avenir_tpu.parallel.mesh import DATA_AXIS, shard_map
 
 WORD_BITS = 32
 
@@ -228,3 +234,35 @@ def _set_supports(cols: jnp.ndarray, cands: jnp.ndarray) -> jnp.ndarray:
         return jnp.sum(jax.lax.population_count(both).astype(jnp.int32))
 
     return jax.lax.map(one, cands)
+
+
+# The same two programs over columns whose basket axis is sharded over a
+# mesh's data axis: under `shard_map` each chip slices and counts its own
+# words (partitioning by annotation alone would all-gather the columns
+# for the Gram's dynamic slices), and `psum` adds the int32 counts, so
+# float32 never sums past one block of one chip.
+BASKETS_SHARDED = P(None, DATA_AXIS)     # the resident columns on a mesh
+
+
+@partial(jax.jit, static_argnames=("mesh", "block_words"))
+def _pair_gram_mesh(cols: jnp.ndarray, mesh: Mesh, block_words: int
+                    ) -> jnp.ndarray:
+    """`_pair_gram` of every chip's words, added across the chips: int32
+    [V_rows, V_rows], replicated. `block_words` divides a chip's words."""
+    def shard(words):
+        return jax.lax.psum(_pair_gram(words, block_words), DATA_AXIS)
+
+    return shard_map(shard, mesh, in_specs=BASKETS_SHARDED,
+                     out_specs=P())(cols)
+
+
+@partial(jax.jit, static_argnames=("mesh",))
+def _set_supports_mesh(cols: jnp.ndarray, cands: jnp.ndarray, mesh: Mesh
+                       ) -> jnp.ndarray:
+    """`_set_supports` of every chip's words for the same candidates,
+    added across the chips: int32 [C], replicated."""
+    def shard(words, rows):
+        return jax.lax.psum(_set_supports(words, rows), DATA_AXIS)
+
+    return shard_map(shard, mesh, in_specs=(BASKETS_SHARDED, P()),
+                     out_specs=P())(cols, cands)

@@ -117,7 +117,13 @@ def run_job(name: str, conf, inputs: Sequence[str], output: str = "") -> JobResu
     counter pair: `Mem:PredictedPeakBytes` (the analysis/mem analytic
     footprint model at the job's block size and corpus) next to the
     measured `Mem:PeakRSS`, so a long-running process records the
-    model's error over time."""
+    model's error over time.
+
+    Where the process sees several chips, a job that has a route over
+    them builds one mesh over all of them (`utils.devices.job_mesh`) and
+    takes it; none is chosen by a key. Today that is the itemset miner's
+    resident route (`frequentItemsApriori`); every other family keeps to
+    the first device until a PR gives it a route."""
     canonical, _prefix, cfg = _job_cfg(name, conf)
     fn = _REGISTRY[canonical][2]
     if output:
@@ -2801,6 +2807,7 @@ def apriori_job(cfg: JobConfig, inputs: List[str], output: str) -> JobResult:
     reference's per-round outputs (FrequentItemsApriori.java:123-126)."""
     from avenir_tpu.models.association import (FrequentItemsApriori,
                                                StreamingTransactionSource)
+    from avenir_tpu.utils.devices import job_mesh
 
     miner = FrequentItemsApriori(
         support_threshold=cfg.assert_float("support.threshold"),
@@ -2818,7 +2825,8 @@ def apriori_job(cfg: JobConfig, inputs: List[str], output: str) -> JobResult:
     whole = None
     if cfg.get("stream.block.size.mb") is None:
         whole = miner.mine_whole(inputs, delim=cfg.field_delim_regex,
-                                 skip_field_count=skip, marker=marker)
+                                 skip_field_count=skip, marker=marker,
+                                 mesh=job_mesh())
     cache_counters = {}
     if whole is not None:
         levels, n_rows = whole

@@ -27,6 +27,8 @@ _lock = threading.Lock()
 #: seconds inside them, and how many the persistent cache answered
 _compiles = {"count": 0, "seconds": 0.0, "cache_hits": 0}
 _counting = False
+#: the most devices any program of this process ran on
+_devices_used = 1
 
 
 class DeviceUnavailable(RuntimeError):
@@ -88,10 +90,35 @@ def _count_compiles() -> None:
     monitoring.register_event_listener(_on_compile_event)
 
 
+def job_mesh():
+    """The mesh of a job that has a route over several chips: one data
+    axis over every device this process sees, or nothing where it sees
+    one, and the job is then what it is on one chip. No property, flag or
+    variable chooses: a user who wants fewer chips hides them from the
+    runtime. Built once a job, on the job's thread."""
+    import jax
+
+    devs = jax.local_devices()
+    if len(devs) < 2:
+        return None
+    from avenir_tpu.parallel.mesh import data_mesh
+
+    return data_mesh(devs)
+
+
+def note_devices_used(count: int) -> None:
+    """A program of this process ran on `count` devices."""
+    global _devices_used
+    with _lock:
+        _devices_used = max(_devices_used, count)
+
+
 def device_report() -> Dict:
     """What this process runs on, as JAX reports it, with its compile
     counts and its CSV parser — the row `/healthz` and chip_smoke.py
-    print, so that no caller has to infer the device from the outside."""
+    print, so that no caller has to infer the device from the outside.
+    `devices_used` is the most devices any program of the process ran
+    on: 1 until a job took a route over a mesh (`job_mesh`)."""
     import jax
 
     from avenir_tpu.native.ingest import native_available
@@ -99,9 +126,11 @@ def device_report() -> Dict:
     devs = jax.devices()
     with _lock:
         compiles = dict(_compiles)
+        used = _devices_used
     return {"platform": devs[0].platform,
             "device_kind": devs[0].device_kind,
             "device_count": len(devs),
+            "devices_used": used,
             "xla_compiles": compiles["count"],
             "compile_s": round(compiles["seconds"], 3),
             "compile_cache_hits": compiles["cache_hits"],
