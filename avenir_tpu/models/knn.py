@@ -30,7 +30,7 @@ per-query simple linear regression over the neighbors (doRegression(),
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 import jax
@@ -252,17 +252,25 @@ class NeighborIndex:
     def search(self, queries: Tuple) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(dist [nq,k], train index [nq,k]) of prepared `queries`, as
         dispatched; unfillable slots are (+inf, -1)."""
+        return self.search_counted(queries)[:2]
+
+    def search_counted(self, queries: Tuple) -> Tuple[
+            jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray]]:
+        """`search`, and where the exact kernel serves, per query block
+        its count of the train slices it extracted (slices of
+        ops.pallas_knn.slice_rows(self.block) rows), as dispatched with
+        the rest; None on the other routes."""
         q_num, q_cat, nq = queries
         if self.use_pallas:
             from avenir_tpu.ops.pallas_knn import knn_topk_lanes, knn_topk_pallas
 
             topk = knn_topk_lanes if self.packed else knn_topk_pallas
-            dist, idx = topk(
+            dist, idx, *extracted = topk(
                 jnp.asarray(q_num), self.t_num, k=self.k, block_q=_BLOCK_Q,
                 block_t=self.block, metric=self.metric,
                 n_valid=self.n_valid, n_attrs=self.n_attrs)
-            return dist[:nq], idx[:nq]
-        return blocked_topk_neighbors(
+            return dist[:nq], idx[:nq], extracted[0] if extracted else None
+        dist, idx = blocked_topk_neighbors(
             jnp.asarray(q_num) if self.t_num is not None else None,
             self.t_num,
             jnp.asarray(q_cat) if self.t_cat is not None else None,
@@ -275,6 +283,18 @@ class NeighborIndex:
             n_valid=self.n_valid,
             approx=self.approx,
         )
+        return dist, idx, None
+
+    def slice_counts(self, extracted: jnp.ndarray) -> Dict[str, int]:
+        """What `search_counted`'s third value says, fetched: the train
+        slices the exact kernel tested (query blocks x slices of the
+        padded corpus), those it extracted, and the rows of a slice."""
+        from avenir_tpu.ops.pallas_knn import slice_rows
+
+        width = slice_rows(self.block)
+        return {"slices": extracted.shape[0] * (self.n_padded // width),
+                "extracted": int(np.asarray(extracted).sum()),
+                "slice_rows": width}
 
     def neighbors(self, test: Dataset) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(dist [nq,k], train index [nq,k]); unfillable slots are (+inf, -1)."""
@@ -397,8 +417,9 @@ class NearestNeighborClassifier:
                     queries, self.train_labels, len(self.class_values),
                     self.kernel, self.kernel_param)
             note["kernel"] = "fused" if scores is not None else self.index.kernel
+            extracted = None
             if scores is None:
-                dist, idx = self.index.search(queries)
+                dist, idx, extracted = self.index.search_counted(queries)
                 neigh_labels = self.train_labels[idx]
                 neigh_post = self.train_post[idx]
                 scores = _vote(
@@ -406,8 +427,10 @@ class NearestNeighborClassifier:
                     self.kernel, self.kernel_param, len(self.class_values),
                     self.class_cond, self.inverse_weighted,
                 )
-        with obs.span("knn.query.fetch", rows=len(test)):
+        with obs.span("knn.query.fetch", rows=len(test)) as note:
             scores = np.asarray(scores)
+            if extracted is not None:
+                note.update(self.index.slice_counts(extracted))
         # the reference's threshold branch exists only in non-class-cond mode
         # (Neighborhood.classify(), :272-312: weighted path pure-argmaxes)
         if (self.decision_threshold > 0 and len(self.class_values) == 2

@@ -4,15 +4,24 @@ The KNN hot loop (SURVEY §7 "hard parts": blocked streaming top-k is the
 main genuinely new kernel) spends its time producing an [nq, nt] distance
 surface and reducing each row to its k smallest entries. The jnp path
 (ops/distance.blocked_topk_neighbors) materializes each [nq, block] tile
-through HBM and pays for a full sort-based lax.top_k per block. This kernel
-keeps each [BQ, BT] tile entirely in VMEM and replaces the sort with k
-iterative min-extractions (k is small — 5-ish — so k VPU passes over the
-tile beat a sort), merging into a running [BQ, k] best buffer that lives in
-the revisited output block across the train-block grid axis.
+through HBM and pays for a full sort-based lax.top_k per block. The exact
+kernel walks each [BQ, BT] block in slices of slice_rows(BT) train rows
+(a rolled loop), computes a slice's distances in VMEM and first asks one
+thing of them: does any lie strictly under its query's k-th best so far?
+Only then does it run k iterative extractions of the least (distance,
+column) pair on the slice (k is small — 5-ish — so k VPU passes beat a
+sort) and merge them into the running [BQ, k] best buffer, which lives in
+the revisited output block across the train-block grid axis. A train row
+at position p of a corpus in no particular order enters a query's best k
+with probability k/p, so most slices are passed over; the answer is the
+same bits either way, the k least (distance, index) pairs in
+lexicographic order (a tie goes to the row already held, then to the
+lower column), and a third output counts the slices extracted.
 
-Memory: tile is BQ x BT f32 in VMEM (default 256 x 8192 = 8 MB, the
-measured sweet spot under the 16 MB scoped-vmem limit), distances never
-touch HBM; output is [nq, k] + [nq, k] only.
+Memory: the train block is BT x D f32 in VMEM, row-major (default 8192
+rows), a slice's distances BQ x slice_rows(BT) (256 x 2048 = 2 MB), the
+manhattan form's query columns broadcast along the lanes D x BQ x 128;
+distances never touch HBM; output is [nq, k] + [nq, k] and the count.
 
 Numeric-feature metrics only (euclidean via one MXU matmul, manhattan via a
 D-pass VPU loop); the mixed categorical path stays on the jnp route.
@@ -21,6 +30,7 @@ D-pass VPU loop); the mixed categorical path stays on the jnp route.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -75,17 +85,27 @@ def _tile_distance(q, t, metric, compute_dtype):
     return tile
 
 
+def _least(x, col):
+    """Row minimum of x [BQ, S] and the lowest column that holds it (col is
+    the column iota). Two min reductions, not jnp.argmin: the compiled
+    index reduction gives a tie to the highest lane (shown on a v5e,
+    PERF.md section 6, PR 37), so with it the order among equal distances
+    would depend on where a row happens to lie in a vector register."""
+    m = jnp.min(x, axis=1)
+    return m, jnp.min(jnp.where(x == m[:, None], col, x.shape[1]), axis=1)
+
+
 def _merge_into_best(best_d_ref, best_i_ref, cand_d, cand_i, k):
     """Fold [BQ, m] candidates into the carried [BQ, k] best buffers via k
-    min+argmin rounds on the (small) concatenated array."""
+    extraction rounds on the (small) concatenated array; among equal
+    distances the rows already held come first, then the lower column."""
     all_d = jnp.concatenate([best_d_ref[...], cand_d], axis=1)
     all_i = jnp.concatenate([best_i_ref[...], cand_i], axis=1)
     pos = jax.lax.broadcasted_iota(jnp.int32, all_d.shape, 1)
     new_d = []
     new_i = []
     for _ in range(k):
-        m = jnp.min(all_d, axis=1)
-        am = jnp.argmin(all_d, axis=1).astype(jnp.int32)
+        m, am = _least(all_d, pos)
         sel = pos == am[:, None]
         # gather the index at the argmin lane via a masked reduction
         picked_i = jnp.sum(jnp.where(sel, all_i, 0), axis=1)
@@ -96,36 +116,126 @@ def _merge_into_best(best_d_ref, best_i_ref, cand_d, cand_i, k):
     best_i_ref[...] = jnp.stack(new_i, axis=1)
 
 
-def _knn_kernel(q_ref, t_ref, best_d_ref, best_i_ref, *, k: int,
-                metric: str, block_t: int, n_valid: int, nt: int,
+#: widest slice of train rows the exact kernel tests at once (the widths
+#: tried on the chip are in PERF.md section 6, PR 37)
+_SLICE_ROWS = 2048
+#: most VMEM the query columns' lane broadcasts may take, made once a
+#: query block; above it they are made again for every slice
+_QUERY_BROADCAST_BYTES = 4 << 20
+
+
+def slice_rows(block_t: int) -> int:
+    """Train rows of one slice of the exact kernel's tile: the widest run
+    of whole 128-row groups that divides `block_t`, at most _SLICE_ROWS;
+    a block that has no such run is one slice."""
+    s = math.gcd(block_t, _SLICE_ROWS)
+    return s if s % _LANES == 0 else block_t
+
+
+def _group_rows(block_t: int) -> int:
+    """Train rows whose distances the exact kernel accumulates at once over
+    all features: one lane group of a slice (the whole of an odd slice)."""
+    width = slice_rows(block_t)
+    return _LANES if width % _LANES == 0 else width
+
+
+def _hoists_queries(d: int, block_q: int, block_t: int) -> bool:
+    return d * block_q * _group_rows(block_t) * 4 <= _QUERY_BROADCAST_BYTES
+
+
+def _manhattan_parts(q_ref, qb_ref, ts, group):
+    """The [BQ, group] distance tiles of a train slice ts [S, D], one a
+    lane group: each accumulated over all D features before the next is
+    begun, so it stays in vector registers (the sums are the whole-tile
+    form's, feature by feature in float32). The slice's rows go to the
+    lanes by an identity matmul at HIGHEST precision, which moves every
+    finite float32 exactly: three bfloat16 pieces, each times one."""
+    bq, d = q_ref.shape
+    dp = -(-d // 8) * 8
+    ident = (jax.lax.broadcasted_iota(jnp.int32, (dp, d), 0)
+             == jax.lax.broadcasted_iota(jnp.int32, (dp, d), 1))
+    t_rows = jax.lax.dot_general(
+        ident.astype(jnp.float32), ts, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)                 # [dp, S]
+    parts = []
+    for g in range(ts.shape[0] // group):
+        acc = jnp.zeros((bq, group), jnp.float32)
+        for f in range(d):
+            q_col = (qb_ref[f] if qb_ref is not None else
+                     jnp.broadcast_to(q_ref[:, f:f + 1], (bq, group)))
+            acc = acc + jnp.abs(
+                q_col - t_rows[f:f + 1, g * group:(g + 1) * group])
+        parts.append(acc)
+    return parts
+
+
+def _knn_kernel(q_ref, t_ref, best_d_ref, best_i_ref, n_ext_ref, *qb_ref,
+                k: int, metric: str, block_t: int, n_valid: int, nt: int,
                 compute_dtype=jnp.float32):
-    """Exact path: k min+argmin extraction rounds over the full tile."""
+    """Exact path. The tile is walked in slices of slice_rows(block_t)
+    train rows; a slice is extracted (k rounds of the least pair left,
+    then the merge) only where some row of it lies strictly under its
+    query's k-th best. A slice with no such row would leave the best
+    buffers as they are (a tie goes to the row already held, then to the
+    lower column: _least), so skipping it changes no bit, and the result
+    is the k least (distance, index) pairs in lexicographic order
+    whatever the slice width. n_ext_ref counts the slices this query block
+    extracted; qb_ref, where the wrapper made room for it, holds the
+    manhattan form's query columns broadcast along the lanes."""
     tb = pl.program_id(1)
+    width = slice_rows(block_t)
+    group = _group_rows(block_t)
+    bq, d = q_ref.shape
+    qb_ref = qb_ref[0] if qb_ref else None
 
     @pl.when(tb == 0)
     def _init():
         best_d_ref[...] = jnp.full_like(best_d_ref, _INF)
         best_i_ref[...] = jnp.full_like(best_i_ref, -1)
+        n_ext_ref[...] = jnp.zeros_like(n_ext_ref)
+        if qb_ref is not None:          # the query block is every tb's
+            for f in range(d):
+                qb_ref[f] = jnp.broadcast_to(q_ref[:, f:f + 1], (bq, group))
 
-    tile = _tile_distance(q_ref[...], t_ref[...], metric, compute_dtype)
-    base = tb * block_t
-    col = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
-    if n_valid < nt:                        # static: skip mask when unpadded
-        tile = jnp.where(base + col < n_valid, tile, _INF)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bq, width), 1)
 
-    # k min-extractions: tile top-k without a sort
-    cand_d = []
-    cand_i = []
-    for _ in range(k):
-        m = jnp.min(tile, axis=1)                    # [BQ]
-        am = jnp.argmin(tile, axis=1).astype(jnp.int32)
-        cand_d.append(m[:, None])
-        cand_i.append(base + am[:, None])
-        tile = jnp.where(col == am[:, None], _INF, tile)
+    def one_slice(c, carry):
+        start = pl.multiple_of(c * width, width)
+        ts = t_ref[pl.ds(start, width), :]
+        if metric == "manhattan":
+            parts = _manhattan_parts(q_ref, qb_ref, ts, group)
+        else:
+            tile = _tile_distance(q_ref[...], ts, metric, compute_dtype)
+            parts = [tile[:, g:g + group] for g in range(0, width, group)]
+        base = tb * block_t + start
+        if n_valid < nt:                    # static: skip mask when unpadded
+            lane = jax.lax.broadcasted_iota(jnp.int32, (bq, group), 1)
+            parts = [jnp.where(base + g * group + lane < n_valid, part, _INF)
+                     for g, part in enumerate(parts)]
+        # the test, one minimum a pair: +inf until k rows are held, so the
+        # first slices always pass; a masked pad row is +inf and never does
+        least = functools.reduce(jnp.minimum, parts)
 
-    _merge_into_best(best_d_ref, best_i_ref,
-                     jnp.concatenate(cand_d, axis=1),
-                     jnp.concatenate(cand_i, axis=1), k)
+        @pl.when(jnp.any(least < best_d_ref[:, k - 1:k]))
+        def _extract():
+            # k extractions of the least pair left: top-k without a sort
+            rest = jnp.concatenate(parts, axis=1)
+            cand_d = []
+            cand_i = []
+            for _ in range(k):
+                m, am = _least(rest, col)                    # [BQ] each
+                cand_d.append(m[:, None])
+                cand_i.append(base + am[:, None])
+                rest = jnp.where(col == am[:, None], _INF, rest)
+            _merge_into_best(best_d_ref, best_i_ref,
+                             jnp.concatenate(cand_d, axis=1),
+                             jnp.concatenate(cand_i, axis=1), k)
+            n_ext_ref[...] += 1
+
+        return carry
+
+    jax.lax.fori_loop(0, block_t // width, one_slice, 0)
 
 
 def _knn_kernel_packed(q_ref, t_ref, best_d_ref, best_i_ref, *, k: int,
@@ -630,8 +740,10 @@ def knn_topk_pallas(
     compute_dtype: str = "float32",
     packed: bool = False,
     n_attrs: Optional[int] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(dist [nq, k] ascending, index [nq, k]) of the k nearest train rows.
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """(dist [nq, k] ascending, index [nq, k]) of the k nearest train rows,
+    and per query block the slices of slice_rows(block_t) train rows
+    that the kernel extracted [nq // block_q] (packed: every one).
 
     Distances match ops.distance.pairwise_distance semantics (attribute-
     averaged; euclidean = sqrt of mean squared per-attribute distance) for
@@ -662,24 +774,40 @@ def knn_topk_pallas(
         k=k, metric=metric, block_t=block_t, n_valid=nv, nt=nt,
         compute_dtype=jnp.dtype(compute_dtype).type)
     grid = (nq // block_q, nt // block_t)
-    best_d, best_i = pl.pallas_call(
+    out_specs = [
+        # revisited across the train axis: the running best buffer
+        pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
+        pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((nq, k), jnp.float32),
+        jax.ShapeDtypeStruct((nq, k), jnp.int32),
+    ]
+    scratch = []
+    if not packed:
+        # the exact kernel's count of extracted slices, one (8, 128) block
+        # a query block, every element of it the count
+        out_specs.append(pl.BlockSpec((8, _LANES), lambda i, j: (i, 0)))
+        out_shape.append(
+            jax.ShapeDtypeStruct((grid[0] * 8, _LANES), jnp.int32))
+        if metric == "manhattan" and _hoists_queries(d, block_q, block_t):
+            scratch.append(pltpu.VMEM(
+                (d, block_q, _group_rows(block_t)), jnp.float32))
+    best_d, best_i, *n_ext = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_t, d), lambda i, j: (j, 0)),
         ],
-        out_specs=[
-            # revisited across the train axis: the running best buffer
-            pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, k), jnp.float32),
-            jax.ShapeDtypeStruct((nq, k), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
     )(q, t)
+    # the packed kernel extracts every tile whole: every slice of it
+    extracted = (n_ext[0][::8, 0] if n_ext else jnp.full(
+        (grid[0],), nt // slice_rows(block_t), jnp.int32))
     na = d if n_attrs is None else n_attrs
     if metric == "euclidean":
         # kernel carries squared sums; finish to attribute-averaged sqrt
@@ -687,7 +815,7 @@ def knn_topk_pallas(
     else:
         best_d = best_d / max(na, 1)
     best_i = jnp.where(jnp.isinf(best_d), -1, best_i)
-    return best_d, best_i
+    return best_d, best_i, extracted
 
 
 def pallas_available() -> bool:

@@ -293,3 +293,64 @@ def test_the_block_route_names_no_phases(files):
         "dataset.range", "dataset.parse"]
     assert len(by_block) == len(by_path) == TEST_ROWS
     np.testing.assert_array_equal(by_block.labels(), by_path.labels())
+
+
+# ------------------------------ the exact kernel's counter (ISSUE 37)
+COUNTER_SCHEMA = {"fields": [
+    {"name": "studentID", "ordinal": 0, "id": True, "dataType": "string"},
+    {"name": "a1", "ordinal": 1, "dataType": "int", "feature": True,
+     "min": 0, "max": 9999},
+    {"name": "a2", "ordinal": 2, "dataType": "int", "feature": True,
+     "min": 0, "max": 9999},
+    {"name": "status", "ordinal": 3, "dataType": "categorical",
+     "cardinality": ["fail", "pass"]}]}
+
+
+def _counter_dataset(a):
+    schema = FeatureSchema.from_json(COUNTER_SCHEMA)
+    text = "".join(f"S{i},{x},{y},{'pass' if (x + y) % 2 else 'fail'}\n"
+                   for i, (x, y) in enumerate(a.tolist()))
+    return Dataset.from_csv(text.encode(), schema)
+
+
+@pytest.mark.parametrize("corpus", ["falling", "shuffled"])
+def test_the_fetch_span_carries_the_exact_kernels_count(corpus, monkeypatch):
+    """`knn.query.fetch` says how many train slices the exact kernel
+    tested and how many it extracted. In falling order of distance to the
+    cohort every slice holds a nearer row, and the kernel does all of
+    today's work; on a shuffled corpus of whole numbers a query soon holds
+    five rows no later one beats, and most slices are passed over."""
+    import functools
+
+    import avenir_tpu.ops.pallas_knn as pk
+    from avenir_tpu.models.knn import NearestNeighborClassifier
+
+    monkeypatch.setattr(pk, "pallas_available", lambda: True)
+    monkeypatch.setattr(pk, "knn_topk_pallas",
+                        functools.partial(pk.knn_topk_pallas, interpret=True))
+    rng = np.random.default_rng(37)
+    if corpus == "falling":
+        # the cohort is one point (the queries' pad rows are zeros too)
+        train = np.stack([9999 - np.arange(8192), np.zeros(8192, int)], 1)
+        test = np.zeros((300, 2), int)
+    else:
+        train = rng.integers(0, 16, (65_536, 2))
+        test = rng.integers(0, 16, (256, 2))
+    knn = NearestNeighborClassifier(_counter_dataset(train))
+    assert knn.index.kernel == "exact"
+    with obs.capture() as rec:
+        knn.predict(_counter_dataset(test))
+    fetch, = [s for s in rec.spans() if s.name == "knn.query.fetch"]
+    width = pk.slice_rows(knn.index.block)
+    blocks = -(-len(test) // 256)
+    assert fetch.attrs["rows"] == len(test)
+    assert fetch.attrs["slice_rows"] == width == 2048
+    assert fetch.attrs["slices"] == blocks * (knn.index.n_padded // width)
+    if corpus == "falling":
+        assert fetch.attrs["slices"] == 8
+        assert fetch.attrs["extracted"] == fetch.attrs["slices"]
+    else:
+        assert fetch.attrs["slices"] == 32
+        assert 1 <= fetch.attrs["extracted"] < fetch.attrs["slices"] / 2
+    dispatch, = [s for s in rec.spans() if s.name == "knn.query.dispatch"]
+    assert dispatch.attrs["kernel"] == "exact"

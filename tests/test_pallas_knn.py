@@ -21,7 +21,7 @@ def test_kernel_matches_jnp_path(metric):
 
     ref_d, ref_i = blocked_topk_neighbors(
         jnp.asarray(q), jnp.asarray(t), k=k, block=nt, metric=metric)
-    got_d, got_i = knn_topk_pallas(
+    got_d, got_i, _ = knn_topk_pallas(
         jnp.asarray(q), jnp.asarray(t), k=k, block_q=128, block_t=256,
         metric=metric, interpret=True)
     np.testing.assert_allclose(np.asarray(got_d), np.asarray(ref_d),
@@ -39,7 +39,7 @@ def test_kernel_masks_padding():
     q = rng.normal(size=(nq, d)).astype(np.float32)
     t_real = rng.normal(size=(100, d)).astype(np.float32)
     t_pad, _, n_valid = pad_train(t_real, None, 128)
-    got_d, got_i = knn_topk_pallas(
+    got_d, got_i, _ = knn_topk_pallas(
         jnp.asarray(q), jnp.asarray(t_pad), k=k, block_q=128, block_t=128,
         n_valid=n_valid, interpret=True)
     assert (np.asarray(got_i) < 100).all()
@@ -56,7 +56,7 @@ def test_kernel_multi_block_merge():
     # plant the 4 nearest rows in 4 different 128-blocks
     for b, scale in enumerate([0.01, 0.02, 0.03, 0.04]):
         t[b * 128 + 7] = scale
-    got_d, got_i = knn_topk_pallas(
+    got_d, got_i, _ = knn_topk_pallas(
         jnp.asarray(q), jnp.asarray(t), k=k, block_q=128, block_t=128,
         interpret=True)
     expect = {7, 135, 263, 391}
@@ -69,7 +69,7 @@ def test_kernel_small_train_fills_with_sentinels():
     q = np.zeros((128, 2), np.float32)
     t_real = np.ones((2, 2), np.float32)
     t_pad, _, n_valid = pad_train(t_real, None, 128)
-    got_d, got_i = knn_topk_pallas(
+    got_d, got_i, _ = knn_topk_pallas(
         jnp.asarray(q), jnp.asarray(t_pad), k=4, block_q=128, block_t=128,
         n_valid=n_valid, interpret=True)
     d0, i0 = np.asarray(got_d)[0], np.asarray(got_i)[0]
@@ -93,7 +93,7 @@ def test_packed_kernel_matches_oracle(case):
             np.float32)
     t_pad, _, n_valid = pad_train(t, None, 256)
 
-    got_d, got_i = knn_topk_pallas(
+    got_d, got_i, _ = knn_topk_pallas(
         jnp.asarray(q), jnp.asarray(t_pad), k=k, block_q=128, block_t=256,
         n_valid=n_valid, interpret=True, packed=True)
     got_d, got_i = np.asarray(got_d), np.asarray(got_i)
@@ -384,3 +384,132 @@ def test_randomized_classify_sweep_fused_vs_composed():
                                False, False))
         agree = (scores.argmax(1) == ref.argmax(1)).mean()
         assert agree >= 0.98, (trial, kernel_fn, agree)
+
+
+# ------------------------------------------- the exact kernel, bit for bit
+def _k_least_pairs(q, t, k, metric):
+    """The k least (distance, index) pairs a query in lexicographic order,
+    plain numpy: the features added in the kernel's order in float32 (the
+    euclidean cases are whole numbers, exact in any order), a stable sort
+    on the distance; (+inf, -1) where the corpus runs out."""
+    acc = np.zeros((q.shape[0], t.shape[0]), np.float32)
+    for f in range(q.shape[1]):
+        diff = q[:, f][:, None] - t[:, f][None, :]
+        acc = acc + (np.abs(diff) if metric == "manhattan" else diff * diff)
+    if metric == "euclidean":
+        acc = np.sqrt(acc)
+    idx = np.argsort(acc, axis=1, kind="stable")[:, :k]
+    dist = np.take_along_axis(acc, idx, axis=1)
+    short = k - idx.shape[1]
+    if short > 0:
+        dist = np.pad(dist, ((0, 0), (0, short)), constant_values=np.inf)
+        idx = np.pad(idx, ((0, 0), (0, short)), constant_values=-1)
+    return dist, idx.astype(np.int32)
+
+
+def _sorted_by_distance(t, q0, metric, falling):
+    """`t` in rising (or falling) order of its rows' distance to `q0`, rows
+    at the same distance dropped: every row strictly nearer (or farther)
+    than the one before it."""
+    diff = t - q0[None, :]
+    dist = np.abs(diff).sum(1) if metric == "manhattan" else (diff ** 2).sum(1)
+    _, first = np.unique(dist, return_index=True)
+    t = t[first]
+    return t[::-1].copy() if falling else t
+
+
+#: (id, query rows, train rows, block_q, block_t, k, values, order)
+_EXACT_CASES = [
+    ("ties", 256, 768, 128, 256, 5, "few", "drawn"),
+    ("ties-one-tile", 128, 2048, 128, 2048, 5, "few", "drawn"),
+    ("falling", 256, 3000, 128, 256, 5, "whole", "falling"),
+    ("rising", 256, 3000, 128, 256, 5, "whole", "rising"),
+    ("falling-8192", 128, 20000, 128, 8192, 5, "whole", "falling"),
+    ("rising-8192", 128, 20000, 128, 8192, 5, "whole", "rising"),
+    ("block-256", 128, 1024, 128, 256, 5, "whole", "drawn"),
+    ("block-768", 128, 2304, 128, 768, 5, "whole", "drawn"),
+    ("block-2048", 128, 6144, 128, 2048, 5, "whole", "drawn"),
+    ("block-8192", 128, 16384, 128, 8192, 5, "whole", "drawn"),
+    ("padded-tail", 128, 1500, 128, 768, 5, "whole", "drawn"),
+    ("padded-tail-8192", 128, 9000, 128, 8192, 3, "whole", "drawn"),
+    ("smaller-than-k", 128, 3, 128, 256, 5, "whole", "drawn"),
+    ("tiles-and-blocks", 512, 4096, 128, 512, 5, "whole", "drawn"),
+    ("k1", 256, 2048, 256, 1024, 1, "few", "drawn"),
+    ("k8", 128, 2048, 128, 512, 8, "whole", "drawn"),
+]
+
+
+def _exact_case(case, metric):
+    name, nq, nt, bq, bt, k, values, order = case
+    rng = np.random.default_rng([37, len(name), nt])
+    d = 9
+    hi = np.array([600, 200, 100, 28, 100, 100, 280, 180, 26], np.float32)
+    if values == "few":
+        q = rng.integers(0, 4, (nq, d)).astype(np.float32)
+        t = rng.integers(0, 4, (nt, d)).astype(np.float32)
+    else:
+        q = np.floor(rng.random((nq, d)) * (hi + 1)).astype(np.float32)
+        t = np.floor(rng.random((nt, d)) * (hi + 1)).astype(np.float32)
+        if metric == "manhattan":       # shares of the ranges: real sums
+            q, t = q / hi, t / hi
+    if order != "drawn":
+        q = np.broadcast_to(q[:1], q.shape).copy()
+        t = _sorted_by_distance(t, q[0], metric, falling=order == "falling")
+    return q, t, bq, bt, k
+
+
+@pytest.mark.parametrize("metric", ["manhattan", "euclidean"])
+@pytest.mark.parametrize("case", _EXACT_CASES, ids=[c[0] for c in _EXACT_CASES])
+def test_exact_kernel_is_the_k_least_pairs_bit_for_bit(case, metric):
+    """ISSUE 37: a slice is extracted only where a row of it lies strictly
+    under its query's k-th best, and the result is the same bits as the
+    whole-tile extraction's: the k least (distance, index) pairs."""
+    from avenir_tpu.ops.pallas_knn import slice_rows
+
+    q, t, bq, bt, k = _exact_case(case, metric)
+    t_pad, _, n_valid = pad_train(t, None, bt)
+    got_d, got_i, ext = knn_topk_pallas(
+        jnp.asarray(q), jnp.asarray(t_pad), k=k, block_q=bq, block_t=bt,
+        metric=metric, n_valid=n_valid, n_attrs=1, interpret=True)
+    want_d, want_i = _k_least_pairs(q, t, k, metric)
+    np.testing.assert_array_equal(np.asarray(got_i), want_i)
+    np.testing.assert_array_equal(np.asarray(got_d), want_d)
+
+    ext = np.asarray(ext)
+    slices = t_pad.shape[0] // slice_rows(bt)       # a query block's
+    assert ext.shape == (q.shape[0] // bq,)
+    assert (ext >= 1).all() and (ext <= slices).all()
+    order = case[-1]
+    if order == "falling":          # every slice holds a nearer row
+        real = -(-n_valid // slice_rows(bt))
+        assert (ext == real).all(), (ext, real)
+    if order == "rising":           # only the head does
+        assert (ext == 1).all(), ext
+
+
+def test_exact_kernel_with_rows_too_wide_to_keep_their_broadcasts():
+    """Above _QUERY_BROADCAST_BYTES the query columns' lane broadcasts are
+    made for every slice, not once a query block: the same answer."""
+    from avenir_tpu.ops.pallas_knn import _hoists_queries
+
+    d, bq, bt, k = 80, 128, 256, 4
+    assert _hoists_queries(9, 256, 8192) and not _hoists_queries(d, bq, bt)
+    rng = np.random.default_rng(80)
+    q = (rng.integers(0, 7, (bq, d)) / 7).astype(np.float32)
+    t = (rng.integers(0, 7, (700, d)) / 7).astype(np.float32)
+    t_pad, _, n_valid = pad_train(t, None, bt)
+    got_d, got_i, _ = knn_topk_pallas(
+        jnp.asarray(q), jnp.asarray(t_pad), k=k, block_q=bq, block_t=bt,
+        metric="manhattan", n_valid=n_valid, n_attrs=1, interpret=True)
+    want_d, want_i = _k_least_pairs(q, t, k, "manhattan")
+    np.testing.assert_array_equal(np.asarray(got_i), want_i)
+    np.testing.assert_array_equal(np.asarray(got_d), want_d)
+
+
+@pytest.mark.parametrize("block_t,want", [
+    (128, 128), (256, 256), (512, 512), (768, 256), (1024, 1024),
+    (2048, 2048), (2304, 256), (8192, 2048), (100, 100), (64, 64)])
+def test_slice_rows_divides_every_block(block_t, want):
+    from avenir_tpu.ops.pallas_knn import slice_rows
+
+    assert slice_rows(block_t) == want and block_t % slice_rows(block_t) == 0

@@ -59,8 +59,10 @@ def run(dim):
         def many(q, t):
             def step(i):
                 qi = jnp.roll(q, i, axis=0)
+                # the exact kernel returns its count of extracted slices too
                 dist, idx = fn(qi, t, k=K, block_q=bq, block_t=bt,
-                               metric="euclidean", compute_dtype=cdt, **extra)
+                               metric="euclidean", compute_dtype=cdt,
+                               **extra)[:2]
                 return jnp.sum(dist) + jnp.sum(idx).astype(jnp.float32)
             return jax.lax.map(step, jnp.arange(1, STEPS + 1)).sum()
 

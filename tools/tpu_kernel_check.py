@@ -67,6 +67,52 @@ def check(name, got_d, got_i, q, t, k, metric, rtol):
     return ok
 
 
+def exact_at_cells_shape(kw, quick):
+    """The exact kernel at the benchmark's kNN cells' shape (manhattan over
+    nine whole-number activity fields as shares of their ranges, k=5, tiles
+    of 256 x 8,192, a padded tail), its (summed distance, index) held bit
+    for bit to the k least pairs in lexicographic order. The oracle adds
+    the features in the kernel's order in float32, so the sums are the same
+    bits; whole numbers make ties by the thousand, so the order among them
+    is tested too. Prints the share of slices the kernel extracted."""
+    import jax.numpy as jnp
+    from avenir_tpu.ops.distance import pad_train
+    from avenir_tpu.ops.pallas_knn import knn_topk_pallas, slice_rows
+
+    bq, bt, d, k = 256, 8192, 9, 5
+    nq, nt = (256, 2 * bt - 1000) if quick else (512, 128 * bt - 1000)
+    rng = np.random.default_rng([7, nt, d])
+    hi = np.array([600, 200, 100, 28, 100, 100, 280, 180, 26], np.float32)
+
+    def draw(n):
+        x = np.clip(rng.normal(0.5, 0.12, (n, d)) * hi, 0, hi)
+        return x.astype(np.int32).astype(np.float32) / hi
+
+    q, t = draw(nq), draw(nt)
+    t_pad, _, n_valid = pad_train(t, None, bt)
+    got_d, got_i, ext = knn_topk_pallas(
+        jnp.asarray(q), jnp.asarray(t_pad), k=k, block_q=bq, block_t=bt,
+        metric="manhattan", n_valid=n_valid, n_attrs=1, **kw)
+    got_d, got_i = np.asarray(got_d), np.asarray(got_i)
+    ok = True
+    rows = np.arange(bq, dtype=np.int32)
+    for b in range(0, nq, bq):
+        acc = np.zeros((bq, nt), np.float32)
+        for f in range(d):
+            acc += np.abs(q[b:b + bq, f][:, None] - t[:, f][None, :])
+        for j in range(k):      # the least pair left: argmin takes the first
+            near = acc.argmin(axis=1)
+            ok = ok and np.array_equal(got_i[b:b + bq, j], near) \
+                and np.array_equal(got_d[b:b + bq, j], acc[rows, near])
+            acc[rows, near] = np.inf
+    slices = (nq // bq) * (t_pad.shape[0] // slice_rows(bt))
+    ext = int(np.asarray(ext).sum())
+    print(f"{'PASS' if ok else 'FAIL'} exact/cells-shape {nq} x {nt} "
+          f"(slices of {slice_rows(bt)} rows: {ext} of {slices} extracted)"
+          + ("" if ok else ": not the k least (distance, index) pairs"))
+    return ok
+
+
 def run_cases(interpret: bool = False, quick: bool = False):
     """(passed, total) over every case; one PASS/FAIL line each."""
     import jax
@@ -96,11 +142,11 @@ def run_cases(interpret: bool = False, quick: bool = False):
         t_pad, _, n_valid = pad_train(t, None, bt)
         qd, td = jnp.asarray(q), jnp.asarray(t_pad)
 
-        de, ie = knn_topk_pallas(qd, td, k=k, block_q=bq, block_t=bt,
+        de, ie, _ = knn_topk_pallas(qd, td, k=k, block_q=bq, block_t=bt,
                                  metric=metric, n_valid=n_valid, **kw)
         results.append(check(f"exact/{label}", de, ie, q, t, k, metric, 1e-3))
         if bt <= 4096:
-            dp, ip = knn_topk_pallas(qd, td, k=k, block_q=bq, block_t=bt,
+            dp, ip, _ = knn_topk_pallas(qd, td, k=k, block_q=bq, block_t=bt,
                                      metric=metric, n_valid=n_valid,
                                      packed=True, **kw)
             results.append(
@@ -214,6 +260,8 @@ def run_cases(interpret: bool = False, quick: bool = False):
     ok = set(np.asarray(il)[0].tolist()) == set(cols)
     print(f"{'PASS' if ok else 'FAIL'} lanes/same-lane-collision")
     results.append(ok)
+
+    results.append(exact_at_cells_shape(kw, quick))
 
     return int(sum(results)), len(results)
 
