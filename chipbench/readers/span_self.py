@@ -1,8 +1,10 @@
 """Milliseconds of the program's span of one name that none of the spans
 the metric's file lists under `children` covers, per job: the span's
 duration less the union of the children's intervals, clipped to it.
-`ctx["spans"]` carries no thread, so the file names the job thread's
-leaves; a program without the span leaves the metric out."""
+The file names the job thread's leaves, whatever thread a span of that
+name was recorded on (the spans carry their thread since PR 38; the list
+stays until a PR shows that the two agree); a program without the span
+leaves the metric out."""
 from chipbench import reduce
 
 

@@ -3,7 +3,9 @@ Python over plain lists, so that tests can check them on a small recorded
 event list and every PR computes the same number in the same way.
 
 An event is `[name, start_ns, duration_ns]`; a span is
-`{"name", "t0", "dur"}` with seconds on the host's clock.
+`{"name", "t0", "dur", "tid", "attrs"}` with seconds on the host's clock
+(`tid` and `attrs` may be missing: a span recorded before PR 38 kept
+neither).
 """
 
 from __future__ import annotations
@@ -83,13 +85,12 @@ def top_by_name(events: Iterable[Event], n: int = 10
     return [[name, ns / 1e9] for name, ns in rows]
 
 
-def idle_by_neighbours(events: Sequence[Event], lo: float, hi: float,
-                       n: int = 10) -> List[List]:
-    """[[name, idle seconds], ...], most first: each idle gap of [lo, hi]
-    named by the device operation that ended before it and the one that
-    began after it (`job start` and `job end` at the window's ends), the
-    gaps of one name added up. The program has no spans on the profiler's
-    clock yet, so this is as far as a gap can be named."""
+def neighbour_gaps(events: Sequence[Event], lo: float, hi: float
+                   ) -> Dict[str, float]:
+    """{name: idle ns}: each idle gap of [lo, hi] named by the device
+    operation that ended before it and the one that began after it
+    (`job start` and `job end` at the window's ends), the gaps of one name
+    added up."""
     ops = sorted((e for e in events if e[1] + e[2] > lo and e[1] < hi),
                  key=lambda e: e[1])
     acc: Dict[str, float] = {}
@@ -103,7 +104,122 @@ def idle_by_neighbours(events: Sequence[Event], lo: float, hi: float,
     if hi > at:
         key = f"{left} -> job end"
         acc[key] = acc.get(key, 0.0) + (hi - at)
-    rows = sorted(acc.items(), key=lambda kv: -kv[1])[:n]
+    return acc
+
+
+def idle_by_neighbours(events: Sequence[Event], lo: float, hi: float,
+                       n: int = 10) -> List[List]:
+    """[[name, idle seconds], ...] of the n names of `neighbour_gaps` with
+    most idle time, most first. It says which program ends a gap, not what
+    the host was doing: `innermost_on_profiler_clock` says that."""
+    rows = sorted(neighbour_gaps(events, lo, hi).items(),
+                  key=lambda kv: -kv[1])[:n]
+    return [[name, ns / 1e9] for name, ns in rows]
+
+
+# ------------------------------------------- the host's clock on the trace's
+#: the clock join is refused where its slack is above this share of the
+#: window, or below 0
+MAX_SLACK_SHARE = 0.01
+NO_SPAN = "no span"
+REST = "rest"
+
+
+def clock_join(spans: Sequence[Dict], root: str, lo: float, hi: float):
+    """(root span, slack ns) of the join of the host's clock to the
+    profiler's through the one span called `root`, or None where there is
+    not exactly one. The root lies inside the window [lo, hi], so a span
+    that starts at `t0` stands at `lo + (t0 - root's t0)`, too early by at
+    most `slack = window length - root's duration`."""
+    roots = [s for s in spans if s["name"] == root]
+    if len(roots) != 1:
+        return None
+    return roots[0], (hi - lo) - roots[0]["dur"] * 1e9
+
+
+def join_holds(slack: float, lo: float, hi: float) -> bool:
+    return 0.0 <= slack <= MAX_SLACK_SHARE * (hi - lo)
+
+
+def on_profiler_clock(span: Dict, root: Dict, lo: float
+                      ) -> Tuple[float, float]:
+    """(start ns, end ns) of a span by the join through `root`."""
+    start = lo + (span["t0"] - root["t0"]) * 1e9
+    return start, start + span["dur"] * 1e9
+
+
+def leaves_on_profiler_clock(spans, names, root, lo):
+    """[(start_ns, end_ns, name), ...] of the spans called one of `names`,
+    sorted and made disjoint: of two that overlap, the one that began
+    first keeps the time."""
+    out, front = [], lo
+    for s in sorted((s for s in spans if s["name"] in names),
+                    key=lambda s: s["t0"]):
+        start, end = on_profiler_clock(s, root, lo)
+        start = max(start, front)
+        if end > start:
+            out.append((start, end, s["name"]))
+            front = end
+    return out
+
+
+def job_thread(spans: Sequence[Dict], root: Dict) -> List[Dict]:
+    """The spans of the root's thread. A span without `tid` counts as the
+    job thread's."""
+    tid = root.get("tid")
+    return [s for s in spans if s.get("tid", tid) == tid]
+
+
+def innermost_on_profiler_clock(spans, root, lo, hi):
+    """[(start_ns, end_ns, name), ...] that cut [lo, hi] without a gap,
+    each piece named by the innermost span of the job's thread that covers
+    it, or `NO_SPAN`. The innermost is the shortest: of spans that nest it
+    is the one inside the others. Of two alike it is the one begun later,
+    and then the name that sorts last (`a.b.c` before `a.b`)."""
+    placed = []
+    for s in job_thread(spans, root):
+        start, end = on_profiler_clock(s, root, lo)
+        start, end = max(start, lo), min(end, hi)
+        if end > start:
+            placed.append((start, end, s["name"]))
+    placed.sort()
+    cuts = sorted({lo, hi} | {p[0] for p in placed} | {p[1] for p in placed})
+    out, active, nxt = [], [], 0
+    for a, b in zip(cuts, cuts[1:]):
+        while nxt < len(placed) and placed[nxt][0] <= a:
+            active.append(placed[nxt])
+            nxt += 1
+        active = [p for p in active if p[1] > a]
+        inner = max(active, key=lambda p: (p[0] - p[1], p[0], p[2]))[2] \
+            if active else NO_SPAN
+        out.append((a, b, inner))
+    return out
+
+
+def idle_inside(pieces, gaps) -> Dict[str, float]:
+    """{name: ns} of the idle gaps that fall inside each of the sorted,
+    disjoint (start, end, name) pieces; over the pieces of
+    `innermost_on_profiler_clock`, which leave no gap, the values add up to
+    the gaps' length."""
+    acc: Dict[str, float] = {}
+    at = 0                              # both lists are sorted: one walk
+    for g0, g1 in gaps:
+        while at < len(pieces) and pieces[at][1] <= g0:
+            at += 1
+        for p0, p1, name in pieces[at:]:
+            if p0 >= g1:
+                break
+            acc[name] = acc.get(name, 0.0) + min(p1, g1) - max(p0, g0)
+    return acc
+
+
+def top_with_rest(acc: Dict[str, float], keep: int) -> List[List]:
+    """[[name, seconds], ...], most first: all of `acc` where it has at most
+    `keep + 1` names, else the `keep` largest and `REST` with the others'
+    sum, so that the rows always add up to the whole."""
+    rows = sorted(acc.items(), key=lambda kv: -kv[1])
+    if len(rows) > keep + 1:
+        rows = rows[:keep] + [(REST, sum(ns for _n, ns in rows[keep:]))]
     return [[name, ns / 1e9] for name, ns in rows]
 
 

@@ -53,6 +53,10 @@ from chipbench import compare, manifest, reduce, xtrace  # noqa: E402
 
 WORK_DIR = ".chipbench_work"         # inside the checkout, git-ignored
 JOB_ANNOTATION = "chipbench.job"
+ROOT_SPAN = "job.cli"                # the program's one span around a job
+#: named rows of a chip's idle time in `breakdown.idle_gaps` and in
+#: `notes.by_chip`, and `rest` besides: the driver keeps 10 rows a list
+IDLE_ROWS, CHIP_ROWS = 9, 5
 
 
 class NoChip(RuntimeError):
@@ -268,7 +272,8 @@ def traced_job(one_job: Callable[[int], None], work: str) -> Dict:
     events = xtrace.read_events(xtrace.newest_xplane(log_dir))
     shutil.rmtree(log_dir, ignore_errors=True)
     lo, hi = xtrace.window_of(events["annotations"], JOB_ANNOTATION)
-    spans = [{"name": s.name, "t0": s.t0, "dur": s.dur} for s in rec.spans()]
+    spans = [{"name": s.name, "t0": s.t0, "dur": s.dur, "tid": s.tid,
+              "attrs": s.attrs or {}} for s in rec.spans()]
     return {"spans": spans, "devices": events["devices"],
             "window_ns": (lo, hi), "compiles": (before, after),
             "lines": events["lines"]}
@@ -299,12 +304,49 @@ def per_layer(cell: manifest.Cell, man: manifest.Manifest, ctx: Dict,
     busy = sum(reduce.busy_ns(d["ops"], lo, hi) for d in devs) / len(devs)
     first = devs[0]["ops"]
     in_window = [e for e in first if e[1] + e[2] > lo and e[1] < hi]
-    breakdown = {
-        "device_ops": reduce.top_by_name(in_window),
-        "idle_gaps": reduce.idle_by_neighbours(first, lo, hi)}
+    idle_of = idle_namer(ctx, lo, hi)
+    breakdown = {"device_ops": reduce.top_by_name(in_window),
+                 "idle_gaps": reduce.top_with_rest(idle_of(first), IDLE_ROWS)}
+    ctx["notes"]["idle_by_neighbours"] = reduce.idle_by_neighbours(first, lo, hi)
+    if len(devs) > 1:
+        ctx["notes"]["by_chip"] = []
+        for name, dev in ctx["devices"].items():
+            chip_busy = reduce.busy_ns(dev["ops"], lo, hi)
+            ctx["notes"]["by_chip"].append({
+                "chip": name, "busy_s": chip_busy / 1e9,
+                "idle_pct": 100.0 * (1.0 - chip_busy / (hi - lo)),
+                "idle_gaps": reduce.top_with_rest(idle_of(dev["ops"]),
+                                                  CHIP_ROWS)})
     return metrics, {"breakdown": breakdown, "notes": ctx["notes"],
                      "device": {"busy_s": busy / 1e9,
                                 "window_s": (hi - lo) / 1e9}}
+
+
+def idle_namer(ctx: Dict, lo: float, hi: float
+               ) -> Callable[[List], Dict[str, float]]:
+    """From one chip's operations to {name: idle ns} over the traced
+    window, adding up to that chip's idle time: each part of each gap put
+    down to the innermost span of the job's thread the host was in, or
+    `no span` (`reduce.innermost_on_profiler_clock`). Where the clock join
+    through `ROOT_SPAN` is refused, each gap is named by the device
+    operations around it (`reduce.neighbour_gaps`).
+    `notes.idle_gaps_named_by` says which, and why."""
+    joined = reduce.clock_join(ctx["spans"], ROOT_SPAN, lo, hi)
+    if joined is None:
+        why = f"operations: not one {ROOT_SPAN} span to join the clocks by"
+    else:
+        root, slack = joined
+        ctx["notes"]["clock_join_slack_ms"] = slack / 1e6
+        why = (None if reduce.join_holds(slack, lo, hi) else
+               f"operations: the clock join's slack is outside 0 to "
+               f"{reduce.MAX_SLACK_SHARE} of the window")
+    if why is None:
+        ctx["notes"]["idle_gaps_named_by"] = "span"
+        pieces = reduce.innermost_on_profiler_clock(ctx["spans"], root, lo, hi)
+        return lambda ops: reduce.idle_inside(pieces,
+                                              reduce.idle_gaps(ops, lo, hi))
+    ctx["notes"]["idle_gaps_named_by"] = why
+    return lambda ops: reduce.neighbour_gaps(ops, lo, hi)
 
 
 def main(argv: Optional[List[str]] = None) -> int:

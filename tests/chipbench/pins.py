@@ -191,3 +191,107 @@ def hold_the_first_sixteen(doc):
     assert names[:8] == FIRST_EIGHT
     assert sorted(names[8:16]) == sorted(SPAN_EIGHT)
     assert len(set(names)) == len(names)
+
+
+# ------------------------------------------- each cell's names, in order
+#: the per-layer names of three cells as the benchmark had them when PR 38
+#: made these pins containment, in their order. A later PR may add names
+#: to a cell's list (new entries go at the end of `per_layer`), never take
+#: one away or reorder them
+SHARED_FOUR = ["compiles_in_window", "device_idle_share", "peak_hbm_gb",
+               "train_encode_ms_per_job"]
+FOREST_CELL, FIA_CELL, MESH_CELL = ("rf-hangup.rebuild", "fia-t10i4.remine",
+                                    "fia-t10i4-mesh4.remine")
+FOREST_NAMES = [
+    "forest_level_ms_per_job", "forest_level_roofline",
+    "forest_sample_ms_per_job", "forest_segments_ms_per_job",
+    "forest_select_ms_per_job", "forest_unspanned_ms_per_job",
+    "forest_idle_named_share", "forest_segments_device_ms_per_job"]
+FIA_NAMES = [
+    "fia_read_ms_per_job", "fia_scan_ms_per_job", "fia_put_ms_per_job",
+    "fia_candidates_ms_per_job", "fia_support_ms_per_job",
+    "fia_pairs_roofline", "fia_sets_roofline", "fia_unspanned_ms_per_job",
+    "fia_idle_named_share"]
+MESH_NAMES = [
+    "mesh_read_ms_per_job", "mesh_scan_ms_per_job", "mesh_put_ms_per_job",
+    "mesh_support_ms_per_job", "mesh_allreduce_ms_per_job",
+    "mesh_pairs_roofline", "mesh_sets_roofline", "mesh_unspanned_ms_per_job",
+    "mesh_idle_named_share"]
+#: the layer of each of the miner's nine, and of the four-chip cell's nine
+FIA_LAYERS = ["Parse / replay", "Parse / replay", "Job registry and executors",
+              "Job registry and executors", "Device kernels", "Device kernels",
+              "Device kernels", "Entry and device rule", "Device"]
+MESH_LAYERS = ["Parse / replay", "Parse / replay", "Job registry and executors",
+               "Mesh", "Mesh", "Mesh", "Mesh", "Entry and device rule",
+               "Device"]
+
+
+def hold_in_order(names, accepted):
+    """Every accepted name stands in `names`, in the accepted order; other
+    names may stand among and after them."""
+    rest = iter(names)
+    assert all(name in rest for name in accepted), (accepted, names)
+
+
+def cell_names(man, cell, key="per_layer"):
+    return [m["name"] for m in getattr(man.cell(cell), key)]
+
+
+def own_entries(man, names, cell):
+    """The entries of `names`: each is `cell`'s and moves `job_s`."""
+    entries = {m["name"]: m for m in man.doc["per_layer"]}
+    for name in names:
+        assert cell in entries[name]["workloads"], name
+        assert entries[name]["moves"] == "job_s", name
+    return [entries[name] for name in names]
+
+
+def hold_forest_names(man):
+    """`rf-hangup.rebuild`: the five metrics it shares, then its own eight,
+    in order; `job_s` and `setup_s`; no kNN cell reports a `forest_*`."""
+    hold_in_order(cell_names(man, FOREST_CELL),
+                  SHARED_FOUR[:3] + ["train_parse_ms_per_job",
+                                     "train_encode_ms_per_job"] + FOREST_NAMES)
+    hold_in_order(cell_names(man, FOREST_CELL, "end_to_end"), ["job_s", "setup_s"])
+    own_entries(man, FOREST_NAMES, FOREST_CELL)
+    assert not set(FOREST_NAMES) & set(cell_names(man, "knn-elearn.bulk"))
+
+
+def hold_fia_names(man):
+    """`fia-t10i4.remine`: the four metrics it shares, then the miner's
+    nine in their order and with their layers, which stand in `per_layer`
+    in that order too; the forest cell and the four-chip cell report none
+    of the nine."""
+    hold_in_order(cell_names(man, FIA_CELL), SHARED_FOUR + FIA_NAMES)
+    hold_in_order(cell_names(man, FIA_CELL, "end_to_end"), ["job_s", "setup_s"])
+    hold_in_order([m["name"] for m in man.doc["per_layer"]], FIA_NAMES)
+    entries = own_entries(man, FIA_NAMES, FIA_CELL)
+    assert [m["layer"] for m in entries] == FIA_LAYERS
+    for other in (FOREST_CELL, MESH_CELL):
+        assert not set(FIA_NAMES) & set(cell_names(man, other)), other
+
+
+def hold_mesh_names(man):
+    """`fia-t10i4-mesh4.remine`: the four metrics it shares (it stands on
+    their lists), then its own nine in their order, with their layers and
+    sources, all of them after the miner's nine in `per_layer`; the
+    one-chip cell reports none of them, and this cell no `fia_*`, least of
+    all `fia_*_roofline`, which would divide the whole file's work by one
+    chip's peak."""
+    hold_in_order(cell_names(man, MESH_CELL), SHARED_FOUR + MESH_NAMES)
+    hold_in_order(cell_names(man, MESH_CELL, "end_to_end"), ["job_s", "setup_s"])
+    names = [m["name"] for m in man.doc["per_layer"]]
+    hold_in_order(names, FIA_NAMES + MESH_NAMES)
+    entries = own_entries(man, MESH_NAMES, MESH_CELL)
+    assert [m["layer"] for m in entries] == MESH_LAYERS
+    assert [m["source"] for m in entries] == (
+        ["program_span"] * 3 + ["device_trace"] * 4 + ["program_span"] * 2)
+    own_entries(man, SHARED_FOUR, MESH_CELL)
+    assert not set(MESH_NAMES) & set(cell_names(man, FIA_CELL))
+    assert not any(n.startswith("fia_") for n in cell_names(man, MESH_CELL))
+
+
+def hold_every_cells_names(man):
+    hold_forest_names(man)
+    hold_fia_names(man)
+    hold_mesh_names(man)

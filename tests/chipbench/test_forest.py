@@ -357,19 +357,12 @@ def test_the_deployment_through_every_pin():
     assert live >= 0.27 * 2**34
 
 
-def test_the_cells_per_layer_metrics_are_these_twelve():
-    cell = manifest.Manifest().cell(CELL)
-    assert [m["name"] for m in cell.per_layer] == [
-        "compiles_in_window", "device_idle_share", "peak_hbm_gb",
-        "train_parse_ms_per_job", "train_encode_ms_per_job"] + FOREST_METRICS
-    assert len(FOREST_METRICS) == 7
-    assert [m["name"] for m in cell.end_to_end] == ["job_s", "setup_s"]
-    for name in FOREST_METRICS:
-        entry = next(m for m in DOC["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "job_s"
-    # no kNN cell reports them
-    assert not set(FOREST_METRICS) & {
-        m["name"] for m in manifest.Manifest().cell("knn-elearn.bulk").per_layer}
+def test_the_cells_per_layer_metrics_hold_these_thirteen_in_order():
+    """The five it shares and its own eight (PR 38 added the eighth,
+    `forest_segments_device_ms_per_job`), in their order among whatever a
+    later PR adds, and no kNN cell reports one (`pins.hold_forest_names`)."""
+    pins.hold_forest_names(manifest.Manifest())
+    assert FOREST_METRICS[:8] == pins.FOREST_NAMES
 
 
 # ------------------------------------------- the readers, a recorded job
@@ -401,12 +394,13 @@ EXPECTED = {
     "forest_select_ms_per_job": 3.0,
     "forest_unspanned_ms_per_job": 10.6,       # 0.8 + 5 + 4.8 ms of the job
     "forest_idle_named_share": 100.0 * (85.5 - 10.6 - 0.4) / 85.5,
+    "forest_segments_device_ms_per_job": 1.3,  # jit__segment_lines (PR 38)
 }
 
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))
 def test_each_forest_metric_reads_the_recorded_job(ctx, name):
-    assert sorted(EXPECTED) == sorted(FOREST_METRICS)
+    assert sorted(EXPECTED) == sorted(pins.FOREST_NAMES)
     assert read(ctx, name) == pytest.approx(EXPECTED[name], rel=1e-6)
 
 
@@ -442,6 +436,23 @@ def test_a_program_without_the_spans_leaves_the_metric_out(ctx, name):
     assert read(ctx, name) is not None
     ctx["spans"] = []
     assert read(ctx, name) is None
+
+
+def test_the_segment_program_is_read_apart_from_the_level_programs(ctx):
+    """`forest_segments_device_ms_per_job` (PR 38) reads `_segment_lines`
+    alone; the level programs' metrics read what they read without it;
+    where no segment program ran nothing is returned, never 0."""
+    assert read(ctx, "forest_segments_device_ms_per_job") == \
+        pytest.approx(1.3, rel=1e-9)
+    dev = ctx["devices"]["/device:TPU:0"]
+    dev["modules"] = [m for m in dev["modules"] if "_segment_lines" not in m[0]]
+    assert read(ctx, "forest_segments_device_ms_per_job") is None
+    assert read(ctx, "forest_level_ms_per_job") == pytest.approx(14.5)
+    entry = next(m for m in DOC["per_layer"]
+                 if m["name"] == "forest_segments_device_ms_per_job")
+    assert DOC["per_layer"].index(entry) == 43   # after PR 37's 43 entries
+    assert (entry["source"], entry["layer"], entry["moves"]) == (
+        "device_trace", "Device kernels", "job_s")
 
 
 def test_both_forest_lists_name_the_same_leaves_and_no_parent():

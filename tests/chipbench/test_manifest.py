@@ -58,7 +58,7 @@ def test_configurations_do_not_share_a_file_or_a_source():
 def test_cell_files_are_found_by_name(cell):
     entry = next(w for w in DOC["workloads"] if w["name"] == cell)
     pins.hold_cell(manifest.Manifest(), entry)
-    assert entry["chips"] == 1      # no path of the job crosses chips (D5)
+    assert entry["chips"] in (1, 4)  # since PR 36 a path of the job does cross chips
 
 
 def test_at_most_a_quarter_of_the_cells_ask_for_four_chips():
@@ -374,6 +374,46 @@ def test_a_deployment_of_another_job_family_is_added_as_files_and_entries(tmp_pa
         m["name"] for m in again.cell(CELLS[0]).per_layer]
 
     assert not set(added) & {os.path.relpath(p, bench) for p in before}
+    for p, stamp in before.items():
+        assert (os.path.getmtime(p), os.path.getsize(p)) == stamp, p
+    shutil.rmtree(str(tmp_path), ignore_errors=True)
+
+
+def test_a_per_layer_entry_appended_at_the_end_passes_every_pin(tmp_path):
+    """What a later PR of any kind may do: one more metric at the end of
+    `per_layer`, listing every cell, as a file and an entry. Every pin of
+    `pins.py` holds over the copy, each cell's accepted names in their
+    order among them (PR 38 made the pins that held names to their places
+    containment), and every cell reports the new metric last."""
+    man = small_copy(str(tmp_path), train_rows=8192)
+    bench = man.bench_dir
+    before = file_stamps(bench)
+    with open(os.path.join(bench, "metrics", "throwaway_ms_per_job.json"),
+              "w") as fh:
+        json.dump({"name": "throwaway_ms_per_job", "unit": "ms",
+                   "reader": "span_sum", "params": {"span": "job.cli"}}, fh)
+    doc = json.loads(json.dumps(man.doc))
+    doc["per_layer"].append({
+        "name": "throwaway_ms_per_job", "unit": "ms", "better": "lower",
+        "source": "program_span", "layer": "Entry and device rule",
+        "moves": "job_s", "workloads": [w["name"] for w in doc["workloads"]]})
+    with open(os.path.join(man.root, "BENCHMARK.json"), "w") as fh:
+        json.dump(doc, fh)
+
+    again = manifest.Manifest(man.root, bench)
+    for cfg in doc["configs"]:
+        pins.hold_configuration(again, cfg)
+        pins.hold_environment(again, cfg, ROOT)
+    for entry in doc["workloads"]:
+        pins.hold_cell(again, entry)
+        assert pins.cell_names(again, entry["name"])[-1] == "throwaway_ms_per_job"
+    pins.hold_four_chip_share(doc)
+    for m in doc["per_layer"]:
+        pins.hold_per_layer_metric(again, m)
+    for name in pins.SPAN_EIGHT:
+        pins.hold_span_metric_entry(doc, name)
+    pins.hold_the_first_sixteen(doc)
+    pins.hold_every_cells_names(again)
     for p, stamp in before.items():
         assert (os.path.getmtime(p), os.path.getsize(p)) == stamp, p
     shutil.rmtree(str(tmp_path), ignore_errors=True)

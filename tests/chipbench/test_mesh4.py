@@ -1,10 +1,10 @@
 """The four-chip deployment `fia-t10i4-mesh4` and its cell
 `fia-t10i4-mesh4.remine`: the entry, the configuration (`fia-t10i4`'s but
 for its size) and the rule of that size as arithmetic, the cell's thirteen
-per-layer names and where they stand (last, after the one-chip cell's
-nine), every pin of `pins.py`, the two new readers over a recorded
-four-device job (data/events_mesh4_job.json) and over the recorded
-one-device job, and the cell's job on the CPU: a process that sees four
+per-layer names and their order (after the one-chip cell's nine; since
+PR 38 not their places), every pin of `pins.py`, the two new readers over
+a recorded four-device job (data/events_mesh4_job.json) and over the
+recorded one-device job, and the cell's job on the CPU: a process that sees four
 devices, at 45,056 baskets with slabs of 4,096, against the plain reference
 through the check a chip run uses."""
 
@@ -26,8 +26,7 @@ with open(os.path.join(ROOT, "BENCHMARK.json")) as _fh:
     DOC = json.load(_fh)
 NAMES = [m["name"] for m in DOC["per_layer"]]
 MESH_METRICS = [n for n in NAMES if n.startswith("mesh_")]
-SHARED = ["compiles_in_window", "device_idle_share", "peak_hbm_gb",
-          "train_encode_ms_per_job"]
+SHARED = pins.SHARED_FOUR
 EXACT = ("sets_bad", "unstable_bytes", "support_wrong", "sets_surplus",
          "closure_broken", "sets_missing")
 GIB16 = 1 << 34
@@ -46,12 +45,14 @@ def test_the_deployment_through_every_pin():
         if CELL in m.get("workloads", []):
             pins.hold_per_layer_metric(man, m)
     pins.hold_the_first_sixteen(DOC)
-    assert entry == DOC["workloads"][-1] and cfg == DOC["configs"][-1]
+    # added after the five one-chip cells and four configurations; entries
+    # that are there keep their places, later ones go after them
+    assert DOC["workloads"].index(entry) == 5 and DOC["configs"].index(cfg) == 4
     assert cfg["reduced"] == ["train_rows"] and entry["chips"] == 4
     assert (entry["config"], entry["traffic"]) == (CONFIG,
                                                    "remine-nightly-mesh4")
-    # the only cell that asks for four chips
-    assert [w["name"] for w in DOC["workloads"] if w["chips"] == 4] == [CELL]
+    # the first cell that asks for four chips
+    assert [w["name"] for w in DOC["workloads"] if w["chips"] == 4][0] == CELL
     assert len(cfg["source"]) <= 200 and len(entry["why"]) <= 200
     for word in ("host path", "mesh", "GB of bit columns a chip"):
         assert word in entry["why"]
@@ -115,75 +116,43 @@ def test_the_rule_of_the_program_takes_the_cell_on_four_chips_alone(
 
 # ------------------------------------------------------ the per-layer names
 def test_the_cells_per_layer_metrics_are_these_thirteen():
-    cell = manifest.Manifest().cell(CELL)
-    assert [m["name"] for m in cell.per_layer] == SHARED + MESH_METRICS
-    assert MESH_METRICS == [
-        "mesh_read_ms_per_job", "mesh_scan_ms_per_job", "mesh_put_ms_per_job",
-        "mesh_support_ms_per_job", "mesh_allreduce_ms_per_job",
-        "mesh_pairs_roofline", "mesh_sets_roofline",
-        "mesh_unspanned_ms_per_job", "mesh_idle_named_share"]
-    assert [m["name"] for m in cell.end_to_end] == ["job_s", "setup_s"]
+    """The four it shares and its nine, in their order among whatever a
+    later PR adds, with their layers and sources (`pins.hold_mesh_names`);
+    the cell stands on the four shared lists after the cells that were on
+    them before it."""
+    pins.hold_mesh_names(manifest.Manifest())
+    assert MESH_METRICS[:9] == pins.MESH_NAMES
     layers = {m["name"]: m for m in DOC["per_layer"]}
-    for name in MESH_METRICS:
-        assert layers[name]["workloads"] == [CELL]
-        assert layers[name]["moves"] == "job_s"
-    assert [layers[name]["layer"] for name in MESH_METRICS] == [
-        "Parse / replay", "Parse / replay", "Job registry and executors",
-        "Mesh", "Mesh", "Mesh", "Mesh", "Entry and device rule", "Device"]
-    assert [layers[name]["source"] for name in MESH_METRICS] == [
-        "program_span"] * 3 + ["device_trace"] * 4 + ["program_span"] * 2
-    # the four lists the cell joins, as their last name
     for name in SHARED:
-        assert layers[name]["workloads"][-1] == CELL
-    assert {m["name"] for m in DOC["per_layer"]
-            if CELL in m["workloads"]} == set(SHARED + MESH_METRICS)
+        assert layers[name]["workloads"].index(CELL) == 5
 
 
-def test_the_nine_stand_last_directly_after_the_one_chip_cells_nine():
+def test_the_nine_stand_after_the_one_chip_cells_nine_in_their_order():
     """A PR adds entries at the end of a list of `BENCHMARK.json` and
-    nowhere else: the nine `mesh_*` are the last nine, the nine `fia_*`
-    stand directly in front of them, in their order and on
-    `fia-t10i4.remine` alone, and everything in front of those is as PR 35
-    left it. This cell joins no `fia_*` list, least of all
+    nowhere else: the nine `mesh_*` stand after the nine `fia_*`, each nine
+    in its order, wherever later entries put the end of the list (PR 36
+    appended them last; PR 38 appended one more after them). The `fia_*`
+    are the one-chip cell's; this cell joins no `fia_*` list, least of all
     `fia_*_roofline`, which divide the whole file's work by one chip's
     peak."""
-    assert NAMES[-9:] == MESH_METRICS and len(MESH_METRICS) == 9
-    assert NAMES[-18:-9] == [n for n in NAMES if n.startswith("fia_")]
-    assert NAMES[-18] == "fia_read_ms_per_job"
-    assert NAMES[-19] == "nb_posterior_device_ms_per_job"
-    for m in DOC["per_layer"][-18:-9]:
-        assert m["workloads"] == [ONE_CHIP + ".remine"]
+    pins.hold_in_order(NAMES, pins.FIA_NAMES + pins.MESH_NAMES)
+    assert NAMES.index("fia_read_ms_per_job") == 25
+    assert NAMES.index("mesh_read_ms_per_job") == 34
+    for m in DOC["per_layer"]:
+        if m["name"].startswith("fia_"):
+            assert ONE_CHIP + ".remine" in m["workloads"]
+            assert CELL not in m["workloads"]
     one = [m["name"] for m in manifest.Manifest().cell(
         ONE_CHIP + ".remine").per_layer]
     assert not set(one) & set(MESH_METRICS)
 
 
 def test_the_one_chip_cells_thirteen_are_as_they_were_but_for_their_place():
-    """Every line of `test_itemsets.py`'s
-    `test_the_cells_per_layer_metrics_are_these_thirteen` but the one that
-    holds `fia_*` to the list's last nine places, which `conftest.py` marks
-    as expected to fail (new entries go last): the names, their order,
-    their one cell, what they move and their layers."""
-    one_chip = ONE_CHIP + ".remine"
-    fia = [n for n in NAMES if n.startswith("fia_")]
-    cell = manifest.Manifest().cell(one_chip)
-    assert [m["name"] for m in cell.per_layer] == SHARED + fia
-    assert fia == [
-        "fia_read_ms_per_job", "fia_scan_ms_per_job", "fia_put_ms_per_job",
-        "fia_candidates_ms_per_job", "fia_support_ms_per_job",
-        "fia_pairs_roofline", "fia_sets_roofline", "fia_unspanned_ms_per_job",
-        "fia_idle_named_share"]
-    assert [m["name"] for m in cell.end_to_end] == ["job_s", "setup_s"]
-    layers = {m["name"]: m for m in DOC["per_layer"]}
-    for name in fia:
-        assert layers[name]["workloads"] == [one_chip]
-        assert layers[name]["moves"] == "job_s"
-    assert not set(fia) & {m["name"] for m in manifest.Manifest().cell(
-        "rf-hangup.rebuild").per_layer}
-    assert [layers[name]["layer"] for name in fia] == [
-        "Parse / replay", "Parse / replay", "Job registry and executors",
-        "Job registry and executors", "Device kernels", "Device kernels",
-        "Device kernels", "Entry and device rule", "Device"]
+    """What `test_itemsets.py` holds of the one-chip cell's names
+    (`pins.hold_fia_names`: the names, their order, their cell, what they
+    move and their layers), held from here too, since this cell's nine
+    must stand after them and none of them may list this cell."""
+    pins.hold_fia_names(manifest.Manifest())
 
 
 def test_the_span_metrics_read_what_the_one_chip_cells_read():
@@ -278,7 +247,7 @@ EXPECTED = {
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))
 def test_each_mesh_metric_reads_the_recorded_four_chip_job(ctx, name):
-    assert sorted(EXPECTED) == sorted(MESH_METRICS)
+    assert sorted(EXPECTED) == sorted(pins.MESH_NAMES)
     assert read(ctx, name) == pytest.approx(EXPECTED[name], rel=1e-6)
 
 

@@ -132,8 +132,9 @@ def test_a_join_no_better_than_a_hundredth_of_the_window_is_refused(
 
 
 def test_leaves_that_overlap_share_no_idle_time_twice(ctx):
-    """`ctx["spans"]` carries no thread: the prefetcher's wait for its
-    block has the name of the job thread's, and may overlap a leaf."""
+    """The reader goes by its list of names, not by thread: the
+    prefetcher's wait for its block has the name of the job thread's, and
+    may overlap a leaf."""
     fetch = span(ctx, "knn.query.fetch")
     ctx["spans"].append({"name": "stream.stall.consumer",
                          "t0": fetch["t0"] + 0.001, "dur": 0.030})
