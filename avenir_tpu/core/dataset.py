@@ -21,7 +21,6 @@ ids never need to touch the device.
 
 from __future__ import annotations
 
-import contextlib
 import io
 import os
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
@@ -78,9 +77,11 @@ class Dataset:
         no keep_raw), 'native' requires it, 'python' forces the row parser.
 
         A path source parses under a `dataset.parse` span whose children
-        (`dataset.read`, `.parse.native`, `.encode`, `.range`) stay on the
-        caller's thread; the block route (bytes, on the prefetcher's
-        thread, which `stream.parse` already spans) emits none of them."""
+        (`dataset.read`, `.parse.native`, `.encode`, `.range`, and inside
+        `.parse.native` its steps `.parse.count`, `.prefill`, `.fields`,
+        `.check`, `.ids`) stay on the caller's thread; the block route
+        (bytes, on the prefetcher's thread, which `stream.parse` already
+        spans) emits none of them."""
         if isinstance(source, str) and os.path.exists(source):
             with _obs.span("dataset.parse", path=source,
                            nbytes=os.path.getsize(source)) as note:
@@ -168,7 +169,7 @@ class Dataset:
 
         # only the path route names its phases: a block's parse runs on
         # the prefetcher's thread, inside that route's own stream.parse
-        span = _obs.span if spanned else _no_span
+        span = _obs.span if spanned else _obs.no_span
 
         if not native_available():
             if required:
@@ -196,7 +197,7 @@ class Dataset:
                 n, columns, lazy = parse_csv_native(
                     data, delim, numeric,
                     [(f.ordinal, f.cardinality) for f in cats], strings,
-                    lazy_strings=True)
+                    lazy_strings=True, span=span)
                 note["rows"] = n
         except ValueError as e:
             # align cardinality errors with the Python parser (field name);
@@ -386,11 +387,6 @@ class Dataset:
 
     def __repr__(self) -> str:
         return f"Dataset(n={self.n_rows}, fields={len(self.schema)})"
-
-
-def _no_span(name: str, **attrs):
-    """What stands where `obs.span` would on a route that names no phases."""
-    return contextlib.nullcontext(attrs)
 
 
 def _discover_cardinality(fld, tokens) -> None:

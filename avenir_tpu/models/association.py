@@ -112,10 +112,11 @@ def stream_candidate_support(src: "StreamingTransactionSource",
     counts_d = jnp.zeros(c_pad, jnp.int32)
     for packed in double_buffered(src.packed_chunks(block)):
         # host-side span: the donated fold dispatches async, so the
-        # duration is dispatch+transfer time, not device occupancy
-        with _obs.span("stream.fold", sink="apriori_support"):
-            counts_d = bitset_fold_counts(
-                counts_d, jnp.asarray(packed), cand_d)
+        # duration is dispatch+transfer time, not device occupancy;
+        # recorded, as every per-block fold is, so it reads no counters
+        t0 = _obs.now()
+        counts_d = bitset_fold_counts(counts_d, jnp.asarray(packed), cand_d)
+        _obs.record("stream.fold", t0, sink="apriori_support")
     return np.asarray(counts_d, np.int64)
 
 
@@ -845,18 +846,20 @@ class FrequentItemsApriori:
                         continue          # padding: the slab stays empty
                     # the put is this route's fold of the stream: a slab
                     # of it goes into the resident state (the coverage
-                    # auditor's name)
-                    with _obs.span("stream.fold", sink="apriori_resident"):
-                        # one chip: `jnp.int32` is a device operation that
-                        # paces this loop; without it 24 more slabs are in
-                        # flight at 50M baskets (340 MB, PR 36)
-                        if chip is None:
-                            slab, word_at = (jnp.asarray(slabs[at]),
-                                             jnp.int32(j * words))
-                        else:
-                            slab, word_at = (jax.device_put(slabs[at], chip),
-                                             np.int32(j * words))
-                        parts[d] = place_columns(parts[d], slab, word_at)
+                    # auditor's name); recorded, as every per-block fold
+                    # is, so it reads no counters
+                    t0 = _obs.now()
+                    # one chip: `jnp.int32` is a device operation that
+                    # paces this loop; without it 24 more slabs are in
+                    # flight at 50M baskets (340 MB)
+                    if chip is None:
+                        slab, word_at = (jnp.asarray(slabs[at]),
+                                         jnp.int32(j * words))
+                    else:
+                        slab, word_at = (jax.device_put(slabs[at], chip),
+                                         np.int32(j * words))
+                    parts[d] = place_columns(parts[d], slab, word_at)
+                    _obs.record("stream.fold", t0, sink="apriori_resident")
             if mesh is None:
                 return jax.block_until_ready(parts[0])
             note_devices_used(len(chips))

@@ -217,10 +217,13 @@ class NeighborIndex:
 
             self.packed = t_num.shape[0] <= LANE_CORPUS_CAP
         with obs.span("knn.index.put") as note:
+            issued = obs.now()
             self.t_num = jnp.asarray(t_num) if t_num is not None else None
             self.t_cat = jnp.asarray(x_cat) if x_cat is not None else None
             self.ranges = jnp.asarray(ranges) if ranges.size else None
             note["nbytes"] = _nbytes(self.t_num, self.t_cat)
+        obs.landed("knn.index.put.landed",
+                   (self.t_num, self.t_cat, self.ranges), issued)
         self.cat_bins = bins
         self.n_valid = n_valid
         self.n_padded = (
@@ -368,8 +371,10 @@ class NearestNeighborClassifier:
         with obs.span("knn.index.put") as note:
             labels = np.zeros((pad,), np.int32)
             labels[:n_valid] = train.labels()
+            issued = obs.now()
             self.train_labels = jnp.asarray(labels)
             note["nbytes"] = labels.nbytes
+        obs.landed("knn.index.put.landed", self.train_labels, issued)
 
         # class-conditional weighting: P(features_i | class_i) per train row,
         # the quantity jobs (2)-(4) of the reference pipeline compute + join

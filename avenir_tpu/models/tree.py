@@ -918,6 +918,7 @@ class DecisionTreeBuilder:
             digits = _weight_digits(w_host.max(initial=1))
             w_host = to_lines(w_host)
             with obs.span("tree.put"):
+                issued = obs.now()
                 labels_d = put(labels)
                 if mesh is not None:
                     ws_d = shard_rows(mesh, w_host[None], axis=1)
@@ -926,6 +927,7 @@ class DecisionTreeBuilder:
                 else:
                     ws_d = jnp.asarray(w_host)[None]
                     leaf_ids = jnp.zeros((1,) + labels.shape, jnp.int32)
+            obs.landed("tree.put.landed", (labels_d, ws_d, leaf_ids), issued)
             return _grow_forest([self], seg_d, labels_d, ws_d, leaf_ids,
                                 digits, note, mesh)[0]
 
@@ -1162,14 +1164,18 @@ class RandomForestBuilder:
                 seg_d = segment_matrix(builders[0].splits, ds)
                 labels = to_lines(ds.labels())
             with obs.span("tree.put"):
+                issued = obs.now()
                 labels_d = jnp.asarray(labels)
                 leaf_ids = jnp.zeros((self.num_trees,) + labels.shape,
                                      jnp.int32)
+            obs.landed("tree.put.landed", (labels_d, leaf_ids), issued)
             with obs.span("forest.sample", sampling=self.sampling) as how:
                 ws, heaviest, made = self._sample(n)
                 how.update(made)
             with obs.span("tree.put"):
+                issued = obs.now()
                 ws_d = jnp.asarray(ws)
+            obs.landed("tree.put.landed", ws_d, issued)
             digits = _weight_digits(heaviest)
             del labels, ws
             self.trees = _grow_forest(builders, seg_d, labels_d, ws_d,

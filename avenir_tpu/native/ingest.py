@@ -167,6 +167,7 @@ def parse_csv_native(
     string_ordinals: List[int],
     lazy_strings: bool = False,
     threads: int = 0,
+    span=_obs.no_span,
 ) -> Tuple[int, Dict[int, np.ndarray], Dict[int, object]]:
     """One native pass: (n_rows, {ordinal: column array}, {ordinal: thunk}).
 
@@ -180,12 +181,21 @@ def parse_csv_native(
     lazy_strings=True, as zero-arg thunks in the third return value
     (materializing millions of python strings costs more than the whole
     numeric/categorical parse; algorithms that never read ids skip it
-    entirely)."""
+    entirely).
+
+    `span` is the caller's span factory (`obs.span`, or `obs.no_span` on
+    a route that names no phases); each step runs under a leaf of it, one
+    after the other: `dataset.parse.count`, `.prefill` (the outputs'
+    sentinels: their pages' first touch), `.fields` (the threaded pass),
+    `.check` (short rows of the categoricals), `.ids` (the string
+    columns' extraction)."""
     lib = _get_lib()
     if lib is None:
         raise RuntimeError("native CSV ingest unavailable (no g++?)")
     d = delim.encode()[0:1]
-    n = int(lib.csv_count_rows_mt(data, len(data), np.int32(threads)))
+    with span("dataset.parse.count") as note:
+        n = int(lib.csv_count_rows_mt(data, len(data), np.int32(threads)))
+        note["rows"] = n
     columns: Dict[int, np.ndarray] = {}
 
     num_ords = np.asarray(numeric_ordinals, np.int32)
@@ -204,24 +214,27 @@ def parse_csv_native(
     # prefill sentinels: rows shorter than the schema leave numeric NaN
     # and categorical the empty token's code (both matching the Python
     # parser), or -1 where the vocabulary has no such value (checked below)
-    num_out = np.full((len(num_ords), n), np.nan, np.float32)
-    cat_out = np.full((len(cat_ords), n), -1, np.int32)
-    for i, (_, card) in enumerate(categorical):
-        if "" in card:
-            cat_out[i] = card.index("")
+    with span("dataset.parse.prefill") as note:
+        num_out = np.full((len(num_ords), n), np.nan, np.float32)
+        cat_out = np.full((len(cat_ords), n), -1, np.int32)
+        for i, (_, card) in enumerate(categorical):
+            if "" in card:
+                cat_out[i] = card.index("")
+        note["nbytes"] = num_out.nbytes + cat_out.nbytes
     err_row = ctypes.c_int64(-1)
     err_ord = ctypes.c_int32(-1)
     # threads=0 lets the library pick hardware_concurrency; stripes are
     # capped so small buffers stay on the sequential path (identical
     # semantics either way — the MT entry splits at newline boundaries
     # into disjoint global row ranges)
-    got = int(lib.csv_parse_mt(
-        data, len(data), d, np.int32(max_ord),
-        num_ords, len(num_ords), num_out,
-        cat_ords, len(cat_ords), vocab_blob, vocab_counts, cat_out,
-        np.int64(n), ctypes.byref(err_row), ctypes.byref(err_ord),
-        np.int32(threads),
-    ))
+    with span("dataset.parse.fields", threads=threads):
+        got = int(lib.csv_parse_mt(
+            data, len(data), d, np.int32(max_ord),
+            num_ords, len(num_ords), num_out,
+            cat_ords, len(cat_ords), vocab_blob, vocab_counts, cat_out,
+            np.int64(n), ctypes.byref(err_row), ctypes.byref(err_ord),
+            np.int32(threads),
+        ))
     if got < 0:
         # recover the offending token for the standard error message
         bad = _extract_column(lib, data, d, int(err_ord.value))
@@ -235,26 +248,28 @@ def parse_csv_native(
             f"{err_ord.value}")
     for i, o in enumerate(numeric_ordinals):
         columns[o] = num_out[i]
-    for i, (o, _) in enumerate(categorical):
-        if (cat_out[i] < 0).any():
-            row = int(np.argmax(cat_out[i] < 0))
-            raise ValueError(
-                f"value '' not in declared cardinality of ordinal {o} "
-                f"(row {row} is short)")
-        columns[o] = cat_out[i]
+    with span("dataset.parse.check"):
+        for i, (o, _) in enumerate(categorical):
+            if (cat_out[i] < 0).any():
+                row = int(np.argmax(cat_out[i] < 0))
+                raise ValueError(
+                    f"value '' not in declared cardinality of ordinal {o} "
+                    f"(row {row} is short)")
+            columns[o] = cat_out[i]
     lazy: Dict[int, object] = {}
-    for o in string_ordinals:
-        if lazy_strings:
-            # the native extraction runs now into a COMPACT per-column
-            # buffer (so the thunk does not pin the whole CSV block); only
-            # the python-string materialization — the expensive part — is
-            # deferred
+    with span("dataset.parse.ids", columns=len(string_ordinals),
+              nbytes=0) as note:
+        for o in string_ordinals:
             raw = _extract_column_bytes(lib, data, d, o)
-            lazy[o] = (lambda r=raw: np.array(
-                r.decode().split("\n")[:-1], dtype=object))
-        else:
-            columns[o] = np.array(_extract_column(lib, data, d, o),
-                                  dtype=object)
+            note["nbytes"] += len(raw)
+            if lazy_strings:
+                # the native extraction runs now into a COMPACT per-column
+                # buffer (so the thunk does not pin the whole CSV block);
+                # only the python-string materialization — the expensive
+                # part — is deferred
+                lazy[o] = (lambda r=raw: np.array(_lines(r), dtype=object))
+            else:
+                columns[o] = np.array(_lines(raw), dtype=object)
     return got, columns, lazy
 
 
@@ -266,11 +281,14 @@ def _extract_column_bytes(lib, data: bytes, d: bytes, ordinal: int) -> bytes:
     return buf.raw[:w] if w > 0 else b""
 
 
-def _extract_column(lib, data: bytes, d: bytes, ordinal: int) -> List[str]:
-    raw = _extract_column_bytes(lib, data, d, ordinal)
-    if not raw:
-        return []
+def _lines(raw: bytes) -> List[str]:
+    """The tokens of a newline-joined column buffer (trailing newline
+    included), as `_extract_column_bytes` gives it."""
     return raw.decode().split("\n")[:-1]
+
+
+def _extract_column(lib, data: bytes, d: bytes, ordinal: int) -> List[str]:
+    return _lines(_extract_column_bytes(lib, data, d, ordinal))
 
 
 def distinct_column_native(data: bytes, delim: str, ordinal: int,

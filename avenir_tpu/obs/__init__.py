@@ -19,7 +19,12 @@ so nothing here may import jax/numpy at module scope):
   of the same name, so that under a profiler session (``python -m
   avenir_tpu <job> --trace DIR``) the span stands on the host's line of
   the device trace, on the device operations' clock. ``jax`` is looked
-  up, never imported.
+  up, never imported. Every ``obs.span`` also carries what the process
+  spent over it, from ``getrusage``: ``cpu_ms``, ``minflt`` (first
+  touches of fresh pages) and ``nivcsw`` (the CPU taken away), process-
+  wide since the native passes run on the library's threads.
+  ``obs.landed(name, arrays, t0)`` records how long a large put took to
+  land on the device, from a waiter thread, so the job never waits.
 - **Streaming histograms** (:mod:`avenir_tpu.obs.histogram`): fixed
   log-spaced bucket accumulators that merge like ``RunningStats``
   (counts and sums are additive, so ``merge`` is associative and
@@ -35,23 +40,24 @@ Contract: tracing is observation only, so a fused run with tracing ON
 writes the bytes of the run with tracing OFF
 (``tests/test_shared_scan.py::test_fused_outputs_byte_identical_under_tracing``).
 What the spans cost on the chip is in ``PERF.md`` (PR 25: nothing one
-can measure). Tracing is ON by
-default (``AVENIR_TRACE=0`` or :func:`set_enabled` turns it off); every
-record call is one enabled-flag load away from free when off.
+can measure; the counters two ``getrusage`` calls a span). Tracing is
+ON by default (``AVENIR_TRACE=0`` or :func:`set_enabled` turns it off);
+every record call is one enabled-flag load away from free when off, and
+then no counter is read and no waiter started.
 """
 
 # the submodule is named ``histogram`` (not ``hist``) on purpose: a
 # submodule named ``hist`` would shadow the ``obs.hist(name)`` accessor
 # __all__ advertises below
 from avenir_tpu.obs.histogram import LatencyHistogram
-from avenir_tpu.obs.trace import (Span, SpanRecorder, capture, enabled,
-                                  hist, hist_summaries, now, observe,
-                                  record, record_min, recorder, reset_hists,
-                                  set_enabled, span)
+from avenir_tpu.obs.trace import (USAGE_ATTRS, Span, SpanRecorder, capture,
+                                  enabled, hist, hist_summaries, landed, now,
+                                  no_span, observe, record, record_min,
+                                  recorder, reset_hists, set_enabled, span)
 
 __all__ = [
-    "Span", "SpanRecorder", "LatencyHistogram",
+    "Span", "SpanRecorder", "LatencyHistogram", "USAGE_ATTRS",
     "capture", "enabled", "set_enabled", "recorder",
-    "now", "record", "record_min", "span",
+    "now", "record", "record_min", "span", "no_span", "landed",
     "observe", "hist", "hist_summaries", "reset_hists",
 ]

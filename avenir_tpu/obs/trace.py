@@ -18,6 +18,16 @@ per-phase table.
 Memory bound: the ring keeps the NEWEST ``capacity`` spans (overflow
 drops the oldest and counts them in ``dropped``) — a resident server
 can trace forever in O(capacity).
+
+Resource counters: every :func:`span` also carries what the process
+spent over it, read by ``getrusage(RUSAGE_SELF)`` at open and close
+(:data:`USAGE_ATTRS`): ``cpu_ms`` (user plus system time), ``minflt``
+(minor page faults: first touches of fresh pages) and ``nivcsw``
+(involuntary context switches: the CPU taken away). The readings are
+the whole process's, not the span's thread's, because the native passes
+run on the library's own threads; a span that overlaps another thread's
+work counts that work too. The retroactive :func:`record` sites carry
+none.
 """
 
 from __future__ import annotations
@@ -30,6 +40,11 @@ import threading
 import time
 from typing import Dict, Iterator, List, NamedTuple, Optional
 
+try:
+    from resource import RUSAGE_SELF, getrusage
+except ImportError:             # no such module on this platform: no counters
+    getrusage = None
+
 #: default ring capacity (spans); ~100 bytes each -> a few MB bound
 DEFAULT_CAPACITY = 65_536
 
@@ -37,6 +52,10 @@ DEFAULT_CAPACITY = 65_536
 #: handoffs complete in microseconds; recording every one would be
 #: noise, not attribution
 STALL_MIN_SECS = 1e-3
+
+#: the longest :func:`capture` waits at its end for each put still on its
+#: way to the device, so that the landing joins the captured ring
+LANDING_WAIT_SECS = 60.0
 
 
 class Span(NamedTuple):
@@ -163,11 +182,26 @@ def record_min(name: str, t0: float, min_dur: float = STALL_MIN_SECS,
         _recorder.record(name, t0, dur, attrs=attrs or None)
 
 
+#: what :func:`span` adds to its attributes from the process's usage
+USAGE_ATTRS = ("cpu_ms", "minflt", "nivcsw")
+
+
+def _usage():
+    """(CPU seconds, minor faults, involuntary switches) of the whole
+    process so far, or None where the platform has no ``resource``."""
+    if getrusage is None:
+        return None
+    ru = getrusage(RUSAGE_SELF)
+    return ru.ru_utime + ru.ru_stime, ru.ru_minflt, ru.ru_nivcsw
+
+
 @contextlib.contextmanager
 def span(name: str, **attrs) -> Iterator[Dict]:
     """Context-manager span around a region (exception-safe: the span
     records however the block exits). Yields the span's attribute dict,
     so what is known only at the end (``rows``, ``nbytes``) is set there.
+    At the close the process's usage over the span is added to it
+    (``cpu_ms``, ``minflt``, ``nivcsw``: the module docstring).
 
     With tracing on and ``jax`` already imported the region also enters
     a ``jax.profiler.TraceAnnotation`` of the same name: under a live
@@ -181,19 +215,84 @@ def span(name: str, **attrs) -> Iterator[Dict]:
     jax = sys.modules.get("jax")
     note = jax.profiler.TraceAnnotation(name) if jax is not None \
         else contextlib.nullcontext()
+    # the counters are read outside the timed interval, at both ends
+    before = _usage()
     t0 = time.perf_counter()
     try:
         with note:
             yield attrs
     finally:
-        record(name, t0, **attrs)
+        dur = time.perf_counter() - t0
+        if before is not None:
+            after = _usage()
+            attrs.update(cpu_ms=1e3 * (after[0] - before[0]),
+                         minflt=after[1] - before[1],
+                         nivcsw=after[2] - before[2])
+        if _ENABLED:
+            _recorder.record(name, t0, dur, attrs=attrs or None)
+
+
+def no_span(name: str, **attrs):
+    """What stands where :func:`span` would on a route that names no
+    phases: the same attribute dict, nothing recorded."""
+    return contextlib.nullcontext(attrs)
+
+
+def landed(name: str, arrays, t0: float,
+           **attrs) -> Optional[threading.Thread]:
+    """Record `name` from `t0` (from :func:`now`: when the put of the
+    device `arrays`, any pytree, was issued) to the moment they are
+    ready on the device, with their ``nbytes``. A short-lived daemon
+    thread waits for them, so the caller never does and nothing is
+    synchronised; the span stands on that thread, in the ring that was
+    in place at the call, and :func:`capture` joins the waiters at its
+    end. An array deleted or donated before it lands ends the span with
+    ``landed`` False, and nothing is raised. Returns the thread, or None
+    with tracing off or ``jax`` not imported, when nothing is read."""
+    jax = sys.modules.get("jax")
+    if not _ENABLED or jax is None:
+        return None
+    leaves = jax.tree_util.tree_leaves(arrays)
+    attrs["nbytes"] = sum(int(a.nbytes) for a in leaves)
+    ring = _recorder
+
+    def wait() -> None:
+        try:
+            jax.block_until_ready(leaves)
+            attrs["landed"] = True
+        except RuntimeError:    # deleted or donated: it will not land
+            attrs["landed"] = False
+        ring.record(name, t0, time.perf_counter() - t0, attrs=attrs)
+
+    waiter = threading.Thread(target=wait, name=name, daemon=True)
+    with _waiters_lock:
+        _waiters[:] = [w for w in _waiters if w.is_alive()]
+        _waiters.append(waiter)
+    waiter.start()
+    return waiter
+
+
+#: the landing waiters started and not yet joined
+_waiters: List[threading.Thread] = []
+_waiters_lock = threading.Lock()
+
+
+def _join_waiters(timeout: float = LANDING_WAIT_SECS) -> None:
+    """Join every landing waiter started so far, each for `timeout` at
+    most, so that a captured run holds the landings of its puts."""
+    with _waiters_lock:
+        pending, _waiters[:] = list(_waiters), []
+    for waiter in pending:
+        waiter.join(timeout)
 
 
 @contextlib.contextmanager
 def capture(capacity: int = DEFAULT_CAPACITY) -> Iterator[SpanRecorder]:
     """Swap in a FRESH recorder (and force tracing on) for the duration
     — the span-coverage auditor and tests capture one run's spans in
-    isolation this way — then restore the previous recorder and flag."""
+    isolation this way — then wait for the landings of the puts made
+    inside it (:func:`landed`) and restore the previous recorder and
+    flag."""
     global _recorder
     fresh = SpanRecorder(capacity)
     prev_rec, _recorder = _recorder, fresh
@@ -201,6 +300,7 @@ def capture(capacity: int = DEFAULT_CAPACITY) -> Iterator[SpanRecorder]:
     try:
         yield fresh
     finally:
+        _join_waiters()
         _recorder = prev_rec
         set_enabled(prev_on)
 

@@ -174,8 +174,6 @@ class RunSession:
                     if cost:
                         self.store.note_fold_cost(canonical, self.digest,
                                                   cost)
-            _obs.record("tune.decide", _obs.now(), job=self.profile_job,
-                        moves=len(reasons))
             return chosen
         except Exception:
             return None

@@ -25,6 +25,12 @@ from avenir_tpu.obs.histogram import LatencyHistogram
 from avenir_tpu.obs.trace import SpanRecorder
 
 
+def _given(attrs):
+    """A span's attributes less the resource counters every span adds."""
+    return {k: v for k, v in (attrs or {}).items()
+            if k not in trace.USAGE_ATTRS}
+
+
 # ------------------------------------------------------------------- ring
 def test_ring_overflow_keeps_newest_spans():
     rec = SpanRecorder(capacity=8)
@@ -101,7 +107,8 @@ def test_span_context_manager_records_on_exception():
                 raise RuntimeError("boom")
     spans = rec.spans()
     assert [sp.name for sp in spans] == ["risky"]
-    assert spans[0].attrs == {"tag": "x"}
+    assert _given(spans[0].attrs) == {"tag": "x"}
+    assert set(trace.USAGE_ATTRS) <= set(spans[0].attrs)
 
 
 def test_span_yields_its_attributes_so_the_end_can_set_them():
@@ -116,8 +123,8 @@ def test_span_yields_its_attributes_so_the_end_can_set_them():
             trace.set_enabled(prev)
         with trace.span("bare"):
             pass
-    assert [(sp.name, sp.attrs) for sp in rec.spans()] == [
-        ("parse", {"path": "p", "rows": 7}), ("bare", None)]
+    assert [(sp.name, _given(sp.attrs)) for sp in rec.spans()] == [
+        ("parse", {"path": "p", "rows": 7}), ("bare", {})]
 
 
 def test_span_annotates_for_the_profiler_only_when_jax_is_there(monkeypatch):

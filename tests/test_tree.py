@@ -867,7 +867,8 @@ def test_forest_sample_says_how_the_draws_were_made(tmp_path):
         run_job("randomForest", forest_properties(schema_path), [train],
                 str(tmp_path / "out"))
     (span,) = [sp for sp in ring.spans() if sp.name == "forest.sample"]
-    assert span.attrs == {
+    assert {k: v for k, v in span.attrs.items()
+            if k not in obs.USAGE_ATTRS} == {
         "sampling": "withReplace", "native": True,
         "threads": span.attrs["threads"],
         "rejected": raw_walk(0, rows, 10 * rows)[1]}
