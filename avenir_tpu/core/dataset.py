@@ -430,6 +430,18 @@ def pad_rows(n: int, multiple: int) -> int:
     return ((n + multiple - 1) // multiple) * multiple
 
 
+def _feature_ranges(num_fields) -> np.ndarray:
+    """float32 [Dn]: each numeric field's declared max - min, 1.0 where the
+    schema declares no extent."""
+    return np.array(
+        [
+            (f.max - f.min) if (f.max is not None and f.min is not None) else 1.0
+            for f in num_fields
+        ],
+        dtype=np.float32,
+    )
+
+
 def extract_mixed_features(ds: "Dataset"):
     """Split a dataset into distance-ready arrays: (x_num float32 [n, Dn],
     ranges float32 [Dn], x_cat int32 [n, Dc] | None, cat_bins tuple | None).
@@ -441,13 +453,7 @@ def extract_mixed_features(ds: "Dataset"):
     num_fields = [f for f in ds.schema.feature_fields if f.is_numeric]
     cat_fields = [f for f in ds.schema.feature_fields if f.is_categorical]
     x_num = ds.feature_matrix(num_fields)
-    ranges = np.array(
-        [
-            (f.max - f.min) if (f.max is not None and f.min is not None) else 1.0
-            for f in num_fields
-        ],
-        dtype=np.float32,
-    )
+    ranges = _feature_ranges(num_fields)
     if cat_fields:
         x_cat = np.stack(
             [ds.column(f.ordinal).astype(np.int32) for f in cat_fields], axis=1
@@ -456,3 +462,19 @@ def extract_mixed_features(ds: "Dataset"):
     else:
         x_cat, bins = None, None
     return x_num, ranges, x_cat, bins
+
+
+def mixed_feature_columns(ds: "Dataset"):
+    """`extract_mixed_features`' parts before they are stacked: (numeric
+    columns float32 [n] each, ranges float32 [Dn], categorical code columns
+    int32 [n] each, cat_bins tuple | None). A column already of its type
+    is the dataset's own array, not a copy; another is converted as
+    `feature_matrix` converts it."""
+    num_fields = [f for f in ds.schema.feature_fields if f.is_numeric]
+    cat_fields = [f for f in ds.schema.feature_fields if f.is_categorical]
+    num = [ds.column(f.ordinal).astype(np.float32, copy=False)
+           for f in num_fields]
+    cats = [ds.column(f.ordinal).astype(np.int32, copy=False)
+            for f in cat_fields]
+    bins = tuple(len(f.cardinality) for f in cat_fields) if cat_fields else None
+    return num, _feature_ranges(num_fields), cats, bins

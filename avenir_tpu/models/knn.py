@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from avenir_tpu import obs
 from avenir_tpu.core.dataset import Dataset, pad_rows
 from avenir_tpu.models.naive_bayes import NaiveBayesModel
+from avenir_tpu.native import ingest
 from avenir_tpu.ops.distance import blocked_topk_neighbors, pad_train
 from avenir_tpu.utils.metrics import ConfusionMatrix
 
@@ -51,6 +52,7 @@ _BLOCK_Q = 256
 
 
 from avenir_tpu.core.dataset import extract_mixed_features as _extract
+from avenir_tpu.core.dataset import mixed_feature_columns
 
 
 def _nbytes(*arrays) -> int:
@@ -65,13 +67,15 @@ def _expand_mixed(x_num, ranges, x_cat, bins, metric: str):
     by 1/sqrt(2) (euclidean) or 1/2 (manhattan) makes the kernel's summed
     term exactly the hamming mismatch count of ops.distance's mixed
     semantics. The caller divides by the SEMANTIC attribute count
-    (n_attrs) instead of the expanded column count."""
+    (n_attrs) instead of the expanded column count. `kernel_matrix`
+    writes these rows in one native pass; this stacked form is its
+    fallback and its tests' oracle."""
     n = x_num.shape[0] if x_num is not None else x_cat.shape[0]
     cols = []
     if x_num is not None and x_num.shape[1]:
         cols.append(np.asarray(x_num, np.float32)
                     / np.maximum(np.asarray(ranges, np.float32), 1e-9))
-    scale = (1.0 / np.sqrt(2.0)) if metric == "euclidean" else 0.5
+    scale = _onehot_scale(metric)
     rows = np.arange(n, dtype=np.int32)
     for f, b in enumerate(bins or ()):
         oh = np.zeros((n, b), np.float32)
@@ -80,6 +84,41 @@ def _expand_mixed(x_num, ranges, x_cat, bins, metric: str):
     x = np.concatenate(cols, axis=1) if cols else np.zeros((n, 0), np.float32)
     n_attrs = (x_num.shape[1] if x_num is not None else 0) + len(bins or ())
     return x, n_attrs
+
+
+def _cat_names(ds: Dataset) -> Tuple[str, ...]:
+    """The dataset's categorical features' names, in column order."""
+    return tuple(f.name for f in ds.schema.feature_fields if f.is_categorical)
+
+
+def _onehot_scale(metric: str) -> np.float32:
+    """A one-hot entry's value: a mismatched pair then adds exactly 1 to
+    the kernel's sum (see `_expand_mixed`)."""
+    return np.float32((1.0 / np.sqrt(2.0)) if metric == "euclidean" else 0.5)
+
+
+def kernel_matrix(num, ranges, cats, bins, metric: str, multiple: int,
+                  threads: int = 0, cat_names=()) -> Tuple[np.ndarray, bool]:
+    """The pallas kernels' input from a dataset's columns
+    (`mixed_feature_columns`): float32 [n padded to `multiple`, width],
+    `_expand_mixed`'s normalised, one-hot-expanded rows, then zero rows.
+    Written in one native pass striped over the rows
+    (`native.ingest.knn_index_matrix_native`); where the native library
+    is not built, `_expand_mixed` and `pad_train` make the same bytes.
+    Returns (matrix, whether the native pass wrote it)."""
+    n = len(num[0]) if num else len(cats[0]) if cats else 0
+    if ingest.native_available():
+        out = np.empty((pad_rows(n, multiple), len(num) + sum(bins or ())),
+                       np.float32)
+        ingest.knn_index_matrix_native(num, ranges, cats, bins or (),
+                                       _onehot_scale(metric), out,
+                                       threads=threads, cat_names=cat_names)
+        return out, True
+    x_num = (np.stack(num, axis=1) if num
+             else np.zeros((n, 0), np.float32))
+    x_cat = np.stack(cats, axis=1) if cats else None
+    x, _ = _expand_mixed(x_num, ranges, x_cat, bins, metric)
+    return pad_train(x, None, multiple)[0], False
 
 
 @partial(jax.jit, static_argnames=("kernel", "num_classes", "class_cond",
@@ -162,15 +201,13 @@ class NeighborIndex:
         self.approx = approx
         self.block = min(block, max(len(train), 1))
 
-        with obs.span("knn.index.extract"):
-            x_num, ranges, x_cat, bins = _extract(train)
         # the pallas kernels serve numeric AND mixed data on real TPU (the
         # flop-heavy sifarish role): categoricals one-hot-expand into the
         # numeric matrix (_expand_mixed) so the hamming term is matmul work
         from avenir_tpu.ops.pallas_knn import pallas_available
 
-        has_features = (x_num.shape[1] + (x_cat.shape[1] if x_cat is not None
-                                          else 0)) > 0
+        has_features = any(f.is_numeric or f.is_categorical
+                           for f in train.schema.feature_fields)
         if use_pallas:
             # explicit opt-in still requires the kernel's preconditions
             if not pallas_available():
@@ -192,22 +229,32 @@ class NeighborIndex:
         )
         self.packed = packed and self.use_pallas
         self.n_attrs = None
-        self._expand_ranges = ranges
         if self.use_pallas:
-            # normalize + one-hot-expand once; pad to the kernel block.
-            # 256x8192 f32 tile = 8 MB VMEM, the measured sweet spot; the
-            # lane-packed kernel carries global chunk ids so block_t has no
-            # index-bit cap (corpus cap 524288 rows enforced by the kernel)
-            with obs.span("knn.index.expand"):
-                x_num, self.n_attrs = _expand_mixed(x_num, ranges, x_cat,
-                                                    bins, metric)
-            x_cat = None
-            # 256-row granularity: the lane kernel's pair-fold front end
-            # requires block_t % 256 == 0 (the exact kernel only needs
-            # 128, but a 128-odd block would crash the packed path)
+            # normalize + one-hot-expand once, padded to the kernel block,
+            # straight from the dataset's columns into the matrix the put
+            # takes. 256x8192 f32 tile = 8 MB VMEM, the measured sweet
+            # spot; the lane-packed kernel carries global chunk ids so
+            # block_t has no index-bit cap (corpus cap 524288 rows
+            # enforced by the kernel). 256-row granularity: the lane
+            # kernel's pair-fold front end requires block_t % 256 == 0
+            # (the exact kernel only needs 128, but a 128-odd block would
+            # crash the packed path)
             self.block = max(256, min(pad_rows(len(train), 256), 8192))
-        with obs.span("knn.index.pad"):
-            t_num, x_cat, n_valid = pad_train(x_num, x_cat, self.block)
+            with obs.span("knn.index.extract"):
+                num, ranges, cats, bins = mixed_feature_columns(train)
+            with obs.span("knn.index.expand", threads=0) as note:
+                t_num, note["native"] = kernel_matrix(
+                    num, ranges, cats, bins, metric, self.block,
+                    cat_names=_cat_names(train))
+                note["nbytes"] = t_num.nbytes
+            self.n_attrs = len(num) + len(cats)
+            x_cat, n_valid = None, len(train)
+        else:
+            with obs.span("knn.index.extract"):
+                x_num, ranges, x_cat, bins = _extract(train)
+            with obs.span("knn.index.pad"):
+                t_num, x_cat, n_valid = pad_train(x_num, x_cat, self.block)
+        self._expand_ranges = ranges
         # the cap is a static property of the corpus: decide the packed
         # routing once here, not per query (beyond the lane kernel's
         # packed-chunk-id cap the exact kernel serves — explicit index
@@ -241,16 +288,14 @@ class NeighborIndex:
         """A test block as the search takes it: (q_num, q_cat, nq). On the
         pallas route q_num is normalized, one-hot-expanded and padded to
         the kernels' 256-row query block, and q_cat is None."""
-        q_num, _, q_cat, _ = _extract(test)
         if not self.use_pallas:
+            q_num, _, q_cat, _ = _extract(test)
             return q_num, q_cat, len(test)
-        q, _ = _expand_mixed(q_num, self._expand_ranges, q_cat,
-                             self.cat_bins, self.metric)
-        nq = q.shape[0]
-        pad = (-nq) % _BLOCK_Q
-        if pad:
-            q = np.concatenate([q, np.zeros((pad, q.shape[1]), q.dtype)])
-        return q, None, nq
+        num, _, cats, _ = mixed_feature_columns(test)
+        q, _ = kernel_matrix(num, self._expand_ranges, cats, self.cat_bins,
+                             self.metric, _BLOCK_Q,
+                             cat_names=_cat_names(test))
+        return q, None, len(test)
 
     def search(self, queries: Tuple) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(dist [nq,k], train index [nq,k]) of prepared `queries`, as

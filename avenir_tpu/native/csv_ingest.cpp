@@ -1126,3 +1126,73 @@ int64_t pcg64_bootstrap_counts(uint64_t state_hi, uint64_t state_lo,
 }
 
 }  // extern "C"
+
+extern "C" {
+
+// The kNN kernels' input matrix, written once from the parser's columns:
+// out is float32 [n_padded, width], row-major, width = n_num + the sum of
+// bins. In row i < n, column j < n_num is num[j][i] / max(ranges[j],
+// 1e-9) (the floor rounded as numpy rounds the Python float, division in
+// float32 as numpy divides), and each categorical f's bins[f] columns are
+// zero but for `scale` at cat[f][i]; rows n..n_padded are zeros. The rows
+// are striped by stripe_count's rule on the bytes written, so each page
+// of `out` is first touched by the stripe that fills it. Returns the
+// stripes cut; or -1 where some categorical holds a code outside
+// [0, bins[f]), *err_field and *err_row then naming the first such
+// (field, row) in row order.
+int64_t knn_index_matrix(const float* const* num, const float* ranges,
+                         int32_t n_num, const int32_t* const* cat,
+                         const int32_t* bins, int32_t n_cat, float scale,
+                         int64_t n, int64_t n_padded, float* out,
+                         int32_t n_threads, int32_t* err_field,
+                         int64_t* err_row) {
+    int64_t width = n_num;
+    for (int32_t f = 0; f < n_cat; ++f) width += bins[f];
+    std::vector<float> den(n_num);
+    for (int32_t j = 0; j < n_num; ++j)
+        den[j] = std::max(ranges[j], static_cast<float>(1e-9));
+    const int32_t stripes = stripe_count(
+        n_padded * width * static_cast<int64_t>(sizeof(float)), n_threads);
+    // a stripe's first bad code: (row, field), row -1 where none
+    std::vector<int64_t> bad_row(stripes, -1);
+    std::vector<int32_t> bad_field(stripes, -1);
+    auto fill = [&](int32_t s) {
+        const int64_t lo = n_padded * s / stripes;
+        const int64_t hi = n_padded * (s + 1) / stripes;
+        const int64_t filled = std::min(hi, n);
+        bad_row[s] = -1;
+        for (int64_t i = lo; i < filled; ++i) {
+            float* row = out + i * width;
+            for (int32_t j = 0; j < n_num; ++j) row[j] = num[j][i] / den[j];
+            float* hot = row + n_num;
+            for (int32_t f = 0; f < n_cat; ++f) {
+                std::memset(hot, 0, sizeof(float) * bins[f]);
+                const int32_t code = cat[f][i];
+                if (code >= 0 && code < bins[f]) {
+                    hot[code] = scale;
+                } else if (bad_row[s] < 0) {
+                    bad_row[s] = i;
+                    bad_field[s] = f;
+                }
+                hot += bins[f];
+            }
+        }
+        if (hi > filled && hi > lo) {
+            const int64_t from = std::max(lo, filled);
+            std::memset(out + from * width, 0,
+                        sizeof(float) * width * (hi - from));
+        }
+    };
+    if (stripes == 1 || !run_threads(stripes, fill))
+        for (int32_t s = 0; s < stripes; ++s) fill(s);
+    for (int32_t s = 0; s < stripes; ++s) {
+        if (bad_row[s] >= 0) {
+            *err_field = bad_field[s];
+            *err_row = bad_row[s];
+            return -1;
+        }
+    }
+    return stripes;
+}
+
+}  // extern "C"

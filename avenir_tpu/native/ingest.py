@@ -133,6 +133,12 @@ def _build() -> Optional[ctypes.CDLL]:
     lib.pcg64_bootstrap_counts.argtypes = [
         u64, u64, u64, u64, i64, i32, i64, p_i32, i32,
         ctypes.POINTER(i32), ctypes.POINTER(i32)]
+    p_ptr = np.ctypeslib.ndpointer(np.uintp, flags="C_CONTIGUOUS")
+    lib.knn_index_matrix.restype = i64
+    lib.knn_index_matrix.argtypes = [
+        p_ptr, p_f32, i32, p_ptr, p_i32, i32, ctypes.c_float, i64, i64,
+        np.ctypeslib.ndpointer(np.float32, ndim=2, flags="C_CONTIGUOUS"),
+        i32, ctypes.POINTER(i32), p_i64]
     return lib
 
 
@@ -503,6 +509,53 @@ def bootstrap_counts_native(rng: np.random.Generator, n: int,
         lcg["inc"] & mask, n, ws.shape[0], ws.shape[1], ws,
         np.int32(threads), ctypes.byref(most), ctypes.byref(used)))
     return BootstrapCounts(rejected, most.value, used.value)
+
+
+def knn_index_matrix_native(num: Sequence[np.ndarray], ranges: np.ndarray,
+                            cats: Sequence[np.ndarray], bins: Sequence[int],
+                            scale: np.float32, out: np.ndarray,
+                            threads: int = 0,
+                            cat_names: Sequence[str] = ()) -> int:
+    """Write the kNN kernels' input matrix into `out`, float32
+    [n_padded, len(num) + sum(bins)], in one pass striped over the rows
+    (`knn_index_matrix`): each numeric column divided by its range
+    floored at 1e-9, each categorical one-hot at `scale`, the rows past
+    the columns' length zero. `num` are float32 and `cats` int32 columns
+    of one length; `threads=0` lets the library choose. Returns the
+    stripes cut. A code outside [0, bins) raises ValueError, naming the
+    column by `cat_names` where given."""
+    lib = _get_lib()
+    if lib is None:
+        raise RuntimeError("native CSV ingest unavailable (no g++?)")
+    num = [np.ascontiguousarray(c) for c in num]
+    cats = [np.ascontiguousarray(c) for c in cats]
+    n = len(num[0]) if num else len(cats[0]) if cats else 0
+    width = len(num) + int(sum(bins))
+    ranges = np.ascontiguousarray(ranges)
+    if (any(c.dtype != np.float32 or c.shape != (n,) for c in num)
+            or any(c.dtype != np.int32 or c.shape != (n,) for c in cats)
+            or ranges.dtype != np.float32 or ranges.shape != (len(num),)
+            or len(bins) != len(cats)):
+        raise ValueError("knn_index_matrix wants float32 numeric and int32 "
+                         "code columns of one length, a float32 range each")
+    if (out.dtype != np.float32 or out.ndim != 2 or out.shape[0] < n
+            or out.shape[1] != width or not out.flags.c_contiguous):
+        raise ValueError(f"out wants a C-contiguous float32 [>= {n}, "
+                         f"{width}] array")
+    num_at = np.asarray([c.ctypes.data for c in num], np.uintp)
+    cat_at = np.asarray([c.ctypes.data for c in cats], np.uintp)
+    err_field, err_row = ctypes.c_int32(-1), ctypes.c_int64(-1)
+    stripes = int(lib.knn_index_matrix(
+        num_at, ranges, len(num), cat_at, np.asarray(bins, np.int32),
+        len(cats), scale, n, out.shape[0], out, np.int32(threads),
+        ctypes.byref(err_field), ctypes.byref(err_row)))
+    if stripes < 0:
+        f, row = err_field.value, err_row.value
+        name = cat_names[f] if f < len(cat_names) else f"#{f}"
+        raise ValueError(
+            f"categorical field {name!r} holds code {int(cats[f][row])} at "
+            f"row {row}, outside its {bins[f]} values")
+    return stripes
 
 
 def native_seq_ready(delim: str) -> bool:
